@@ -8,7 +8,7 @@ equivalent, written against spec/api.json rather than generated from it:
 - ``RestClient`` — the full REST surface (check, batch check, expand,
   relation-tuple CRUD with pagination, health, version, metrics), returning
   domain objects (RelationTuple, Tree) and raising the same KetoError
-  taxonomy the server maps from.
+  classes the server maps from.
 - ``GrpcClient`` — the five ory.keto.acl.v1alpha1 services over one read
   and one write channel, same surface as the published Keto gRPC clients.
 

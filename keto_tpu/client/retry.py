@@ -43,7 +43,7 @@ from typing import Callable, Optional
 #: gRPC status codes worth retrying: the server was unreachable/restarting,
 #: or explicitly shed this request before doing any work.
 RETRYABLE_GRPC_CODES = ("UNAVAILABLE", "RESOURCE_EXHAUSTED")
-#: The HTTP equivalents (api/rest.py maps the same error taxonomy).
+#: The HTTP equivalents (api/rest.py maps the same error classes).
 RETRYABLE_HTTP_STATUS = (429, 503)
 
 

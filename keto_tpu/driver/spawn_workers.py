@@ -66,9 +66,9 @@ class SpawnWorkerPool:
         if allow_accel:
             env = dict(os.environ)
         else:
-            from ..utils.jaxenv import cpu_fallback_env
+            from ..utils.jaxenv import cpu_env
 
-            env = cpu_fallback_env()
+            env = cpu_env()
         env["KETO_WORKER_SPEC"] = json.dumps(spec)
         for _ in range(1, self.n_workers):
             self._procs.append(

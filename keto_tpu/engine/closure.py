@@ -14,13 +14,13 @@ Correctness contract is identical to the host oracle (CheckEngine): allowed
 iff a tuple path of length <= depth exists (reference semantics,
 internal/check/engine.go:36-114; depth accounting per engine.go:116-123).
 
-Query placement (``query_mode``): the final gather is tiny (B x F0 x L
-bytes) while accelerator dispatch latency varies wildly by deployment —
-sub-ms on local PCIe, ~100ms over a networked tunnel. ``device`` keeps the
-query as one jit call; ``host`` downloads D once per snapshot and serves
-queries from numpy (zero device round-trips on the hot path); ``auto``
-probes the link at first use and picks. The expensive O(M^3) closure BUILD
-always runs on the accelerator.
+Query placement (``query_mode``): ``device`` keeps D on the accelerator and
+runs each batch's gather as one jit call; ``host`` builds or downloads D
+once per snapshot and serves queries from native host kernels (forked read
+replicas, which must never call into jax, need this); ``auto`` follows the
+platform: device on an accelerator, host when the backend IS the host CPU.
+With device queries the closure build runs on the accelerator too (the
+Pallas semiring step on a TPU).
 
 Write-path freshness (``freshness``): every write advances the store version
 and invalidates the closure. Three policies:
@@ -77,7 +77,6 @@ from .overlay import WriteOverlay
 from ..graph.snapshot import _bucket
 
 _MIN_BATCH = 8
-_PROBE_SLOW_S = 0.005  # dispatch+transfer slower than this -> host queries
 
 # the closure stores distances in uint8 with INF_DIST=255 reserved, so the
 # deepest resolvable path is 254 interior steps
@@ -147,32 +146,6 @@ def _scrub_expected_rows(
     # distance back to the source is overwritten by the 0 self-distance
     exp[np.arange(n), rows] = 0
     return exp
-
-
-def _probe_roundtrip_slow() -> bool:
-    """Tiny H2D+D2H round trips; True when the link is latency-bound
-    (networked accelerator) and per-batch device queries would drown in
-    dispatch latency. Median of several probes: a single scheduling hiccup
-    at first use must not pin a locally-attached chip to host mode for the
-    process lifetime (VERDICT r4 weak #8). The decision is logged."""
-    x = jnp.asarray(np.zeros(8, np.float32))
-    np.asarray(x + 1)  # warm any lazy backend init
-    samples = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        np.asarray(jnp.asarray(np.ones(8, np.float32)) + 1)
-        samples.append(time.perf_counter() - t0)
-    rt = float(np.median(samples))
-    slow = rt > _PROBE_SLOW_S
-    logging.getLogger("keto.engine").info(
-        "query placement probe: median roundtrip %.2fms over %d samples "
-        "(threshold %.0fms) -> query_mode=%s",
-        1000 * rt,
-        len(samples),
-        1000 * _PROBE_SLOW_S,
-        "host" if slow else "device",
-    )
-    return slow
 
 
 class _ClosureArtifacts:
@@ -350,6 +323,14 @@ class ClosureCheckEngine:
         # the multi-minute cold build decomposed for /debug/attribution
         # readers and the performance guide
         self.last_build_phases: dict[str, float] = {}
+        # kernel of the most recent FULL build: "pallas" / "lax" (device
+        # semiring step), "host-bitset", or "matmul"
+        self.last_full_build_kernel = ""
+        # rows answered by the closure query on the device vs by the exact
+        # oracle (width overflow or an oversized interior)
+        self.n_device_rows = 0
+        self.n_oracle_rows = 0
+        self._count_lock = threading.Lock()
         from ..telemetry.tracing import NOOP_TRACER
 
         self.tracer = tracer if tracer is not None else NOOP_TRACER
@@ -386,25 +367,18 @@ class ClosureCheckEngine:
         if self._host_queries is None:
             import jax
 
-            try:
-                platform = jax.devices()[0].platform
-            except Exception:
-                platform = "unknown"
-            if platform == "cpu":
-                # XLA-CPU "device" queries run on the same silicon as the
-                # native-C host path but pay per-batch XLA dispatch and
-                # lose the prefetch-pipelined gathers (measured: host
-                # 759k vs device 697k RPS at github10m, gap widening with
-                # scale) — host wins whenever the backend IS the host.
-                # The roundtrip probe only arbitrates real accelerators:
-                # local chip -> device, tunneled chip -> host.
-                self._host_queries = True
-                logging.getLogger("keto.engine").info(
-                    "query placement: cpu backend -> query_mode=host "
-                    "(native kernels beat XLA-CPU dispatch)"
-                )
-            else:
-                self._host_queries = _probe_roundtrip_slow()
+            # XLA-CPU "device" queries run on the same silicon as the
+            # native-C host path but pay per-batch XLA dispatch and lose
+            # the prefetch-pipelined gathers (measured: host 759k vs device
+            # 697k RPS at github10m, gap widening with scale) — host wins
+            # whenever the backend IS the host; any accelerator serves
+            platform = jax.default_backend()
+            self._host_queries = platform == "cpu"
+            logging.getLogger("keto.engine").info(
+                "query placement: %s backend -> query_mode=%s",
+                platform,
+                "host" if self._host_queries else "device",
+            )
         return self._host_queries
 
     def fallback_engine(self):
@@ -731,6 +705,7 @@ class ClosureCheckEngine:
                     if host:
                         from .semiring import build_closure_bitset
 
+                        self.last_full_build_kernel = "host-bitset"
                         d_host = build_closure_bitset(
                             ig.ii_src,
                             ig.ii_dst,
@@ -744,8 +719,13 @@ class ClosureCheckEngine:
                             snap, ig, k_max, host=True, d_host=d_host
                         )
                     else:
-                        from .pallas_spmv import build_closure_semiring
+                        from .pallas_spmv import (
+                            build_closure_semiring,
+                            semiring_step,
+                        )
 
+                        self.last_full_build_kernel = semiring_step()
+                        span.set_attr("step", self.last_full_build_kernel)
                         packed = pack_adjacency(
                             ig.ii_src, ig.ii_dst, m_pad
                         )
@@ -761,6 +741,7 @@ class ClosureCheckEngine:
                 phases["kernel"] = round(time.perf_counter() - t0, 6)
             else:
                 t0 = time.perf_counter()
+                self.last_full_build_kernel = "matmul"
                 with self.tracer.span("closure.matmul", interior=ig.m):
                     art = _ClosureArtifacts(snap, ig, k_max, host)
                 phases["matmul"] = round(time.perf_counter() - t0, 6)
@@ -1105,6 +1086,7 @@ class ClosureCheckEngine:
         state, pinned = self._serving_pinned()
         if not isinstance(state, _ClosureArtifacts):
             # interior too large for a closure: exact fallback
+            self._tally(oracle=len(requests))
             return self.fallback_engine().batch_check(
                 requests, max_depth, depths
             )
@@ -1186,6 +1168,7 @@ class ClosureCheckEngine:
         if not isinstance(state, _ClosureArtifacts):
             # interior too large for a closure: exact fallback (the only
             # path that needs real tuple objects)
+            self._tally(oracle=n)
             return self.fallback_engine().batch_check(
                 cols.materialize(), max_depth, depths
             )
@@ -1242,6 +1225,7 @@ class ClosureCheckEngine:
         if not isinstance(state, _ClosureArtifacts):
             snap = self.snapshots.snapshot()
             reqs = self._decode_requests(snap, start, target)
+            self._tally(oracle=len(reqs))
             res = np.asarray(
                 self.fallback_engine().batch_check(
                     reqs, depths=[int(d) for d in depth]
@@ -1259,6 +1243,11 @@ class ClosureCheckEngine:
         return self._check_arrays(
             snap, art, start, target, is_id, depth, pinned
         )
+
+    def _tally(self, device: int = 0, oracle: int = 0) -> None:
+        with self._count_lock:
+            self.n_device_rows += device
+            self.n_oracle_rows += oracle
 
     def _decode_requests(self, snap, start, target) -> list[RelationTuple]:
         """ids -> RelationTuples (overflow/fallback paths only)."""
@@ -1379,6 +1368,7 @@ class ClosureCheckEngine:
                 over_reqs = self._decode_requests(
                     snap, start[idxs], target[idxs]
                 )
+            self._tally(oracle=len(idxs))
             res = fb.batch_check(
                 over_reqs, depths=[int(depth[i]) for i in idxs]
             )
@@ -1468,6 +1458,7 @@ class ClosureCheckEngine:
             best[best >= INF_DIST] = 1 << 30  # INF never satisfies a budget
             total = 1 + best + extra
             return (direct & (depth >= 1)) | (total <= depth)
+        self._tally(device=n)
         b = _bucket_pow2(n)
         if b != n:
             pad_rows = b - n

@@ -182,6 +182,9 @@ class DeviceFallbackEngine:
         # shapes whose compile failed, served by the oracle without opening
         # the circuit; insertion-ordered so the cap prunes oldest first
         self._quarantine: dict[tuple[int, int], float] = {}
+        # batches the host oracle answered (any cause); read by
+        # chip_smoke.py, which fails the run unless it stays 0
+        self.n_fallback_batches = 0
         self._m_failures = None
         self._m_fallback_batches = None
         self._m_open = None
@@ -619,6 +622,8 @@ class DeviceFallbackEngine:
     def _fallback_check(
         self, requests, max_depth, depths, deadlines=None
     ) -> list:
+        with self._lock:
+            self.n_fallback_batches += 1
         if self._m_fallback_batches is not None:
             self._m_fallback_batches.inc()
         if deadlines is not None:

@@ -10,7 +10,7 @@ same failure contract:
 
 The cursor is base64url(compact-JSON) of ``{"k": kind, "v": version, ...}``
 plus engine-specific payload keys. Keeping the mint/validate pair here (one
-wire format, one taxonomy) is what lets a list token presented to the expand
+wire format, one error scheme) is what lets a list token presented to the expand
 endpoint — or vice versa — fail typed instead of resuming garbage work.
 """
 

@@ -10,9 +10,10 @@ On TPU the step runs as a Pallas kernel that fuses the MXU tile matmul with
 the reached-mask compare/select in VMEM — one pass over the adjacency tiles
 per step instead of matmul + three elementwise kernels bouncing [G, M]
 intermediates through HBM. Everywhere else (CPU CI, GPU) the same math runs
-as a lax fallback (`_masked_step_lax`) so the builder is platform-complete;
+as a lax step (`_masked_step_lax`) so the builder is platform-complete;
 the two are numerically identical (0/1 masks, f32 accumulation, 0.5
-threshold).
+threshold). The step is chosen from the platform up front; a Pallas
+failure on a TPU raises — it is never papered over with the lax step.
 
 Masks live as bfloat16 0/1 rather than bool: the MXU consumes bf16 tiles
 directly and counts up to the 16k interior limit are exact in the f32
@@ -25,7 +26,6 @@ rows INF) — fuzz-enforced by tests/test_semiring.py.
 
 from __future__ import annotations
 
-import logging
 from functools import partial
 
 import jax
@@ -40,18 +40,11 @@ from ..ops.closure import INF_DIST
 _TG = 128
 _TM = 128
 
-_pallas_broken = False  # flipped on first trace/runtime failure
 
-
-def pallas_available() -> bool:
-    """True when the default backend is a TPU and Pallas has not already
-    failed once this process (tracing errors permanently demote to lax)."""
-    if _pallas_broken:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def semiring_step() -> str:
+    """The masked-SpMV step the builder runs on this platform: "pallas" on
+    a TPU, "lax" everywhere else."""
+    return "pallas" if jax.default_backend() == "tpu" else "lax"
 
 
 def _masked_step_lax(frontier, adj, reached):
@@ -86,7 +79,7 @@ def _masked_step_pallas(frontier, adj, reached):
     ]
     tile = pl.BlockSpec(
         (_TG, _TM),
-        lambda i, j: (i * _TG, j * _TM),
+        lambda i, j: (i, j),
         memory_space=pltpu.VMEM,
     )
     newly, reach = pl.pallas_call(
@@ -95,11 +88,11 @@ def _masked_step_pallas(frontier, adj, reached):
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (_TG, m), lambda i, j: (i * _TG, 0),
+                (_TG, m), lambda i, j: (i, 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (m, _TM), lambda i, j: (0, j * _TM),
+                (m, _TM), lambda i, j: (0, j),
                 memory_space=pltpu.VMEM,
             ),
             tile,
@@ -149,27 +142,16 @@ def _build_closure_semiring(
 
 
 def build_closure_semiring(packed, m, *, m_pad, k_max, group=256):
-    """Device semiring closure build. Prefers the fused Pallas kernel on
-    TPU, transparently demoting to the lax step (same math) if Pallas
-    tracing/compilation fails — the builder must never take the serving
-    path down with it."""
-    global _pallas_broken
+    """Device semiring closure build: the fused Pallas step on a TPU, the
+    lax step elsewhere (`semiring_step`)."""
+    use_pallas = semiring_step() == "pallas"
     grp = group
     while m_pad % grp:
         grp //= 2  # m_pad is a multiple of 256 upstream; be safe anyway
-    if pallas_available() and grp % _TG == 0:
-        try:
-            return _build_closure_semiring(
-                packed, m, m_pad=m_pad, k_max=k_max, group=grp,
-                use_pallas=True,
-            )
-        except Exception:
-            _pallas_broken = True
-            logging.getLogger("keto.engine").warning(
-                "pallas masked-SpMV kernel failed to build; "
-                "demoting semiring builder to the lax step",
-                exc_info=True,
-            )
+    if use_pallas and grp % _TG:
+        raise ValueError(
+            f"m_pad={m_pad} is not a multiple of the Pallas row tile {_TG}"
+        )
     return _build_closure_semiring(
-        packed, m, m_pad=m_pad, k_max=k_max, group=grp, use_pallas=False
+        packed, m, m_pad=m_pad, k_max=k_max, group=grp, use_pallas=use_pallas
     )

@@ -18,12 +18,11 @@ with F0 = interior successors of the start node, L = interior in-neighbors
 of the target (or the target itself when it is a set), extra = 1 for
 subject-id targets (the final s' -> target hop), 0 for set targets.
 
-Transfer discipline: host<->device hops can be expensive (PCIe at best, a
-network tunnel at worst), so the adjacency ships BITPACKED (1 bit/edge-slot,
-8x smaller than uint8) and is expanded on device. The query-side gather
-exists in two forms: `closure_query` (jit, for devices with cheap
-dispatch) and the engine's host-side numpy twin for latency-dominated
-links (keto_tpu/engine/closure.py decides per deployment).
+Transfer discipline: host->device copies cross PCIe, so the adjacency ships
+BITPACKED (1 bit/edge-slot, 8x smaller than uint8) and is expanded on
+device. The query-side gather exists in two forms: `closure_query` (jit,
+on an accelerator) and the engine's host-side twin for a CPU backend and
+forked read replicas (keto_tpu/engine/closure.py picks by platform).
 
 Shapes are static per (m_pad, k_max) — the closure build compiles once per
 snapshot width bucket. D's padding rows/columns stay at INF (255) so a

@@ -55,17 +55,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.6 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..engine.check import DEFAULT_MAX_DEPTH, CheckEngine
 from ..graph.interior import InteriorGraph, build_interior
 from ..graph.snapshot import GraphSnapshot, SnapshotManager
 from ..ops.closure import INF_DIST, build_closure_packed, pack_adjacency
 from ..relationtuple.definitions import RelationTuple, SubjectID, SubjectSet
-from .sharded import _SM_NOCHECK, make_mesh
+from .sharded import make_mesh
 
 
 def _stripe_csr(
@@ -246,7 +243,7 @@ def _sharded_closure_check(
             P("data"), P("data"), P("data"), P("data"),
         ),
         out_specs=(P("data"), P("data")),
-        **_SM_NOCHECK,
+        check_vma=False,
     )(
         d, f0_indptr, f0_vals, l_indptr, l_vals, int_idx,
         out_indptr, out_vals,

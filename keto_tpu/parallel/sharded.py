@@ -29,20 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.6 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# jax 0.6 renamed check_rep -> check_vma; probe which spelling this jax
-# takes so the replication check stays off under either API
-_SM_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
+from jax import shard_map
 
 from ..engine.check import DEFAULT_MAX_DEPTH
 from ..graph.snapshot import GraphSnapshot, SnapshotManager
@@ -132,7 +119,7 @@ def sharded_check(
         mesh=mesh,
         in_specs=(P("edge"), P("edge"), P("data"), P("data"), P("data")),
         out_specs=P("data"),
-        **_SM_NOCHECK,
+        check_vma=False,
     )(src, dst, start, target, depth)
 
 

@@ -3,7 +3,7 @@
 The reference surfaces errors through ory/herodot: every error carries an HTTP
 status, a gRPC code, and a JSON envelope ``{"error": {...}}`` (see reference
 internal/x and the herodot dependency in go.mod). We reproduce the same error
-taxonomy so REST/gRPC handlers can map domain failures to the exact wire
+classification so REST/gRPC handlers can map domain failures to the exact wire
 semantics (e.g. unknown namespace -> 404, malformed tuple -> 400).
 """
 

@@ -80,7 +80,7 @@ class TestRestClient:
         page = rest.get_relation_tuples(RelationQuery(namespace="videos"))
         assert [t.object for t in page.relation_tuples] == ["b"]
 
-    def test_error_taxonomy(self, rest):
+    def test_error_classes(self, rest):
         with pytest.raises(ErrNotFound):
             rest.create_relation_tuple("nope:x#r@u")  # unknown namespace
         with pytest.raises(ErrMalformedInput):

@@ -317,3 +317,84 @@ class TestDeviceView:
         eng.subject_is_allowed(t("n:o#r@alice"))  # forces _TooBig state
         with pytest.raises(RuntimeError):
             eng.device_view()
+
+
+class TestPlacementFollowsPlatform:
+    """query_mode=auto decides from the platform alone: device queries on
+    an accelerator, host queries when the backend is the CPU. No probe."""
+
+    @pytest.mark.parametrize(
+        "platform, host", [("cpu", True), ("tpu", False), ("gpu", False)]
+    )
+    def test_auto_mode(self, store, monkeypatch, platform, host):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        _, eng = make_engines(store, query_mode="auto")
+        assert eng.host_queries() is host
+
+    @pytest.mark.parametrize("mode, host", [("host", True), ("device", False)])
+    def test_explicit_mode_ignores_platform(self, store, monkeypatch, mode, host):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        _, eng = make_engines(store, query_mode=mode)
+        assert eng.host_queries() is host
+
+    @pytest.mark.parametrize(
+        "mode, kernel", [("host", "host-bitset"), ("device", "lax")]
+    )
+    def test_full_build_records_kernel_and_rows(self, store, mode, kernel):
+        store.write_relation_tuples(
+            t("n:o#r@(n:g#m)"), t("n:g#m@(n:h#m)"), t("n:h#m@alice")
+        )
+        oracle, eng = make_engines(store, query_mode=mode)
+        reqs = [t("n:o#r@alice"), t("n:o#r@bob")]
+        assert eng.batch_check(reqs) == oracle.batch_check(reqs) == [
+            True, False,
+        ]
+        assert eng.last_full_build_kernel == kernel
+        assert eng.n_oracle_rows == 0
+        assert eng.n_device_rows == (2 if mode == "device" else 0)
+
+
+class TestPallasStepNeverDemotes:
+    """On a TPU the semiring builder runs the Pallas step; a Pallas failure
+    raises instead of silently switching to the lax step."""
+
+    def test_step_follows_platform(self, monkeypatch):
+        import jax
+
+        from keto_tpu.engine import pallas_spmv
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert pallas_spmv.semiring_step() == "pallas"
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert pallas_spmv.semiring_step() == "lax"
+
+    def test_pallas_failure_raises(self, monkeypatch):
+        from keto_tpu.engine import pallas_spmv
+
+        calls = []
+
+        def broken(*a, use_pallas, **kw):
+            calls.append(use_pallas)
+            raise RuntimeError("mosaic lowering failed")
+
+        monkeypatch.setattr(pallas_spmv, "semiring_step", lambda: "pallas")
+        monkeypatch.setattr(pallas_spmv, "_build_closure_semiring", broken)
+        packed = np.zeros((256, 32), np.uint8)
+        with pytest.raises(RuntimeError, match="mosaic"):
+            pallas_spmv.build_closure_semiring(packed, 3, m_pad=256, k_max=4)
+        with pytest.raises(RuntimeError, match="mosaic"):
+            pallas_spmv.build_closure_semiring(packed, 3, m_pad=256, k_max=4)
+        assert calls == [True, True]  # never retried on the lax step
+
+    def test_misaligned_width_raises_on_tpu(self, monkeypatch):
+        from keto_tpu.engine import pallas_spmv
+
+        monkeypatch.setattr(pallas_spmv, "semiring_step", lambda: "pallas")
+        with pytest.raises(ValueError, match="Pallas row tile"):
+            pallas_spmv.build_closure_semiring(
+                np.zeros((72, 9), np.uint8), 3, m_pad=72, k_max=4
+            )

@@ -99,7 +99,7 @@ class TestClassification:
             ("something unrecognized went wrong", "transient"),
         ],
     )
-    def test_message_taxonomy(self, msg, kind):
+    def test_message_classification(self, msg, kind):
         assert classify_device_error(RuntimeError(msg)) == kind
 
     def test_injected_fault_sites(self):

@@ -297,3 +297,43 @@ class TestEngineParity:
         store = InMemoryTupleStore()
         with pytest.raises(ValueError):
             ClosureCheckEngine(SnapshotManager(store), builder="nope")
+
+
+class TestPallasStepInterpret:
+    """The fused Pallas masked-SpMV step (the TPU builder) run in Pallas
+    interpret mode on the CPU: byte-identical to the lax step and to the
+    dense oracle. Catches block-index mistakes no TPU compile can see."""
+
+    @pytest.fixture
+    def interpret(self, monkeypatch):
+        import functools
+
+        from jax.experimental import pallas as pl
+
+        monkeypatch.setattr(
+            pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True)
+        )
+
+    @pytest.mark.parametrize("seed", range(2))
+    def test_build_matches_lax_and_oracle(self, interpret, seed):
+        import jax.numpy as jnp
+
+        from keto_tpu.engine.pallas_spmv import _build_closure_semiring
+
+        rng = np.random.default_rng(seed)
+        m, m_pad, k_max = 200, 256, 4
+        src, dst = _rand_edges(rng, m, 600)
+        packed = jnp.asarray(pack_adjacency(src, dst, m_pad))
+        got = {
+            use: np.asarray(
+                _build_closure_semiring(
+                    packed, jnp.int32(m), m_pad=m_pad, k_max=k_max,
+                    group=256, use_pallas=use,
+                )
+            )
+            for use in (True, False)
+        }
+        np.testing.assert_array_equal(got[True], got[False])
+        np.testing.assert_array_equal(
+            got[True], _oracle(src, dst, m, m_pad, k_max)
+        )
