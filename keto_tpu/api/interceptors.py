@@ -10,6 +10,11 @@ interceptors at construction; this module provides the equivalent chain:
   histogram observation;
 - a tracing span wraps the handler, parenting any engine-phase spans the
   call produces;
+- each unary call's accounting ledger opens where gRPC hands the call to
+  this program (``intercept_service`` runs on gRPC's poller thread,
+  before the hand-off to the handler pool): the wait for a pool thread
+  and the message receipt land in ``pool_wait``, the request message
+  parse in ``deserialize``, and the check record adopts the ledger;
 - uncaught KetoError escaping a handler maps to its canonical status code
   (the servicers already map errors at the call site; the interceptor is
   the backstop that guarantees parity for any future handler).
@@ -21,6 +26,7 @@ import time
 
 import grpc
 
+from ..telemetry.attribution import TimeLedger, close_arrival, open_arrival
 from ..telemetry.tracing import TRACEPARENT_HEADER, parse_traceparent
 from ..utils.errors import KetoError
 
@@ -61,6 +67,7 @@ class TelemetryInterceptor(grpc.ServerInterceptor):
             )
 
     def intercept_service(self, continuation, handler_call_details):
+        t_call = time.perf_counter()
         handler = continuation(handler_call_details)
         if handler is None or not handler.unary_unary:
             # streaming handlers (health Watch, reflection) pass through
@@ -68,6 +75,17 @@ class TelemetryInterceptor(grpc.ServerInterceptor):
             return handler
         method = handler_call_details.method
         inner = handler.unary_unary
+        parse_request = handler.request_deserializer
+        # start and end of the request's deserialization, which gRPC runs
+        # on the pool thread just before the behavior
+        read: list = []
+
+        def deserialize(raw):
+            read.append(time.perf_counter())
+            try:
+                return raw if parse_request is None else parse_request(raw)
+            finally:
+                read.append(time.perf_counter())
         # W3C trace propagation: a client-minted traceparent on the
         # invocation metadata becomes the remote parent of the grpc.request
         # span, so the whole server-side span tree joins the caller's trace
@@ -79,6 +97,13 @@ class TelemetryInterceptor(grpc.ServerInterceptor):
 
         def wrapped(request, context):
             t0 = time.perf_counter()
+            ledger = TimeLedger(t_call)
+            if read:
+                ledger.mark("pool_wait", read[0])
+                ledger.mark("deserialize", read[1])
+            else:
+                ledger.mark("pool_wait", t0)
+            arrival = open_arrival(ledger)
             code = "OK"
             span = (
                 self.tracer.span("grpc.request", method=method, parent=remote)
@@ -112,10 +137,11 @@ class TelemetryInterceptor(grpc.ServerInterceptor):
                     pass
                 raise
             finally:
+                close_arrival(arrival)
                 self._observe(method, code, time.perf_counter() - t0)
 
         return grpc.unary_unary_rpc_method_handler(
             wrapped,
-            request_deserializer=handler.request_deserializer,
+            request_deserializer=deserialize,
             response_serializer=handler.response_serializer,
         )

@@ -57,7 +57,6 @@ from typing import Optional, Sequence
 from ..faults import FAULTS
 from ..relationtuple.definitions import RelationTuple
 from ..telemetry.attribution import current_ledger, ledger_mark
-from ..telemetry.devstats import DEVSTATS
 from ..telemetry.metrics import (
     deadline_expired_counter,
     pipeline_stage_histogram,
@@ -1068,7 +1067,6 @@ class CheckBatcher:
     def _observe(self, stage: str, seconds: float) -> None:
         if self._m_stage is not None:
             self._m_stage.labels(stage=stage).observe(seconds)
-        DEVSTATS.record_stage(stage, seconds)
 
     @staticmethod
     def _batch_parent(items):

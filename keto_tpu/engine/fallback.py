@@ -53,6 +53,7 @@ from typing import Optional, Sequence
 
 from ..faults import FaultInjected
 from ..relationtuple.definitions import RelationTuple
+from .stages import engine_stage
 
 _COOLDOWN_CAP_S = 60.0
 #: every open window is stretched by up to this fraction of itself
@@ -388,11 +389,15 @@ class DeviceFallbackEngine:
             except Exception as e:
                 self._note_failure(e)
                 return self._fallback_check(requests, max_depth, depths)
-            if not _valid_batch(results, len(requests)):
+            with engine_stage("finish"):
+                valid = _valid_batch(results, len(requests))
+                if valid:
+                    self._record_success()
+                    results = [bool(v) for v in results]
+            if not valid:
                 self._record_failure(None)
                 return self._fallback_check(requests, max_depth, depths)
-            self._record_success()
-            return [bool(v) for v in results]
+            return results
         return self._fallback_check(requests, max_depth, depths)
 
     # -- pipelined surface (encode/launch/decode split) ------------------------
@@ -610,13 +615,17 @@ class DeviceFallbackEngine:
                 return self._fallback_check(
                     cols.materialize(), max_depth, depths
                 )
-            if not _valid_batch(results, n):
+            with engine_stage("finish"):
+                valid = _valid_batch(results, n)
+                if valid:
+                    self._record_success()
+                    results = [bool(v) for v in results]
+            if not valid:
                 self._record_failure(None)
                 return self._fallback_check(
                     cols.materialize(), max_depth, depths
                 )
-            self._record_success()
-            return [bool(v) for v in results]
+            return results
         return self._fallback_check(cols.materialize(), max_depth, depths)
 
     def _fallback_check(

@@ -1,9 +1,7 @@
 """Wall-clock accounting: where every microsecond of a check goes.
 
-The serving stack runs ~5.75M checks/s on-device but ~419k/s over the
-wire (BENCH_r05 serving_overhead ~= 13x). Spans show *shape* but not
-*conservation*: nothing guaranteed the per-stage numbers summed to the
-wall time a caller saw, so "13x" could hide anywhere. This module makes
+Spans show *shape* but not *conservation*: nothing guarantees that
+per-stage numbers sum to the wall time a caller saw. This module makes
 time a conserved quantity:
 
 - ``TimeLedger`` — a per-request monotonic timestamp ledger. Each
@@ -15,6 +13,13 @@ time a conserved quantity:
   ledger argument through every call. On the pipelined path, where the
   request hops threads, the ledger rides the batch entry tuple instead
   and stage loops mark it directly.
+- arrival ledgers — the gRPC interceptor opens a call's ledger where
+  gRPC hands the call to the program (``open_arrival``) and the check
+  record adopts it (``take_arrival_ledger``), so a served call is
+  accounted from its hand-off, not from the servicer's first line.
+- ``ENGINE_STAGES`` — the closure engine's stages; ``engine/stages.py``
+  marks each as both a ledger stage and a host span ``keto.<stage>`` in
+  the JAX profiler's trace, beside the device ops it leads to.
 - ``AttributionLedger`` — process-wide aggregation: per-stage seconds,
   total wall, request count, and the conservation ratio. Anything the
   marks did not cover lands in the explicit ``unattributed`` stage, so
@@ -23,12 +28,30 @@ time a conserved quantity:
   ``/debug/attribution`` and gated in ``bench.py --smoke`` (coverage
   must stay >= 0.95).
 
-Stage vocabulary (flow order): admission (transport handling up to the
-batcher), queue (admission-queue wait), encode (vocab probe + encode +
-encoded-cache probe), launch (launch-queue wait + async kernel enqueue),
-kernel (block-until-materialized on device), decode (result decode +
-cache population + future resolution), serialize (response body build),
-reply (everything after the body until the telemetry record closes).
+Stage vocabulary (flow order):
+
+- arrival (gRPC only): pool_wait (gRPC's hand-off to a pool thread,
+  waiting for that thread or the interpreter, message receipt),
+  deserialize (request message parse), parse (servicer work before the
+  check record: column decode, freshness wait, traceparent);
+- admission (transport handling up to the batcher), queue
+  (admission-queue wait), encode (vocab probe + encode + encoded-cache
+  probe), launch (launch-queue wait + async kernel enqueue);
+- closure engine (``ENGINE_STAGES``): lookup (vocabulary encode), gather
+  (sort, clamps, direct-edge probe, degree split, padded-row gathers;
+  the host query twin's D gather), h2d (operand copies to the device),
+  dispatch (the ``closure_query`` call returning), device_wait (blocking
+  readback of its result), oracle (host-oracle answers of overflow
+  rows), finish (overlay, reorder, list conversion, the circuit
+  breaker's check of the answers);
+- kernel (block-until-materialized on device; on the closure engine
+  path, the engine call's residue outside its named stages), decode
+  (result decode + cache population + future resolution), serialize
+  (response body build), reply (everything after the body until the
+  telemetry record closes).
+
+Each stage's seconds run from the previous mark to its own, so a stage
+also holds any unnamed stretch just before it.
 """
 
 from __future__ import annotations
@@ -38,11 +61,29 @@ import threading
 import time
 from typing import Optional
 
+#: closure-engine stages of one query, in flow order; each is also the
+#: host span ``keto.<stage>`` in the profiler's trace
+#: (``engine.stages.engine_stage``)
+ENGINE_STAGES = (
+    "lookup",
+    "gather",
+    "h2d",
+    "dispatch",
+    "device_wait",
+    "oracle",
+    "finish",
+)
+
+#: gRPC arrival stages, marked before the check record opens
+ARRIVAL_STAGES = ("pool_wait", "deserialize", "parse")
+
 ATTRIBUTION_STAGES = (
+    *ARRIVAL_STAGES,
     "admission",
     "queue",
     "encode",
     "launch",
+    *ENGINE_STAGES,
     "kernel",
     "decode",
     "serialize",
@@ -56,6 +97,11 @@ UNATTRIBUTED = "unattributed"
 
 _current_ledger: contextvars.ContextVar[Optional["TimeLedger"]] = (
     contextvars.ContextVar("keto_tpu_ledger", default=None)
+)
+# the ledger a transport opened at a call's arrival, until a check
+# record adopts it
+_arrival_ledger: contextvars.ContextVar[Optional["TimeLedger"]] = (
+    contextvars.ContextVar("keto_tpu_arrival_ledger", default=None)
 )
 
 
@@ -108,6 +154,26 @@ def ledger_mark(stage: str) -> None:
         led.mark(stage)
 
 
+def open_arrival(ledger: TimeLedger):
+    """Offer ``ledger`` (opened where the transport received the call)
+    to the check record this call will open; returns the reset token,
+    which the transport passes to ``close_arrival`` when the call ends."""
+    return _arrival_ledger.set(ledger)
+
+
+def close_arrival(token) -> None:
+    _arrival_ledger.reset(token)
+
+
+def take_arrival_ledger() -> Optional[TimeLedger]:
+    """The calling context's arrival ledger, at most once: the first
+    check record to ask adopts it."""
+    led = _arrival_ledger.get()
+    if led is not None:
+        _arrival_ledger.set(None)
+    return led
+
+
 class AttributionLedger:
     """Aggregates finished TimeLedgers into a process-wide breakdown.
 
@@ -124,6 +190,9 @@ class AttributionLedger:
         self._requests = 0
         self._entries = 0
         self._counter = None
+        # stage -> the counter's labeled child: one dict probe per stage
+        # per request instead of a locked label lookup
+        self._children: dict = {}
         if metrics is not None:
             from .metrics import time_attribution_counter
 
@@ -151,9 +220,15 @@ class AttributionLedger:
             self._entries += max(1, int(batch_size))
         if self._counter is not None:
             for stage, dt in ledger.stages.items():
-                self._counter.labels(stage=stage).inc(dt)
+                self._child(stage).inc(dt)
             if residual > 0:
-                self._counter.labels(stage=UNATTRIBUTED).inc(residual)
+                self._child(UNATTRIBUTED).inc(residual)
+
+    def _child(self, stage: str):
+        child = self._children.get(stage)
+        if child is None:
+            child = self._children[stage] = self._counter.labels(stage=stage)
+        return child
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -1,16 +1,17 @@
 """Device telemetry: per-device HBM, jit compilation events, host<->device
-transfer bytes, per-stage kernel wall time, and the graph panel.
+transfer bytes, and the graph panel.
 
 One process-wide collector (``DEVSTATS``) because the tally points live
-deep in the engine hot path (batcher stage observer, device-engine
-staging buffers) where threading a registry handle through every
+deep in the engine hot path (device-engine staging buffers, the closure
+engine's query operands) where threading a registry handle through every
 constructor would couple the engine layer to telemetry wiring. The
 driver registry calls ``DEVSTATS.bind(metrics, graph_panel_fn=...)``
 when it builds its MetricsRegistry; ``bind`` is re-entrant — tests build
 many registries per process and each bind simply repoints the exported
-counters/gauges at the newest one. Tallies (transfer bytes, stage
-seconds, compile counts) accumulate for the life of the process, which
-is exactly what a ``_total`` counter wants.
+counters/gauges at the newest one. Tallies (transfer bytes, compile
+counts) accumulate for the life of the process, which is exactly what a
+``_total`` counter wants. Per-stage check-pipeline seconds are host time
+and live in ``keto_pipeline_stage_seconds`` (engine/batcher.py).
 
 HBM gauges sample ``jax.local_devices()[i].memory_stats()`` at scrape
 time; on CPU backends that returns ``None`` and the gauges read 0 —
@@ -66,14 +67,14 @@ class DeviceStatsCollector:
     def __init__(self):
         self._lock = threading.Lock()
         self._transfer_bytes = {"h2d": 0.0, "d2h": 0.0}
-        self._stage_seconds: dict[str, float] = {}
         self._compiles = 0
         self._compile_seconds = 0.0
         self._graph_panel_fn = None
         self._listener_installed = False
         # metric handles from the most recent bind(); None before any
         self._c_transfer = None
-        self._c_kernel = None
+        # direction -> the transfer counter's labeled child
+        self._transfer_children: dict = {}
         self._c_compiles = None
         self._c_compile_s = None
 
@@ -89,11 +90,7 @@ class DeviceStatsCollector:
             "host<->device bytes staged by the check engines",
             labelnames=("direction",),
         )
-        self._c_kernel = metrics.counter(
-            "keto_device_kernel_seconds_total",
-            "cumulative wall seconds spent in each check-pipeline stage",
-            labelnames=("stage",),
-        )
+        self._transfer_children = {}
         self._c_compiles = metrics.counter(
             "keto_device_jit_compilations_total",
             "jit compilation events observed via jax.monitoring",
@@ -108,9 +105,6 @@ class DeviceStatsCollector:
             for direction, nbytes in self._transfer_bytes.items():
                 if nbytes:
                     self._c_transfer.labels(direction=direction).inc(nbytes)
-            for stage, secs in self._stage_seconds.items():
-                if secs:
-                    self._c_kernel.labels(stage=stage).inc(secs)
             if self._compiles:
                 self._c_compiles.inc(self._compiles)
             if self._compile_seconds:
@@ -184,18 +178,24 @@ class DeviceStatsCollector:
             self._transfer_bytes[direction] = (
                 self._transfer_bytes.get(direction, 0.0) + nbytes
             )
-        c = self._c_transfer
-        if c is not None:
-            c.labels(direction=direction).inc(nbytes)
+        if self._c_transfer is not None:
+            self._transfer_child(direction).inc(nbytes)
 
-    def record_stage(self, stage: str, seconds: float) -> None:
+    def record_round_trip(self, h2d: int, d2h: int) -> None:
+        """One dispatch's operand and answer bytes, under one lock."""
         with self._lock:
-            self._stage_seconds[stage] = (
-                self._stage_seconds.get(stage, 0.0) + seconds
-            )
-        c = self._c_kernel
-        if c is not None:
-            c.labels(stage=stage).inc(seconds)
+            self._transfer_bytes["h2d"] += h2d
+            self._transfer_bytes["d2h"] += d2h
+        if self._c_transfer is not None:
+            self._transfer_child("h2d").inc(h2d)
+            self._transfer_child("d2h").inc(d2h)
+
+    def _transfer_child(self, direction: str):
+        child = self._transfer_children.get(direction)
+        if child is None:
+            child = self._c_transfer.labels(direction=direction)
+            self._transfer_children[direction] = child
+        return child
 
     def record_compile(self, seconds: float) -> None:
         with self._lock:
@@ -244,7 +244,6 @@ class DeviceStatsCollector:
                 graph = {}
         with self._lock:
             transfer = dict(self._transfer_bytes)
-            stages = {k: round(v, 6) for k, v in self._stage_seconds.items()}
             compiles = self._compiles
             compile_s = round(self._compile_seconds, 3)
         return {
@@ -252,7 +251,6 @@ class DeviceStatsCollector:
             "graph": graph,
             "devices": self.sample_devices(),
             "transfer_bytes": transfer,
-            "stage_seconds": stages,
             "jit_compilations": compiles,
             "jit_compile_seconds": compile_s,
         }

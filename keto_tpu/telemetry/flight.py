@@ -38,6 +38,7 @@ from .attribution import (
     TimeLedger,
     reset_current_ledger,
     set_current_ledger,
+    take_arrival_ledger,
 )
 from .metrics import DEFAULT_BUCKETS, MetricsRegistry
 from .tracing import Tracer, _current_span, parse_traceparent
@@ -372,8 +373,8 @@ class CheckTelemetry:
 class _CheckRecord:
     __slots__ = (
         "_tel", "transport", "batch_size", "deadline", "detail",
-        "_t0", "_span", "trace_id", "traceparent", "hedge", "ledger",
-        "_ledger_token",
+        "_t0", "_wall_t0", "_span", "trace_id", "traceparent", "hedge",
+        "ledger", "_ledger_token",
     )
 
     def __init__(
@@ -394,9 +395,19 @@ class _CheckRecord:
 
     def __enter__(self):
         self._t0 = time.perf_counter()
-        # the accounting ledger shares t0 with the wall clock so the
-        # conservation check (stages sum >= 95% of wall) is exact
-        self.ledger = TimeLedger(self._t0)
+        arrival = take_arrival_ledger()
+        if arrival is not None:
+            # the transport opened this call's ledger where it received
+            # the call (gRPC interceptor): the accounted wall runs from
+            # there, and the servicer's work since its last mark (column
+            # decode, freshness wait, traceparent) is 'parse'
+            arrival.mark("parse", self._t0)
+            self.ledger = arrival
+        else:
+            # the accounting ledger shares t0 with the wall clock so the
+            # conservation check (stages sum >= 95% of wall) is exact
+            self.ledger = TimeLedger(self._t0)
+        self._wall_t0 = self.ledger.t0
         self._ledger_token = set_current_ledger(self.ledger)
         remote = (
             parse_traceparent(self.traceparent)
@@ -431,7 +442,10 @@ class _CheckRecord:
             self.ledger.mark(stage)
 
     def __exit__(self, exc_type, exc, tb):
-        duration_s = time.perf_counter() - self._t0
+        end = time.perf_counter()
+        # the latency histogram and flight record time the servicer seam;
+        # the ledger's wall runs from the call's arrival when it had one
+        duration_s = end - self._t0
         outcome = self._tel._classify(exc_type)
         detail = self.detail
         if self.ledger is not None:
@@ -444,7 +458,7 @@ class _CheckRecord:
                 self._ledger_token = None
             if self._tel.attribution is not None:
                 self._tel.attribution.record(
-                    self.ledger, duration_s, self.batch_size
+                    self.ledger, end - self._wall_t0, self.batch_size
                 )
             if self.ledger.stages:
                 detail = dict(detail or ())

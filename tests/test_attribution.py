@@ -450,3 +450,289 @@ class TestFlameTool:
         assert folds
         html = flame.render_html(flame.build_tree(folds))
         assert "<svg" in html
+
+
+# -- a served BatchCheck, accounted from gRPC's hand-off to the reply -------
+
+_ENGINE_STAGES_SEEN = ("lookup", "gather", "h2d", "dispatch", "device_wait",
+                       "finish")
+_ARRIVAL = ("pool_wait", "deserialize", "parse")
+# users in more groups than the closure query's row width (l_max 32) take
+# the host oracle
+_WIDE_GROUPS = 40
+
+
+def _closure_store_tuples():
+    from keto_tpu.relationtuple import RelationTuple
+
+    rows = [f"groups:g{i}#member@alice" for i in range(_WIDE_GROUPS)]
+    rows += [
+        f"docs:d0#view@(groups:g{i}#member)" for i in range(_WIDE_GROUPS)
+    ]
+    rows += [
+        "groups:g0#member@bob",
+        "docs:d1#view@carol",
+        "docs:d2#view@(groups:g0#member)",
+    ]
+    return [RelationTuple.from_string(r) for r in rows]
+
+
+@pytest.fixture(scope="module")
+def closure_server():
+    """A read plane over the closure engine with queries on the device
+    (JAX's CPU device here), holding one user too wide for the query."""
+    cfg = Config(
+        values={
+            "namespaces": [
+                {"id": 1, "name": "groups"},
+                {"id": 2, "name": "docs"},
+            ],
+            "serve": {
+                "read": {"port": 0, "host": "127.0.0.1"},
+                "write": {"port": 0, "host": "127.0.0.1"},
+            },
+            "log": {"level": "error"},
+            "engine": {"mode": "closure", "query_mode": "device"},
+        },
+        env={},
+    )
+    from keto_tpu.driver import Registry
+
+    registry = Registry(cfg)
+    # loaded before boot, so the boot's closure holds the tuples (writes
+    # after it would be answered by the write overlay)
+    registry.store().write_relation_tuples(*_closure_store_tuples())
+    s = ServerFixture(registry)
+    yield s
+    s.stop()
+
+
+def _columnar_batch_check(port: int, rows) -> list:
+    """One columnar BatchCheck of ``(namespace, object, relation,
+    subject_id)`` rows over gRPC."""
+    from keto_tpu.api import check_service_pb2
+    from keto_tpu.client import GrpcClient
+
+    req = check_service_pb2.BatchCheckRequest(
+        namespaces=[r[0] for r in rows],
+        objects=[r[1] for r in rows],
+        relations=[r[2] for r in rows],
+        subject_ids=[r[3] for r in rows],
+    )
+    with GrpcClient(f"127.0.0.1:{port}") as g:
+        return list(g.check_service.BatchCheck(req).allowed)
+
+
+def _stage_window(server, send) -> tuple[dict, dict]:
+    """Stage seconds the ledger gained while ``send()`` ran, and the
+    window's snapshot totals (requests, wall, attributed)."""
+    att = server.registry.attribution()
+    before = att.snapshot()
+    send()
+    after = att.snapshot()
+    stages = {
+        k: v["seconds"] - before["stages"].get(k, {}).get("seconds", 0.0)
+        for k, v in after["stages"].items()
+    }
+    totals = {
+        k: after[k] - before[k]
+        for k in ("requests", "wall_s", "attributed_s")
+    }
+    return stages, totals
+
+
+class TestServedBatchCheckStages:
+    def test_stages_from_arrival_to_reply(self, closure_server):
+        port = closure_server.read_port
+        # warm-up: the first call builds the closure and compiles
+        assert _columnar_batch_check(
+            port, [("docs", "d2", "view", "bob")]
+        ) == [True]
+        windows = []
+        for i in range(7):
+            # fresh subjects each call, so the result cache misses; 128
+            # rows, one query program shape for every call
+            batch = [("docs", "d2", "view", "bob")] + [
+                ("docs", f"d{1 + j % 2}", "view", f"nobody{j}-{i}")
+                for j in range(127)
+            ]
+            windows.append(_stage_window(
+                closure_server,
+                lambda: _columnar_batch_check(port, batch),
+            ))
+        residue = []
+        for stages, totals in windows:
+            assert totals["requests"] == 1
+            for s in _ARRIVAL + _ENGINE_STAGES_SEEN + ("admission",):
+                assert stages.get(s, 0.0) > 0, (s, stages)
+            assert all(v >= 0 for v in stages.values())
+            assert stages.get("oracle", 0.0) == 0
+            engine = sum(stages[s] for s in _ENGINE_STAGES_SEEN)
+            residue.append(stages.get("kernel", 0.0) / engine)
+        wall = sum(t["wall_s"] for _, t in windows)
+        assert sum(t["attributed_s"] for _, t in windows) / wall >= 0.95
+        # the batcher's trailing mark holds only the engine call's
+        # residue (median call: a preempted call may read more)
+        assert sorted(residue)[len(residue) // 2] < 0.01, residue
+
+    def test_overflow_row_shows_oracle(self, closure_server):
+        port = closure_server.read_port
+
+        def send():
+            assert _columnar_batch_check(
+                port,
+                [("docs", "d0", "view", "alice"),
+                 ("docs", "d2", "view", "bob"),
+                 ("docs", "d1", "view", "carol"),
+                 ("docs", "d1", "view", "dave")],
+            ) == [True, True, True, False]
+
+        eng = closure_server.registry.check_engine()
+        oracle_rows = getattr(eng, "primary", eng).n_oracle_rows
+        stages, _ = _stage_window(closure_server, send)
+        assert stages.get("oracle", 0.0) > 0, stages
+        assert getattr(eng, "primary", eng).n_oracle_rows == oracle_rows + 1
+        for s in _ENGINE_STAGES_SEEN:
+            assert stages.get(s, 0.0) > 0, (s, stages)
+
+    def test_device_query_counts_its_transfers(self, closure_server):
+        from keto_tpu.telemetry.devstats import DEVSTATS
+
+        def exported():
+            text = closure_server.registry.metrics().expose()
+            return {
+                d: float(v)
+                for d, v in re.findall(
+                    r'keto_device_transfer_bytes_total\{direction="(\w+)"\}'
+                    r" (\S+)",
+                    text,
+                )
+            }
+
+        port = closure_server.read_port
+        batch = [("docs", "d1", "view", f"counted{j}") for j in range(128)]
+        before, before_x = DEVSTATS.panel()["transfer_bytes"], exported()
+        assert _columnar_batch_check(port, batch) == [False] * 128
+        after, after_x = DEVSTATS.panel()["transfer_bytes"], exported()
+        # operands: two int32 index blocks, extra and depth (int32) and
+        # direct (bool) of 128 rows; answers: one per padded row
+        assert after["h2d"] - before["h2d"] >= 128 * (4 + 4 + 4 + 4 + 1)
+        assert after["d2h"] - before["d2h"] >= 128
+        for d in ("h2d", "d2h"):
+            assert after_x[d] - before_x.get(d, 0.0) == pytest.approx(
+                after[d] - before[d]
+            )
+
+
+class TestArrivalLedger:
+    def test_record_adopts_the_arrival_ledger_once(self):
+        from keto_tpu.telemetry.attribution import (
+            close_arrival,
+            open_arrival,
+            take_arrival_ledger,
+        )
+        from keto_tpu.telemetry.flight import CheckTelemetry
+
+        agg = AttributionLedger()
+        tel = CheckTelemetry(attribution=agg)
+        t_call = time.perf_counter() - 1e-3
+        arrival = TimeLedger(t_call)
+        arrival.mark("pool_wait", t_call + 1e-4)
+        arrival.mark("deserialize", t_call + 2e-4)
+        token = open_arrival(arrival)
+        try:
+            with tel.record_check("grpc_batch", batch_size=3) as rec:
+                assert rec.ledger is arrival
+                assert current_ledger() is arrival
+                # admission starts where the record opened, as before
+                assert arrival.last == rec._t0
+                assert arrival.stages["parse"] == pytest.approx(
+                    rec._t0 - (t_call + 2e-4)
+                )
+                ledger_mark("admission")
+            # adopted once: a second record on the call opens its own
+            assert take_arrival_ledger() is None
+        finally:
+            close_arrival(token)
+        snap = agg.snapshot()
+        # the accounted wall runs from the arrival
+        assert snap["wall_s"] >= 2e-4
+        assert list(snap["stages"])[:4] == [
+            "pool_wait", "deserialize", "parse", "admission",
+        ]
+
+    def test_record_without_arrival_keeps_its_own_ledger(self):
+        from keto_tpu.telemetry.flight import CheckTelemetry
+
+        tel = CheckTelemetry(attribution=AttributionLedger())
+        with tel.record_check("rest") as rec:
+            assert rec.ledger.t0 == rec._t0
+            assert not rec.ledger.stages
+
+
+class TestEngineStageSpans:
+    def test_trace_names_and_ledger_stages_agree(self):
+        from keto_tpu.engine.stages import TRACE_NAMES, engine_stage
+        from keto_tpu.telemetry.attribution import ENGINE_STAGES
+
+        assert set(ENGINE_STAGES) <= set(ATTRIBUTION_STAGES)
+        assert [s for s in ATTRIBUTION_STAGES if s in ENGINE_STAGES] == list(
+            ENGINE_STAGES
+        )
+        assert TRACE_NAMES == {s: f"keto.{s}" for s in ENGINE_STAGES}
+        led = TimeLedger()
+        token = set_current_ledger(led)
+        try:
+            for s in ENGINE_STAGES:
+                with engine_stage(s):
+                    pass
+        finally:
+            reset_current_ledger(token)
+        assert set(led.stages) <= set(ENGINE_STAGES)
+        with pytest.raises(KeyError):
+            engine_stage("not_a_stage")
+
+    def test_profiler_trace_holds_the_engine_stages(self, tmp_path):
+        """With a profiler session on, each engine stage the ledger marks
+        is a ``keto.<stage>`` host event in the trace."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from keto_tpu.engine.closure import ClosureCheckEngine
+        from keto_tpu.graph import SnapshotManager
+        from keto_tpu.relationtuple.columns import CheckColumns
+        from keto_tpu.store import InMemoryTupleStore
+        from keto_tpu.telemetry.attribution import ENGINE_STAGES
+
+        store = InMemoryTupleStore()
+        store.write_relation_tuples(*_closure_store_tuples())
+        eng = ClosureCheckEngine(
+            SnapshotManager(store), max_depth=5, query_mode="device"
+        )
+        cols = CheckColumns(
+            ["docs"] * 3, ["d0", "d0", "d1"], ["view"] * 3,
+            ["alice", "bob", "nobody"],
+        ).validate()
+        assert eng.batch_check_columns(cols) == [True, True, False]  # warm
+        led = TimeLedger()
+        token = set_current_ledger(led)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert eng.batch_check_columns(cols) == [True, True, False]
+        finally:
+            jax.profiler.stop_trace()
+            reset_current_ledger(token)
+        (path,) = glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+        )
+        names = {
+            e.name
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for e in line.events
+            if e.name.startswith("keto.")
+        }
+        assert set(led.stages) == set(ENGINE_STAGES)
+        assert names == {f"keto.{s}" for s in ENGINE_STAGES}
