@@ -701,7 +701,8 @@ class CheckBatcher:
                 else cols.select(range(i, min(i + step, n)))
             )
             if run is not None:
-                out.extend(bool(v) for v in run(chunk, max_depth))
+                # the engines' columnar answers are already a list of bools
+                out.extend(run(chunk, max_depth))
             else:
                 out.extend(
                     bool(v)

@@ -103,6 +103,13 @@ def _bucket_pow2(n: int, minimum: int = _MIN_BATCH) -> int:
     return _bucket(n, minimum)
 
 
+def _padded(a: np.ndarray, b: int, fill, dtype) -> np.ndarray:
+    """``a`` as a ``dtype`` vector of ``b`` rows, the rows past it ``fill``."""
+    out = np.full(b, fill, dtype)
+    out[: len(a)] = a
+    return out
+
+
 def _bucket_mult(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
 
@@ -1436,6 +1443,10 @@ class ClosureCheckEngine:
         """Gather + closure query for one fan-out class of rows. Returns
         (allowed, overflow) for the subset."""
         n = len(start)
+        host = art.d_host is not None
+        # the device program's rows: a power-of-two bucket, built at that
+        # size with the padding rows inert (PAD indices, depth 1, no edge)
+        b = n if host else _bucket_pow2(n)
         with engine_stage("gather"):
             # adaptive row widths: pad to this subset's max degree
             # (pow2-bucketed for jit-shape stability), capped at
@@ -1445,42 +1456,35 @@ class ClosureCheckEngine:
             )
             l_w = self._adaptive_width(ig.id_in_indptr, target, self.l_max)
             f0, f0_over = gather_padded_rows(
-                ig.set_out_indptr, ig.set_out_vals, start, f0_w, art.pad
+                ig.set_out_indptr, ig.set_out_vals, start, f0_w, art.pad, b
             )
-            l_id, l_over = gather_padded_rows(
-                ig.id_in_indptr, ig.id_in_vals, target, l_w, art.pad
+            l, l_over = gather_padded_rows(
+                ig.id_in_indptr, ig.id_in_vals, target, l_w, art.pad, b
             )
             # set targets: L = {target} when the target is itself interior
-            l = l_id
-            set_rows = ~is_id
-            if set_rows.any():
+            set_rows = np.flatnonzero(~is_id)
+            if set_rows.size:
                 t_int = ig.interior_index[target[set_rows]]
-                l = l_id.copy()
                 l[set_rows] = art.pad
                 l[set_rows, 0] = np.where(t_int >= 0, t_int, art.pad)
             l_over &= is_id  # set-target rows never overflow
             over = f0_over | l_over
 
-            extra = is_id.astype(np.int32)
-            if art.d_host is not None:
+            if host:
                 # host twin of ops.closure.closure_query: same math, zero
                 # device round-trips (latency-bound links)
                 sub = art.d_host[f0[:, :, None], l[:, None, :]]
                 best = sub.min(axis=(1, 2)).astype(np.int32)
                 best[best >= INF_DIST] = 1 << 30  # INF never satisfies
-                total = 1 + best + extra
+                total = 1 + best + is_id
                 return (direct & (depth >= 1)) | (total <= depth), over
-            # the device program's rows: padded to a power-of-two bucket
-            operands = [f0, l, extra, depth, direct]
-            b = _bucket_pow2(n)
-            if b != n:
-                fills = (art.pad, art.pad, 0, 1, False)
-                operands = [
-                    np.concatenate(
-                        [a, np.full((b - n, *a.shape[1:]), fill, a.dtype)]
-                    )
-                    for a, fill in zip(operands, fills)
-                ]
+            operands = (
+                f0,
+                l,
+                _padded(is_id, b, 0, np.int32),
+                _padded(depth, b, 1, np.int32),
+                _padded(direct, b, False, np.bool_),
+            )
         return self._query(art, operands, n), over
 
     @staticmethod
@@ -1492,14 +1496,14 @@ class ClosureCheckEngine:
     # -- query kernels --------------------------------------------------------
 
     def _query(self, art, operands, n: int) -> np.ndarray:
-        """``closure_query`` on the device over the padded operands
-        (f0, l, extra, depth, direct) of ``n`` rows: copy them over, run,
-        read the answers back."""
+        """``closure_query`` on the device over the padded host operands
+        (f0, l, extra, depth, direct) of ``n`` rows: run, read the answers
+        back."""
         self._tally(device=n)
-        with engine_stage("h2d"):
-            on_device = [jnp.asarray(a) for a in operands]
         with engine_stage("dispatch"):
-            res = closure_query(art.d, *on_device)
+            # numpy operands go straight into the jitted call: jaxlib's C++
+            # dispatch copies them to the device along with the launch
+            res = closure_query(art.d, *operands)
         with engine_stage("device_wait"):
             out = np.asarray(res)
         DEVSTATS.record_round_trip(sum(a.nbytes for a in operands), out.nbytes)

@@ -35,6 +35,7 @@ with vectorized numpy — no Python per-edge loops.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 import numpy as np
 
@@ -313,13 +314,16 @@ def gather_padded_rows(
     rows: np.ndarray,
     width: int,
     pad: int,
+    out_rows: Optional[int] = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Gather CSR rows into a padded [n, width] matrix (vectorized).
 
-    Returns (padded int32[n, width], overflow bool[n]) where overflow marks
-    rows whose true degree exceeds `width` (callers route those to a
-    fallback engine rather than silently truncating).
+    Returns (padded int32[out_rows or n, width], overflow bool[n]) where
+    overflow marks rows whose true degree exceeds `width` (callers route
+    those to a fallback engine rather than silently truncating). Rows past
+    n (``out_rows`` > n, a batch bucket's padding) are all `pad`.
     """
+    n = len(rows)
     rows = rows.astype(np.int64)
     off = indptr[rows]
     deg = indptr[rows + 1] - off
@@ -327,7 +331,8 @@ def gather_padded_rows(
     j = np.arange(width, dtype=np.int64)[None, :]
     idx = off[:, None] + j
     valid = j < np.minimum(deg, width)[:, None]
-    out = np.full((len(rows), width), pad, dtype=np.int32)
+    out = np.full((n if out_rows is None else out_rows, width), pad,
+                  dtype=np.int32)
     if vals.size:
-        np.copyto(out, vals[np.minimum(idx, vals.size - 1)], where=valid)
+        np.copyto(out[:n], vals[np.minimum(idx, vals.size - 1)], where=valid)
     return out, overflow

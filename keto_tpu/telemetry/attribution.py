@@ -39,11 +39,11 @@ Stage vocabulary (flow order):
   probe), launch (launch-queue wait + async kernel enqueue);
 - closure engine (``ENGINE_STAGES``): lookup (vocabulary encode), gather
   (sort, clamps, direct-edge probe, degree split, padded-row gathers;
-  the host query twin's D gather), h2d (operand copies to the device),
-  dispatch (the ``closure_query`` call returning), device_wait (blocking
-  readback of its result), oracle (host-oracle answers of overflow
-  rows), finish (overlay, reorder, list conversion, the circuit
-  breaker's check of the answers);
+  the host query twin's D gather), dispatch (the ``closure_query`` call
+  with its host operands returning: their copy to the device and the
+  launch), device_wait (blocking readback of its result), oracle
+  (host-oracle answers of overflow rows), finish (overlay, reorder, list
+  conversion, the circuit breaker's check of the answers);
 - kernel (block-until-materialized on device; on the closure engine
   path, the engine call's residue outside its named stages), decode
   (result decode + cache population + future resolution), serialize
@@ -67,7 +67,6 @@ from typing import Optional
 ENGINE_STAGES = (
     "lookup",
     "gather",
-    "h2d",
     "dispatch",
     "device_wait",
     "oracle",

@@ -1,5 +1,5 @@
 """Device telemetry: per-device HBM, jit compilation events, host<->device
-transfer bytes, and the graph panel.
+transfer bytes, closure-query dispatches, and the graph panel.
 
 One process-wide collector (``DEVSTATS``) because the tally points live
 deep in the engine hot path (device-engine staging buffers, the closure
@@ -67,6 +67,7 @@ class DeviceStatsCollector:
     def __init__(self):
         self._lock = threading.Lock()
         self._transfer_bytes = {"h2d": 0.0, "d2h": 0.0}
+        self._dispatches = 0
         self._compiles = 0
         self._compile_seconds = 0.0
         self._graph_panel_fn = None
@@ -75,6 +76,7 @@ class DeviceStatsCollector:
         self._c_transfer = None
         # direction -> the transfer counter's labeled child
         self._transfer_children: dict = {}
+        self._c_dispatches = None
         self._c_compiles = None
         self._c_compile_s = None
 
@@ -91,6 +93,10 @@ class DeviceStatsCollector:
             labelnames=("direction",),
         )
         self._transfer_children = {}
+        self._c_dispatches = metrics.counter(
+            "keto_device_query_dispatches_total",
+            "closure-query programs launched on the device",
+        )
         self._c_compiles = metrics.counter(
             "keto_device_jit_compilations_total",
             "jit compilation events observed via jax.monitoring",
@@ -105,6 +111,8 @@ class DeviceStatsCollector:
             for direction, nbytes in self._transfer_bytes.items():
                 if nbytes:
                     self._c_transfer.labels(direction=direction).inc(nbytes)
+            if self._dispatches:
+                self._c_dispatches.inc(self._dispatches)
             if self._compiles:
                 self._c_compiles.inc(self._compiles)
             if self._compile_seconds:
@@ -182,11 +190,14 @@ class DeviceStatsCollector:
             self._transfer_child(direction).inc(nbytes)
 
     def record_round_trip(self, h2d: int, d2h: int) -> None:
-        """One dispatch's operand and answer bytes, under one lock."""
+        """One query dispatch: its count, operand and answer bytes, under
+        one lock."""
         with self._lock:
+            self._dispatches += 1
             self._transfer_bytes["h2d"] += h2d
             self._transfer_bytes["d2h"] += d2h
         if self._c_transfer is not None:
+            self._c_dispatches.inc()
             self._transfer_child("h2d").inc(h2d)
             self._transfer_child("d2h").inc(d2h)
 
@@ -234,7 +245,7 @@ class DeviceStatsCollector:
 
     def panel(self) -> dict:
         """The /debug/graph payload: graph shape + device samples +
-        lifetime transfer/compile tallies."""
+        lifetime transfer/dispatch/compile tallies."""
         graph = {}
         fn = self._graph_panel_fn
         if fn is not None:
@@ -244,6 +255,7 @@ class DeviceStatsCollector:
                 graph = {}
         with self._lock:
             transfer = dict(self._transfer_bytes)
+            dispatches = self._dispatches
             compiles = self._compiles
             compile_s = round(self._compile_seconds, 3)
         return {
@@ -251,6 +263,7 @@ class DeviceStatsCollector:
             "graph": graph,
             "devices": self.sample_devices(),
             "transfer_bytes": transfer,
+            "dispatches": dispatches,
             "jit_compilations": compiles,
             "jit_compile_seconds": compile_s,
         }

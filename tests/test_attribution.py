@@ -454,7 +454,7 @@ class TestFlameTool:
 
 # -- a served BatchCheck, accounted from gRPC's hand-off to the reply -------
 
-_ENGINE_STAGES_SEEN = ("lookup", "gather", "h2d", "dispatch", "device_wait",
+_ENGINE_STAGES_SEEN = ("lookup", "gather", "dispatch", "device_wait",
                        "finish")
 _ARRIVAL = ("pool_wait", "deserialize", "parse")
 # users in more groups than the closure query's row width (l_max 32) take
@@ -622,6 +622,24 @@ class TestServedBatchCheckStages:
             assert after_x[d] - before_x.get(d, 0.0) == pytest.approx(
                 after[d] - before[d]
             )
+
+    def test_device_query_counts_its_dispatches(self, closure_server):
+        from keto_tpu.telemetry.devstats import DEVSTATS
+
+        def exported():
+            text = closure_server.registry.metrics().expose()
+            m = re.search(r"^keto_device_query_dispatches_total (\S+)$",
+                          text, re.M)
+            return float(m.group(1)) if m else 0.0
+
+        port = closure_server.read_port
+        batch = [("docs", "d1", "view", f"dispatched{j}") for j in range(64)]
+        before, before_x = DEVSTATS.panel()["dispatches"], exported()
+        assert _columnar_batch_check(port, batch) == [False] * 64
+        after, after_x = DEVSTATS.panel()["dispatches"], exported()
+        # 64 narrow rows: one fan-out class, one closure_query dispatch
+        assert after - before == 1
+        assert after_x - before_x == after - before
 
 
 class TestArrivalLedger:

@@ -398,3 +398,90 @@ class TestPallasStepNeverDemotes:
             pallas_spmv.build_closure_semiring(
                 np.zeros((72, 9), np.uint8), 3, m_pad=72, k_max=4
             )
+
+
+class TestDeviceQueryHandOff:
+    """Device queries hand the host operands straight to the jitted
+    ``closure_query``: no Python-level copy to the device per dispatch."""
+
+    # alice is a member of more groups than the narrow gather width, so
+    # her rows take the wide fan-out class; the rest stay narrow
+    _WIDE = 12
+
+    def _store(self):
+        store = InMemoryTupleStore()
+        rows = [f"groups:g{i}#member@alice" for i in range(self._WIDE)]
+        rows += [
+            f"docs:d0#view@(groups:g{i}#member)" for i in range(self._WIDE)
+        ]
+        rows += [
+            "groups:g0#member@bob",
+            "groups:g1#member@(groups:g2#member)",
+            "docs:d1#view@carol",
+            "docs:d2#view@(groups:g1#member)",
+        ]
+        store.write_relation_tuples(*(t(r) for r in rows))
+        return store
+
+    def test_mixed_fan_out_batch_without_python_copies(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        from keto_tpu.relationtuple.columns import CheckColumns
+        from keto_tpu.telemetry.devstats import DEVSTATS
+
+        store = self._store()
+        oracle, eng = make_engines(store, query_mode="device")
+        reqs = [
+            t(r)
+            for r in (
+                "docs:d0#view@alice",  # wide
+                "docs:d0#view@bob",
+                "docs:d1#view@carol",
+                "docs:d2#view@alice",  # wide: via g1, one of twelve
+                "docs:d2#view@bob",
+                "docs:d2#view@(groups:g2#member)",
+                "docs:d1#view@nobody",
+                "docs:d0#view@(groups:g0#member)",
+            )
+        ]
+        cols = CheckColumns.from_tuples(reqs).validate()
+        want = oracle.batch_check(reqs)
+        assert any(want) and not all(want)
+        # warm: both fan-out classes' shapes compile here
+        assert eng.batch_check_columns(cols) == want
+
+        def no_copy(*a, **kw):
+            raise AssertionError("operand copied through Python")
+
+        monkeypatch.setattr(jnp, "asarray", no_copy)
+        monkeypatch.setattr(jax, "device_put", no_copy)
+        before = DEVSTATS.panel()["dispatches"]
+        assert eng.batch_check_columns(cols) == want
+        # one dispatch per fan-out class: narrow and wide
+        assert DEVSTATS.panel()["dispatches"] - before == 2
+        assert eng.n_oracle_rows == 0
+
+    def test_query_program_keeps_its_index_parameters(self):
+        """The served shape's compiled program still declares ``f0`` and
+        ``l`` as their own ``s32[B, W]`` parameters, which the roofline
+        reader finds by name in the device trace's op text."""
+        import re
+
+        from keto_tpu.ops.closure import closure_query
+
+        b, w = 128, 8
+        text = closure_query.lower(
+            np.zeros((256, 256), np.uint8),
+            np.zeros((b, w), np.int32),
+            np.zeros((b, w), np.int32),
+            np.zeros(b, np.int32),
+            np.ones(b, np.int32),
+            np.zeros(b, bool),
+        ).compile().as_text()
+        for name, pos in (("f0", 1), ("l", 2)):
+            assert re.search(
+                rf"%{name}\.\d+ = s32\[{b},{w}\]\{{[^}}]*\}} "
+                rf"parameter\({pos}\)",
+                text,
+            ), name
