@@ -949,8 +949,9 @@ class Registry:
             if mode == "host":
                 self._check_engine = CheckEngine(self.store(), max_depth=max_depth)
             elif mode in ("closure", "auto"):
-                # the default: gather-only closure path, with exact
-                # fallback inside the engine for oversized interiors
+                # the default: gather-only closure path (an interior
+                # walk for interiors past the closure limit), with exact
+                # fallback inside the engine for overflowing rows
                 # (VERDICT round 2: `keto serve` must hit the fast path)
                 from ..engine.closure import ClosureCheckEngine
 

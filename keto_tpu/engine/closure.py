@@ -39,9 +39,15 @@ new interior edges connect *existing* interior nodes updates the resident
 closure in O(M^2) per edge (ops.closure.closure_insert_edge — exact for
 single-edge insertion) instead of re-running the O(M^3) matmul build.
 
-Requests whose F0/L rows overflow the padded width, and snapshots whose
-interior exceeds ``interior_limit`` (closure memory is O(M^2)), fall back to
-an exact slower engine — by default the host BFS oracle over the same store.
+Snapshots whose interior exceeds ``interior_limit`` (closure memory is
+O(M^2): Drive-style folders, where every document's owner set is interior)
+hold the interior's forward CSR instead of D, and each batch walks it
+(ops.closure.interior_walk): the same F0/L boundary gathers, a bounded
+lockstep walk in place of the D gather.
+
+Requests whose F0/L rows or walk frontiers overflow their padded widths fall
+back to an exact slower engine — by default the host BFS oracle over the
+same store.
 """
 
 from __future__ import annotations
@@ -50,6 +56,7 @@ import logging
 import threading
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional, Sequence, Union
 
 import jax.numpy as jnp
@@ -60,6 +67,7 @@ from ..graph.interior import (
     build_interior,
     gather_padded_rows,
     interior_blocks,
+    interior_csr,
 )
 from ..graph.snapshot import GraphSnapshot, SnapshotManager
 from ..ops.closure import (
@@ -68,6 +76,8 @@ from ..ops.closure import (
     closure_insert_edge,
     closure_insert_edge_host,
     closure_query,
+    interior_walk,
+    interior_walk_host,
     pack_adjacency,
 )
 from ..relationtuple.definitions import RelationTuple, SubjectID, SubjectSet
@@ -97,6 +107,20 @@ _NARROW_WIDTH = 8
 # spare D rows reserved for overlay-grown interior nodes (new subject sets
 # gaining their first in-edge) between rebuilds
 _GROW_RESERVE = 512
+
+# the interior walk's CSR rows and edges are padded to multiples of this
+_WALK_ROWS_BUCKET = 1 << 16
+
+# the walk's frontier: this many distinct interior sets a row (at least
+# f0_max, the width F0 is gathered at), from at most twice as many
+# out-edges a hop. A Drive-style deployment's widest frontier (drive8m's
+# shape at 800k tuples) is 31 sets from 31 out-edges.
+_WALK_WIDTH = 32
+
+# rows that overflow the walk's widths walk again on the host, the frontier
+# eight times wider each round up to this width, before the fallback
+# engine (which scans the store once per visited set) answers them
+_WALK_WIDE_MAX = 2048
 
 
 def _bucket_pow2(n: int, minimum: int = _MIN_BATCH) -> int:
@@ -223,17 +247,48 @@ class _ClosureArtifacts:
         return self.snap.num_edges
 
 
+class _WalkArtifacts:
+    """Per-snapshot residency for an interior above the closure limit: the
+    interior's forward CSR (graph/interior.py interior_csr) on the device,
+    or on the host for host queries, and no D. Row and edge counts are
+    bucketed so snapshots of about one size share the walk's programs."""
+
+    def __init__(self, snap: GraphSnapshot, ig: InteriorGraph, host: bool):
+        self.snap = snap
+        self.ig = ig
+        self.host = host
+        self.rows = _bucket_mult(ig.m + 1, _WALK_ROWS_BUCKET)
+        csr = interior_csr(
+            ig, self.rows, _bucket_mult(len(ig.ii_src), _WALK_ROWS_BUCKET)
+        )
+        self.pad = self.rows - 1  # the last CSR row: empty
+        self.csr_host = csr  # the overflow rows' wider walk reads it
+        self.csr = csr if host else jnp.asarray(csr).block_until_ready()
+
+    @property
+    def version(self) -> int:
+        return self.snap.version
+
+    @property
+    def num_edges(self) -> int:
+        return self.snap.num_edges
+
+
 @dataclass
 class _TooBig:
-    """Snapshot whose interior exceeds the closure limit (or whose depth
-    exceeds the uint8 range): checks route to the exact fallback engine,
-    which reads the live store — always fresh."""
+    """Checks answered by the exact fallback engine, which reads the live
+    store (always fresh): for a forked read replica, which may not touch
+    the device, or a max depth beyond the closure's uint8 distance range,
+    the whole snapshot; under strong freshness, the batches that find a
+    walk state behind the store, until its background rebuild lands."""
 
     version: int
     num_edges: int
 
 
-_State = Union[_ClosureArtifacts, _TooBig]
+_State = Union[_ClosureArtifacts, _WalkArtifacts, _TooBig]
+#: states that answer from a snapshot (the rest read the live store)
+_SNAPSHOT_STATES = (_ClosureArtifacts, _WalkArtifacts)
 
 
 class ClosureCheckEngine:
@@ -356,8 +411,21 @@ class ClosureCheckEngine:
                 "closure builds by kind",
                 labelnames=("kind",),
             )
+            walk_rows = metrics.counter(
+                "keto_interior_walk_rows_total",
+                "checks of a walk-state snapshot, by what answered them: "
+                "the walk (on the device, or on the host in host query "
+                "mode), a wider walk on the host (rows that overflowed "
+                "it), or the host oracle (rows wider still)",
+                labelnames=("path",),
+            )
+            self._m_walk_rows = {
+                p: walk_rows.labels(path=p)
+                for p in ("device", "host", "oracle")
+            }
         else:
             self._m_checks = self._m_batch_s = self._m_builds = None
+            self._m_walk_rows = None
 
     # -- write overlay ---------------------------------------------------------
 
@@ -471,7 +539,12 @@ class ClosureCheckEngine:
                 ov.drain()
                 if not ov.broken:
                     return ov.version
+        if isinstance(state, _ClosureArtifacts) or (
+            isinstance(state, _WalkArtifacts) and self._bounded(state)
+        ):
             return state.version
+        # the live store answers: a fallback state, or (strong freshness) a
+        # walk state the store has moved past (_serving_pinned)
         return self.snapshots.store.version
 
     def answering_version(self) -> int:
@@ -491,7 +564,7 @@ class ClosureCheckEngine:
                 ov.drain()
                 if ov.active(store_version):
                     return ov.version  # overlay-corrected: live-exact
-        if self._bounded(state) and isinstance(state, _ClosureArtifacts):
+        if self._bounded(state) and isinstance(state, _SNAPSHOT_STATES):
             # serving stale while rebuilding — and the rebuild must be
             # kicked HERE too: a result cache that answers hits without
             # reaching the engine would otherwise starve the background
@@ -555,6 +628,12 @@ class ClosureCheckEngine:
             if self._bounded(state):
                 self._kick_rebuild()
                 return state, pinned
+            if isinstance(state, _WalkArtifacts):
+                # a walk state takes no write overlay: the live store's
+                # oracle answers exactly while the walk rebuilds behind it,
+                # so a check after a write never waits on the rebuild
+                self._kick_rebuild()
+                return _TooBig(store_version, state.num_edges), None
             self._build_sync()
             # loop: re-read state AND overlay together for the fresh pin
 
@@ -649,23 +728,23 @@ class ClosureCheckEngine:
                 ig = build_interior(snap)
             phases["interior"] = round(time.perf_counter() - t0, 6)
             span.set_attr("interior", ig.m)
-            if ig.m > self.interior_limit or (
-                self.global_max_depth > _MAX_CLOSURE_DEPTH
-            ):
+            if self.global_max_depth > _MAX_CLOSURE_DEPTH:
                 # depths beyond the uint8 distance range cannot be resolved
                 # by the closure — exact fallback for the whole snapshot
                 span.set_attr("kind", "fallback")
-                if self.logger is not None:
-                    self.logger.warn(
-                        "interior exceeds closure limit; serving from the "
-                        "exact fallback engine",
-                        interior=ig.m,
-                        limit=self.interior_limit,
-                    )
                 phases["total"] = round(time.perf_counter() - t_build, 6)
                 return _TooBig(
                     version=snap.version, num_edges=snap.num_edges
                 )
+            if ig.m > self.interior_limit:
+                # no O(M^2) closure: batches walk the interior's CSR
+                span.set_attr("kind", "walk")
+                t0 = time.perf_counter()
+                with self.tracer.span("closure.walk", interior=ig.m):
+                    art = _WalkArtifacts(snap, ig, host=self.host_queries())
+                phases["walk"] = round(time.perf_counter() - t0, 6)
+                phases["total"] = round(time.perf_counter() - t_build, 6)
+                return art
             k_max = self.global_max_depth - 1
             host = self.host_queries()
             if isinstance(prev, _ClosureArtifacts):
@@ -908,7 +987,10 @@ class ClosureCheckEngine:
             subject=SubjectSet(namespace="", object="", relation=""),
         )
         self.batch_check([dummy])
-        if isinstance(self._state, _ClosureArtifacts) and not self.host_queries():
+        if (
+            isinstance(self._state, _SNAPSHOT_STATES)
+            and not self.host_queries()
+        ):
             # cover the bucket live batches actually pad into, even when
             # max_batch itself is not a power of two
             top = _bucket_pow2(max(batch, _MIN_BATCH))
@@ -1059,7 +1141,7 @@ class ClosureCheckEngine:
         kicked = False
         while True:
             state = self._state
-            if state is None or not isinstance(state, _ClosureArtifacts):
+            if state is None or not isinstance(state, _SNAPSHOT_STATES):
                 return  # fallback/first-build paths answer from live data
             if state.version >= target:
                 return
@@ -1069,7 +1151,9 @@ class ClosureCheckEngine:
                 if not ov.broken and ov.version >= target:
                     return  # overlay absorbs the writes: already fresh
             if not self._bounded(state):
-                return  # strong freshness: the check itself rebuilds
+                # strong freshness: the check itself rebuilds, or reads the
+                # live store (a walk state)
+                return
             if not kicked:
                 self._kick_rebuild()
                 kicked = True
@@ -1093,8 +1177,8 @@ class ClosureCheckEngine:
             return []
         t0 = time.perf_counter()
         state, pinned = self._serving_pinned()
-        if not isinstance(state, _ClosureArtifacts):
-            # interior too large for a closure: exact fallback
+        if isinstance(state, _TooBig):
+            # no device access or depth past the closure: exact fallback
             self._tally(oracle=len(requests))
             with engine_stage("oracle"):
                 return self.fallback_engine().batch_check(
@@ -1174,16 +1258,16 @@ class ClosureCheckEngine:
         """Columnar batch check: the ``CheckColumns`` string lists are
         vocab-encoded directly (zipped key tuples -> lookup_bulk) with no
         ``RelationTuple``/``Subject`` objects on the answer path. Tuples
-        materialize lazily only on the oversized-interior fallback and on
+        materialize lazily only on the whole-snapshot fallback and on
         overflow rows (``_check_arrays`` decodes those from the vocab)."""
         n = len(cols)
         if not n:
             return []
         t0 = time.perf_counter()
         state, pinned = self._serving_pinned()
-        if not isinstance(state, _ClosureArtifacts):
-            # interior too large for a closure: exact fallback (the only
-            # path that needs real tuple objects)
+        if isinstance(state, _TooBig):
+            # no device access or depth past the closure: exact fallback
+            # (the only path that needs real tuple objects)
             self._tally(oracle=n)
             with engine_stage("oracle"):
                 return self.fallback_engine().batch_check(
@@ -1243,7 +1327,7 @@ class ClosureCheckEngine:
                 np.int32
             )
         state, pinned = self._serving_pinned()
-        if not isinstance(state, _ClosureArtifacts):
+        if isinstance(state, _TooBig):
             snap = self.snapshots.snapshot()
             reqs = self._decode_requests(snap, start, target)
             self._tally(oracle=len(reqs))
@@ -1314,7 +1398,10 @@ class ClosureCheckEngine:
         dummy = snap.dummy_node
         from .. import native
 
-        fused = native.lib is not None and art.d_host is not None
+        walk = isinstance(art, _WalkArtifacts)
+        fused = (
+            not walk and native.lib is not None and art.d_host is not None
+        )
         overflow = None
         with engine_stage("gather"):
             # process rows sorted by start id: requests sharing a start
@@ -1358,7 +1445,8 @@ class ClosureCheckEngine:
                     1,  # set targets: L = {target}
                 )
                 narrow = (f0_deg <= _NARROW_WIDTH) & (l_deg <= _NARROW_WIDTH)
-                if narrow.all() or not narrow.any():
+                # the walk's operands have fixed widths: nothing to split
+                if walk or narrow.all() or not narrow.any():
                     parts = [np.arange(n)]
                 else:
                     parts = [np.nonzero(narrow)[0], np.nonzero(~narrow)[0]]
@@ -1378,8 +1466,24 @@ class ClosureCheckEngine:
                 )
                 allowed[idx] = a
                 overflow[idx] = ov
+            n_over = int(np.count_nonzero(overflow))
+            if walk and n_over:
+                with engine_stage("oracle"):
+                    idx = np.flatnonzero(overflow)
+                    a, ov = self._walk_wide(
+                        art, ig, start[idx], target[idx], is_id[idx],
+                        depth[idx], direct[idx],
+                    )
+                    allowed[idx] = a
+                    overflow[idx] = ov
+            if walk and self._m_walk_rows is not None:
+                n_left = int(np.count_nonzero(overflow))
+                self._m_walk_rows["device"].inc(n - n_over)
+                self._m_walk_rows["host"].inc(n_over - n_left)
+                self._m_walk_rows["oracle"].inc(n_left)
 
-            # ---- exact fallback for overflowing rows (wide F0/L fan-out)
+            # ---- exact fallback for overflowing rows (wide F0/L fan-out,
+            # or a walk frontier past even the widest host walk)
             if overflow.any():
                 with engine_stage("oracle"):
                     fb = self.fallback_engine()
@@ -1440,52 +1544,114 @@ class ClosureCheckEngine:
     def _query_rows(
         self, art, ig, start, target, is_id, depth, direct
     ) -> tuple[np.ndarray, np.ndarray]:
-        """Gather + closure query for one fan-out class of rows. Returns
-        (allowed, overflow) for the subset."""
+        """Gather + closure query (or interior walk) for one fan-out class
+        of rows. Returns (allowed, overflow) for the subset."""
         n = len(start)
-        host = art.d_host is not None
+        walk = isinstance(art, _WalkArtifacts)
+        host = art.host if walk else art.d_host is not None
         # the device program's rows: a power-of-two bucket, built at that
         # size with the padding rows inert (PAD indices, depth 1, no edge)
         b = n if host else _bucket_pow2(n)
         with engine_stage("gather"):
-            # adaptive row widths: pad to this subset's max degree
-            # (pow2-bucketed for jit-shape stability), capped at
-            # f0_max/l_max
-            f0_w = self._adaptive_width(
-                ig.set_out_indptr, start, self.f0_max
+            if walk:
+                # fixed widths: the walk's shapes depend on the bucket only
+                f0_w, l_w = self.f0_max, self.l_max
+            else:
+                # adaptive row widths: pad to this subset's max degree
+                # (pow2-bucketed for jit-shape stability), capped at
+                # f0_max/l_max
+                f0_w = self._adaptive_width(
+                    ig.set_out_indptr, start, self.f0_max
+                )
+                l_w = self._adaptive_width(
+                    ig.id_in_indptr, target, self.l_max
+                )
+            operands, over = self._operands(
+                ig, art.pad, start, target, is_id, depth, direct, f0_w, l_w,
+                b,
             )
-            l_w = self._adaptive_width(ig.id_in_indptr, target, self.l_max)
-            f0, f0_over = gather_padded_rows(
-                ig.set_out_indptr, ig.set_out_vals, start, f0_w, art.pad, b
-            )
-            l, l_over = gather_padded_rows(
-                ig.id_in_indptr, ig.id_in_vals, target, l_w, art.pad, b
-            )
-            # set targets: L = {target} when the target is itself interior
-            set_rows = np.flatnonzero(~is_id)
-            if set_rows.size:
-                t_int = ig.interior_index[target[set_rows]]
-                l[set_rows] = art.pad
-                l[set_rows, 0] = np.where(t_int >= 0, t_int, art.pad)
-            l_over &= is_id  # set-target rows never overflow
-            over = f0_over | l_over
-
-            if host:
+            if host and not walk:
                 # host twin of ops.closure.closure_query: same math, zero
                 # device round-trips (latency-bound links)
+                f0, l = operands[:2]
                 sub = art.d_host[f0[:, :, None], l[:, None, :]]
                 best = sub.min(axis=(1, 2)).astype(np.int32)
                 best[best >= INF_DIST] = 1 << 30  # INF never satisfies
                 total = 1 + best + is_id
                 return (direct & (depth >= 1)) | (total <= depth), over
-            operands = (
-                f0,
-                l,
-                _padded(is_id, b, 0, np.int32),
-                _padded(depth, b, 1, np.int32),
-                _padded(direct, b, False, np.bool_),
+            if host:
+                res = interior_walk_host(
+                    art.csr, *operands, **self._walk_shape(art)
+                )
+        if not host:
+            if walk:
+                kernel = partial(
+                    interior_walk, art.csr, **self._walk_shape(art)
+                )
+            else:
+                kernel = partial(closure_query, art.d)
+            res = self._query(kernel, operands, n)
+        if not walk:
+            return res, over
+        # res is (allowed, overflow): a walk that found a path is exact even
+        # from a truncated F0, L or frontier; only undecided rows go to the
+        # oracle
+        return res[0], (over | res[1]) & ~res[0]
+
+    @staticmethod
+    def _operands(ig, pad, start, target, is_id, depth, direct, f0_w, l_w,
+                  b):
+        """The query's operands (f0, l, extra, depth, direct) for rows
+        (start, target), F0 gathered at width ``f0_w`` and L at ``l_w``,
+        built at ``b`` rows with the padding rows inert (PAD indices,
+        depth 1, no edge); and which rows' F0 or L overflowed its width."""
+        f0, f0_over = gather_padded_rows(
+            ig.set_out_indptr, ig.set_out_vals, start, f0_w, pad, b
+        )
+        l, l_over = gather_padded_rows(
+            ig.id_in_indptr, ig.id_in_vals, target, l_w, pad, b
+        )
+        # set targets: L = {target} when the target is itself interior
+        set_rows = np.flatnonzero(~is_id)
+        if set_rows.size:
+            t_int = ig.interior_index[target[set_rows]]
+            l[set_rows] = pad
+            l[set_rows, 0] = np.where(t_int >= 0, t_int, pad)
+        l_over &= is_id  # set-target rows never overflow
+        operands = (
+            f0,
+            l,
+            _padded(is_id, b, 0, np.int32),
+            _padded(depth, b, 1, np.int32),
+            _padded(direct, b, False, np.bool_),
+        )
+        return operands, f0_over | l_over
+
+    def _walk_wide(self, art, ig, start, target, is_id, depth, direct):
+        """Rows that overflowed the walk, walked again on the host at their
+        whole F0 and L rows, the frontier eight times wider each round up
+        to ``_WALK_WIDE_MAX``: (allowed, still overflowing). Exact where
+        nothing overflows; the rows left go to the fallback engine."""
+        n = len(start)
+        allowed = np.zeros(n, dtype=bool)
+        over = np.ones(n, dtype=bool)
+        width = self._walk_shape(art)["width"]
+        while over.any() and width < _WALK_WIDE_MAX:
+            width = min(8 * width, _WALK_WIDE_MAX)
+            idx = np.flatnonzero(over)
+            s, t = start[idx], target[idx]
+            f0_w = self._adaptive_width(ig.set_out_indptr, s, 1 << 30)
+            l_w = self._adaptive_width(ig.id_in_indptr, t, 1 << 30)
+            operands, _ = self._operands(
+                ig, art.pad, s, t, is_id[idx], depth[idx], direct[idx],
+                f0_w, l_w, len(idx),
             )
-        return self._query(art, operands, n), over
+            res = interior_walk_host(
+                art.csr_host, *operands,
+                **self._walk_shape(art, max(width, f0_w)),
+            )
+            allowed[idx], over[idx] = res[0], res[1]
+        return allowed, over
 
     @staticmethod
     def _adaptive_width(indptr, rows, cap: int) -> int:
@@ -1495,16 +1661,26 @@ class ClosureCheckEngine:
 
     # -- query kernels --------------------------------------------------------
 
-    def _query(self, art, operands, n: int) -> np.ndarray:
-        """``closure_query`` on the device over the padded host operands
-        (f0, l, extra, depth, direct) of ``n`` rows: run, read the answers
-        back."""
+    def _query(self, kernel, operands, n: int) -> np.ndarray:
+        """``kernel`` (``closure_query`` over D, or ``interior_walk`` over
+        the CSR) on the device over the padded host operands (f0, l,
+        extra, depth, direct) of ``n`` rows: run, read the answers back
+        (the rows are the last axis)."""
         self._tally(device=n)
         with engine_stage("dispatch"):
             # numpy operands go straight into the jitted call: jaxlib's C++
             # dispatch copies them to the device along with the launch
-            res = closure_query(art.d, *operands)
+            res = kernel(*operands)
         with engine_stage("device_wait"):
             out = np.asarray(res)
         DEVSTATS.record_round_trip(sum(a.nbytes for a in operands), out.nbytes)
-        return out[:n].copy()
+        return out[..., :n].copy()
+
+    def _walk_shape(self, art, width: int = 0) -> dict:
+        width = width or max(_WALK_WIDTH, self.f0_max)
+        return {
+            "rows": art.rows,
+            "hops": self.global_max_depth - 1,
+            "width": width,
+            "fanout": 2 * width,
+        }

@@ -4,10 +4,11 @@ Observation (the TPU-first redesign of the reference's per-request DFS,
 reference internal/check/engine.go:36-114): in a relation-tuple graph every
 edge's *source* is a subject-set node ``(ns, obj, rel)``, and subject-id nodes
 are sinks. Therefore every node that can appear in the middle of a path is a
-subject set **with at least one incoming edge** — an *interior* node. Real
-graphs have few of them: group/role nesting is small even when objects and
-users number in the millions (the bench's 1M-tuple RBAC graph has ~520k nodes
-but only ~11k interior ones).
+subject set **with at least one incoming edge** — an *interior* node. RBAC
+and org graphs have few of them: group/role nesting is small even when
+objects and users number in the millions (the bench's 1M-tuple RBAC graph has
+~520k nodes but only ~11k interior ones). Drive-style folders are the
+exception: every document's owner set and every folder is interior.
 
 Any check ``start ⇝ target`` decomposes into
 
@@ -26,6 +27,8 @@ CSR row gathers at the boundary. The engines exploit this two ways:
 
 - ``ClosureCheckEngine``: precompute bounded all-pairs distances over the
   interior with MXU matmuls at snapshot time; a check batch is pure gathers.
+  Past ``engine.interior_limit`` it holds the interior's CSR instead
+  (``interior_csr``) and walks it per batch.
 - frontier BFS engines: run the lockstep frontier over interior nodes only.
 
 This module builds the decomposition artifacts from a snapshot's COO arrays
@@ -201,6 +204,20 @@ def build_interior(snap: GraphSnapshot) -> InteriorGraph:
         edge_table=edge_table,
         edge_mask=edge_mask,
     )
+
+
+def interior_csr(ig: InteriorGraph, rows: int, edges: int) -> np.ndarray:
+    """The interior subgraph's forward CSR as one int32 array: the offsets
+    of ``rows`` rows (> m: rows m..rows-1 are empty, and the last is the
+    walk's PAD index), then the successors padded to ``edges`` entries
+    (int32[rows + 1 + edges]). What the interior walk (ops/closure.py
+    interior_walk) holds in place of a dense closure: O(m + e_ii) where
+    the closure is O(m^2)."""
+    indptr, succ = _csr_by(ig.ii_src, ig.ii_dst, rows)
+    out = np.zeros(rows + 1 + max(edges, 1), dtype=np.int32)
+    out[: rows + 1] = indptr
+    out[rows + 1 : rows + 1 + len(succ)] = succ
+    return out
 
 
 @dataclass

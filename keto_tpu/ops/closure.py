@@ -168,3 +168,156 @@ def closure_query(d, f0, l, extra, depth, direct):
     return jnp.logical_or(
         jnp.logical_and(direct, depth >= 1), total <= depth
     )
+
+
+# -- interior walk: interiors too large for a dense closure -------------------
+#
+# Where D would not fit (an interior of millions of subject sets: documents in
+# nested folders), each row walks forward from its F0 set over the interior's
+# own CSR, all rows in lockstep, one hop per step, and tests each hop's
+# frontier against its L set. A frontier is a fixed-width column of distinct
+# interior indices; the CSR's last row is empty and its index is the PAD, the
+# largest value any frontier holds. The helpers take the array module (numpy
+# or jax.numpy) so the host twin runs the same math.
+#
+# Layout, for the chip: rows run along the minor axis ([W, B], [L, B]) and
+# the CSR is one array, offsets then successors (graph/interior.py
+# interior_csr). A hop is then about 26 device ops at B = 128 where rows
+# along the major axis and two arrays made it 57, mostly layout copies and
+# slices: each op is an event in a profiler trace, and a dispatch runs up
+# to max-depth - 1 hops.
+
+
+def _walk_sort(xp, x):
+    if xp is jnp:
+        return lax.sort(x, dimension=0, is_stable=False)
+    return xp.sort(x, axis=0)
+
+
+def _walk_meets(xp, f, l, pad):
+    """bool[B]: does column b of frontier f share an index with l[:, b]?"""
+    both = (f[:, None, :] == l[None, :, :]) & (f != pad)[:, None, :]
+    return xp.any(both, axis=(0, 1))
+
+
+def _walk_step(xp, csr, rows, f, pad, width, fanout):
+    """The distinct successors of each column of frontier f[W, B]: (next
+    frontier [width, B], overflow bool[B]). A row overflows when its
+    frontier's out-edges exceed ``fanout`` or their distinct targets exceed
+    ``width``: the frontier it gets is then truncated.
+
+    Gathers are what a hop costs on the chip (several ns an element), so
+    it makes three: the CSR offsets at f and at f + 1, and one successor
+    id per candidate slot. Which entry's row fills slot j is a masked sum
+    over the W entries, not a gather."""
+    lo = csr[f]
+    deg = csr[f + 1] - lo
+    ends = xp.cumsum(deg, axis=0)
+    starts = ends - deg
+    total = ends[-1]
+    # slot j belongs to entry i when starts[i] <= j < ends[i]; its edge is
+    # the successor at lo[i] + j - starts[i]
+    slot = xp.arange(fanout, dtype=f.dtype)[:, None, None]
+    mine = (starts[None] <= slot) & (slot < ends[None])
+    at = xp.sum(xp.where(mine, (lo - starts)[None], 0), axis=1) + slot[:, 0]
+    valid = slot[:, 0] < total[None]
+    cand = xp.where(valid, csr[xp.where(valid, at + rows + 1, 0)], pad)
+    # distinct, PAD last (it is the largest index), then the first `width`
+    s = _walk_sort(xp, cand)
+    new = xp.concatenate(
+        [s[:1] != pad, (s[1:] != s[:-1]) & (s[1:] != pad)], axis=0
+    )
+    nxt = _walk_sort(xp, xp.where(new, s, pad))[:width]
+    return nxt, (total > fanout) | (xp.sum(new, axis=0) > width)
+
+
+def _walk_start(xp, f0, l, extra, depth, direct, pad, width):
+    """Hop 0: the frontier is F0 itself ([B, F0] in, [width, B] out), and
+    a row is allowed by its direct edge or by F0 meeting L."""
+    f = f0.T
+    if f.shape[0] < width:
+        f = xp.concatenate(
+            [f, xp.full((width - f.shape[0], f.shape[1]), pad, f.dtype)],
+            axis=0,
+        )
+    l = l.T
+    budget = depth - 1 - extra  # interior hops a row may take
+    allowed = (direct & (depth >= 1)) | (
+        _walk_meets(xp, f, l, pad) & (budget >= 0)
+    )
+    return f, l, budget, allowed
+
+
+def _walk_live(xp, h, f, budget, allowed, over, pad):
+    """The frontier of the rows that hop h + 1 could still allow; the
+    others' is all PAD (it has no successors)."""
+    live = ~allowed & ~over & (h < budget) & xp.any(f != pad, axis=0)
+    return xp.where(live[None], f, pad)
+
+
+@partial(jax.jit, static_argnames=("rows", "hops", "width", "fanout"))
+def interior_walk(csr, f0, l, extra, depth, direct, *, rows, hops, width,
+                  fanout):
+    """bool[2, B]: row 0 allowed, row 1 overflow (not allowed, and the
+    walk could not tell: the host oracle answers those rows).
+
+    csr: int32[rows + 1 + E] — the interior's forward CSR, offsets then
+    successors (graph/interior.py interior_csr), whose last row rows - 1
+    is empty and is the PAD index; f0, l, extra, depth, direct as
+    ``closure_query``, F0 of width <= ``width``. A hit at hop h allows the
+    row when 1 + h + extra <= depth, the same accounting as
+    ``closure_query``; ``hops`` (the global max depth - 1) bounds the
+    loop. Shapes are static per (B, F0, L) with width, fanout and hops
+    fixed per engine.
+    """
+    pad = rows - 1
+    f, l, budget, allowed = _walk_start(
+        jnp, f0, l, extra, depth, direct, pad, width
+    )
+    over = jnp.zeros_like(allowed)
+    h = jnp.int32(0)
+    f = _walk_live(jnp, h, f, budget, allowed, over, pad)
+
+    def cond(state):
+        h, f, _, _ = state
+        return (h < hops) & jnp.any(f != pad)
+
+    def body(state):
+        h, f, allowed, over = state
+        f, full = _walk_step(jnp, csr, rows, f, pad, width, fanout)
+        allowed = allowed | _walk_meets(jnp, f, l, pad)
+        over = over | full
+        f = _walk_live(jnp, h + 1, f, budget, allowed, over, pad)
+        return h + 1, f, allowed, over
+
+    _, _, allowed, over = lax.while_loop(
+        cond, body, (h, f, allowed, over)
+    )
+    return jnp.stack([allowed, over & ~allowed])
+
+
+def interior_walk_host(csr, f0, l, extra, depth, direct, *, rows, hops,
+                       width, fanout):
+    """Numpy twin of ``interior_walk`` (host query mode): same math, no
+    device. Rows drop out of the arrays once their frontier is PAD."""
+    import numpy as np
+
+    pad = rows - 1
+    f, l, budget, allowed = _walk_start(
+        np, f0, l, extra, depth, direct, pad, width
+    )
+    over = np.zeros_like(allowed)
+    cols = np.arange(len(allowed))
+    h = 0
+    while h < hops:
+        f = _walk_live(np, h, f, budget[cols], allowed[cols], over[cols],
+                       pad)
+        keep = np.any(f != pad, axis=0)
+        if not keep.any():
+            break
+        cols, f = cols[keep], f[:, keep]
+        f, full = _walk_step(np, csr, rows, f, pad, width, fanout)
+        allowed[cols] |= _walk_meets(np, f, l[:, cols], pad)
+        over[cols] |= full
+        h += 1
+    return np.stack([allowed, over & ~allowed])

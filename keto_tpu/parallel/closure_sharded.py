@@ -3,9 +3,13 @@
 The single-chip closure engine holds three memory classes:
 
 1. the interior distance matrix D — O(M^2) where M is the interior
-   (group/role nesting) count. M does NOT grow with users or objects
-   (SURVEY bench note: 22k interior at 100M tuples), so D stays ~0.5 GB
-   even at 1B tuples → **replicated** on every device.
+   (group/role nesting) count. In RBAC and org shapes M does NOT grow
+   with users or objects (SURVEY bench note: 22k interior at 100M
+   tuples), so D stays ~0.5 GB even at 1B tuples → **replicated** on
+   every device. Drive-shaped deployments are the exception: there every
+   document's owner set and every folder is interior, M grows with
+   objects, and no D fits (the single-chip engine walks the interior's
+   CSR instead, ops/closure.py interior_walk).
 2. the boundary CSRs (F0 = set successors by node, L = interior
    in-neighbors by node) and the direct-edge table — O(E), the actual
    scale axis. At 1B edges these exceed one device's HBM →

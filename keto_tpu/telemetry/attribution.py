@@ -39,10 +39,11 @@ Stage vocabulary (flow order):
   probe), launch (launch-queue wait + async kernel enqueue);
 - closure engine (``ENGINE_STAGES``): lookup (vocabulary encode), gather
   (sort, clamps, direct-edge probe, degree split, padded-row gathers;
-  the host query twin's D gather), dispatch (the ``closure_query`` call
-  with its host operands returning: their copy to the device and the
-  launch), device_wait (blocking readback of its result), oracle
-  (host-oracle answers of overflow rows), finish (overlay, reorder, list
+  the host query twins' D gather or walk), dispatch (the
+  ``closure_query`` or ``interior_walk`` call with its host operands
+  returning: their copy to the device and the launch), device_wait
+  (blocking readback of its result), oracle (host-oracle answers of
+  overflow rows), finish (overlay, reorder, list
   conversion, the circuit breaker's check of the answers);
 - kernel (block-until-materialized on device; on the closure engine
   path, the engine call's residue outside its named stages), decode

@@ -126,21 +126,21 @@ class TestCacheServing:
 
 
 def test_toobig_fallback_answers_stamp_live_version():
-    """A snapshot whose interior exceeds the closure limit routes checks
-    to the live-store fallback; cached answers must invalidate on EVERY
+    """A max depth past the closure's uint8 distances routes checks to
+    the live-store fallback; cached answers must invalidate on EVERY
     write (stamp = store version), even under bounded freshness."""
     reg = new_test_registry(
         namespaces=("videos",),
         values={
             "engine": {
-                "interior_limit": 2,
                 "freshness": "bounded",
                 "rebuild_debounce_ms": 0,
-            }
+            },
+            "serve": {"read": {"max-depth": 256}},
         },
     )
     store = reg.store()
-    # > 2 interior nodes: closure falls back for the whole snapshot
+    # the closure falls back for the whole snapshot
     store.write_relation_tuples(
         t("videos:a#r@(videos:b#r)"),
         t("videos:b#r@(videos:c#r)"),

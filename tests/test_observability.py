@@ -773,3 +773,116 @@ class TestBenchHeartbeat:
         assert lines[1]["skipped"] == "budget"
         for doc in lines:
             assert "wall_s" in doc and "t_mono" in doc and "t" in doc
+
+
+class TestInteriorWalkTelemetry:
+    """A snapshot whose interior exceeds the closure limit: the walk's
+    build phase and span, its rows counter, and the engine stages around
+    its dispatch."""
+
+    @staticmethod
+    def _engine(tracer=None, metrics=None, **kw):
+        from keto_tpu.engine.closure import ClosureCheckEngine
+        from keto_tpu.graph import SnapshotManager
+        from keto_tpu.relationtuple import RelationTuple
+        from keto_tpu.store import InMemoryTupleStore
+
+        store = InMemoryTupleStore()
+        store.write_relation_tuples(*(
+            RelationTuple.from_string(s) for s in (
+                "drive:d#view@(drive:f1#view)",
+                "drive:d#view@(drive:d#owner)",
+                "drive:d#owner@olga",
+                "drive:f1#view@(drive:f0#view)",
+                "drive:f0#view@(group:a#member)",
+                "drive:f0#view@(group:b#member)",
+                "group:a#member@alice",
+                "group:b#member@bob",
+            )
+        ))
+        return ClosureCheckEngine(
+            SnapshotManager(store), max_depth=12, interior_limit=2,
+            query_mode="device", tracer=tracer, metrics=metrics, **kw
+        )
+
+    @staticmethod
+    def _reqs():
+        from keto_tpu.relationtuple import RelationTuple
+
+        return [RelationTuple.from_string(f"drive:d#view@{u}")
+                for u in ("alice", "bob", "olga", "mallory")]
+
+    def test_walk_build_phase_and_span(self):
+        from keto_tpu.engine.closure import _WalkArtifacts
+
+        tracer = Tracer()
+        eng = self._engine(tracer=tracer)
+        assert eng.batch_check(self._reqs()) == [True, True, True, False]
+        assert isinstance(eng._state, _WalkArtifacts)
+        assert eng.last_build_phases["walk"] > 0
+        assert "matmul" not in eng.last_build_phases
+        (walk,) = tracer.finished("closure.walk")
+        assert walk.attrs["interior"] == eng._state.ig.m
+        build = tracer.finished("closure.build")[-1]
+        assert build.attrs["kind"] == "walk"
+        assert walk.parent_id == build.span_id
+
+    @pytest.mark.parametrize("wide_max", [2048, 1])
+    def test_rows_counter_by_path(self, monkeypatch, wide_max):
+        from keto_tpu.engine import closure
+
+        metrics = MetricsRegistry()
+        # a one-wide frontier: rows whose walk meets two groups at once
+        # overflow, and the host walks them again wider or (with no room to
+        # widen) the oracle answers them; the rest stay on the device
+        monkeypatch.setattr(closure, "_WALK_WIDTH", 1)
+        monkeypatch.setattr(closure, "_WALK_WIDE_MAX", wide_max)
+        eng = self._engine(metrics=metrics, f0_max=1)
+        assert eng.batch_check(self._reqs()) == [True, True, True, False]
+        text = metrics.expose()
+        paths = ("device", "host", "oracle")
+        name = 'keto_interior_walk_rows_total{path="%s"}'
+        counts = {
+            p: float(l.split()[-1]) for l in text.splitlines()
+            for p in paths if l.startswith(name % p + " ")
+        }
+        assert sum(counts.values()) == 4
+        assert counts["oracle"] == eng.n_oracle_rows
+        wider = "host" if wide_max > 1 else "oracle"
+        assert counts[wider] > 0
+        assert counts["host" if wider == "oracle" else "oracle"] == 0
+        assert counts["device"] > 0
+
+    def test_dispatch_and_device_wait_wrap_the_walk(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        eng = self._engine()
+        eng.batch_check(self._reqs())  # build and compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.batch_check(self._reqs())
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+        )
+        events = [
+            (e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for e in line.events
+        ]
+
+        def spans(name):
+            return [(a, b) for n, a, b in events if n == name]
+
+        (dispatch,) = spans("keto.dispatch")
+        (wait,) = spans("keto.device_wait")
+        calls = spans("PjitFunction(interior_walk)")
+        assert calls
+        assert all(dispatch[0] <= a and b <= dispatch[1] for a, b in calls)
+        assert dispatch[1] <= wait[0]
+        assert not spans("PjitFunction(closure_query)")

@@ -106,6 +106,33 @@ def test_closure_query_at_rbac1m_width(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 10**9
 
 
+def test_interior_walk_at_drive8m_size(one_chip):
+    from keto_tpu.ops.closure import interior_walk
+
+    # drive8m: ~2.4M interior sets and ~0.9M interior edges (a ~14 MB
+    # CSR), bucketed as the engine buckets them; F0 and L at their fixed
+    # width 32
+    rows, edges, w = 2490368, 917504, 32
+    compiled = interior_walk.lower(
+        _spec((rows + 1 + edges,), jnp.int32, one_chip),
+        _spec((_BATCH, w), jnp.int32, one_chip),
+        _spec((_BATCH, w), jnp.int32, one_chip),
+        _spec((_BATCH,), jnp.int32, one_chip),
+        _spec((_BATCH,), jnp.int32, one_chip),
+        _spec((_BATCH,), jnp.bool_, one_chip),
+        rows=rows,
+        hops=11,
+        width=w,
+        fanout=2 * w,
+    ).compile()
+    text = compiled.as_text()
+    # the lockstep walk: one device loop; a hop gathers the frontier's
+    # two CSR offsets and its successors, and sorts twice
+    assert " while(" in text and text.count(" sort(") == 2
+    assert text.count(" gather(") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 def test_sharded_serving_step_on_four_chips(topo):
     from keto_tpu.parallel.closure_sharded import _sharded_closure_check
     from keto_tpu.parallel.sharded import make_mesh
