@@ -100,9 +100,19 @@ _MAX_CLOSURE_DEPTH = INF_DIST
 # the delta's blast radius, so there is no full-rebuild cliff anymore
 _MAX_INCR_EDGES = 8
 
-# rows whose F0 and L fan-outs both fit this width take the narrow gather
-# path; the heavy tail is processed separately at full width
+# rows whose F0 and L fan-outs both fit this width form the narrow fan-out
+# class, the rest the wide one; a batch holding both is queried in two parts
+# only where that saves more gathered elements of D than a query launch is
+# worth (_fanout_parts)
 _NARROW_WIDTH = 8
+
+# what one more closure_query launch costs, in gathered elements of D: a
+# launch holds the interpreter ~0.3-0.7 ms (the pjit call, the answer's
+# read-back, a second operand build) while the chip gathers an element of
+# D in ~9 ns (v5e, rbac1m: 0.83 s of gathers over ~11k launches of 8,192
+# elements), so a launch is worth 33k-78k elements; the low end favours
+# splitting. The host twin launches nothing: its worth there is 0
+_LAUNCH_ELEMS = 1 << 15
 
 # spare D rows reserved for overlay-grown interior nodes (new subject sets
 # gaining their first in-edge) between rebuilds
@@ -125,6 +135,43 @@ _WALK_WIDE_MAX = 2048
 
 def _bucket_pow2(n: int, minimum: int = _MIN_BATCH) -> int:
     return _bucket(n, minimum)
+
+
+def _pow2_width(deg_max: int, cap: int) -> int:
+    """The gather width for rows of fan-out up to ``deg_max``: the next
+    power of two (a stable jit shape), at least 1 and at most ``cap``."""
+    width = 1 << max(deg_max - 1, 0).bit_length() if deg_max > 1 else 1
+    return min(width, cap)
+
+
+def _fanout_parts(
+    narrow: np.ndarray,
+    f0_deg: np.ndarray,
+    l_deg: np.ndarray,
+    launch_elems: int,
+    padded: bool,
+    f0_max: int,
+    l_max: int,
+) -> list[np.ndarray]:
+    """The row sets to query for a batch that holds both fan-out classes:
+    all its rows as one part when splitting off the ``narrow`` rows would
+    save at most ``launch_elems`` gathered elements of D (one query reads
+    rows x F0 width x L width of them, the rows ``padded`` to the device
+    program's power-of-two bucket), else the narrow and the wide rows as
+    two parts."""
+
+    def volume(rows: np.ndarray) -> int:
+        return (
+            (_bucket_pow2(len(rows)) if padded else len(rows))
+            * _pow2_width(int(f0_deg[rows].max()), f0_max)
+            * _pow2_width(int(l_deg[rows].max()), l_max)
+        )
+
+    parts = [np.flatnonzero(narrow), np.flatnonzero(~narrow)]
+    whole = np.arange(len(narrow))
+    if volume(whole) - sum(volume(p) for p in parts) <= launch_elems:
+        return [whole]
+    return parts
 
 
 def _padded(a: np.ndarray, b: int, fill, dtype) -> np.ndarray:
@@ -423,9 +470,21 @@ class ClosureCheckEngine:
                 p: walk_rows.labels(path=p)
                 for p in ("device", "host", "oracle")
             }
+            fanout = metrics.counter(
+                "keto_closure_fanout_batches_total",
+                "closure-query batches holding both fan-out classes, by "
+                "plan: queried as one part, or split into narrow and wide "
+                "parts (one query each)",
+                labelnames=("plan",),
+            )
+            # keyed by the number of parts _fanout_parts returns
+            self._m_fanout = {
+                1: fanout.labels(plan="one"),
+                2: fanout.labels(plan="split"),
+            }
         else:
             self._m_checks = self._m_batch_s = self._m_builds = None
-            self._m_walk_rows = None
+            self._m_walk_rows = self._m_fanout = None
 
     # -- write overlay ---------------------------------------------------------
 
@@ -1431,11 +1490,10 @@ class ClosureCheckEngine:
                 )
             else:
                 direct = ig.direct_edge(start, target)
-                # split by fan-out: one hot row (a user in 30 groups)
-                # would otherwise widen the WHOLE batch's D gather to
-                # [B, 32, 32]; the narrow majority gathers [*, <=8, <=8] —
-                # ~16x less random traffic into the closure matrix — while
-                # only the heavy tail pays full width
+                # split by fan-out where it pays: one hot row (a user in 30
+                # groups) widens the WHOLE batch's D gather to [B, 32, 32],
+                # where the narrow majority alone gathers [*, <=8, <=8]; but
+                # each part is a query launch, so a small batch stays whole
                 f0_deg = (
                     ig.set_out_indptr[start + 1] - ig.set_out_indptr[start]
                 )
@@ -1449,7 +1507,20 @@ class ClosureCheckEngine:
                 if walk or narrow.all() or not narrow.any():
                     parts = [np.arange(n)]
                 else:
-                    parts = [np.nonzero(narrow)[0], np.nonzero(~narrow)[0]]
+                    # the host twin (D on the host) launches nothing and
+                    # gathers unpadded rows
+                    device = art.d_host is None
+                    parts = _fanout_parts(
+                        narrow,
+                        f0_deg,
+                        l_deg,
+                        _LAUNCH_ELEMS if device else 0,
+                        device,
+                        self.f0_max,
+                        self.l_max,
+                    )
+                    if self._m_fanout is not None:
+                        self._m_fanout[len(parts)].inc()
 
         if not fused:
             allowed = np.zeros(n, dtype=bool)
@@ -1544,8 +1615,8 @@ class ClosureCheckEngine:
     def _query_rows(
         self, art, ig, start, target, is_id, depth, direct
     ) -> tuple[np.ndarray, np.ndarray]:
-        """Gather + closure query (or interior walk) for one fan-out class
-        of rows. Returns (allowed, overflow) for the subset."""
+        """Gather + closure query (or interior walk) for one part of a
+        batch (_fanout_parts). Returns (allowed, overflow) for the subset."""
         n = len(start)
         walk = isinstance(art, _WalkArtifacts)
         host = art.host if walk else art.d_host is not None
@@ -1655,9 +1726,7 @@ class ClosureCheckEngine:
 
     @staticmethod
     def _adaptive_width(indptr, rows, cap: int) -> int:
-        deg_max = int(np.max(indptr[rows + 1] - indptr[rows]))
-        width = 1 << max(deg_max - 1, 0).bit_length() if deg_max > 1 else 1
-        return min(max(width, 1), cap)
+        return _pow2_width(int(np.max(indptr[rows + 1] - indptr[rows])), cap)
 
     # -- query kernels --------------------------------------------------------
 

@@ -405,8 +405,10 @@ class TestDeviceQueryHandOff:
     ``closure_query``: no Python-level copy to the device per dispatch."""
 
     # alice is a member of more groups than the narrow gather width, so
-    # her rows take the wide fan-out class; the rest stay narrow
-    _WIDE = 12
+    # her rows take the wide fan-out class; the rest stay narrow. Twenty
+    # groups gather at width 32, so a large batch of narrow rows with one
+    # of hers saves far more by splitting than a launch is worth
+    _WIDE = 20
 
     def _store(self):
         store = InMemoryTupleStore()
@@ -423,32 +425,64 @@ class TestDeviceQueryHandOff:
         store.write_relation_tuples(*(t(r) for r in rows))
         return store
 
-    def test_mixed_fan_out_batch_without_python_copies(self, monkeypatch):
+    _MIXED = (
+        "docs:d0#view@alice",  # wide
+        "docs:d0#view@bob",  # wide: d0 names twenty groups
+        "docs:d1#view@carol",
+        "docs:d2#view@alice",  # wide: via g1, one of twenty
+        "docs:d2#view@bob",
+        "docs:d2#view@(groups:g2#member)",
+        "docs:d1#view@nobody",
+        "docs:d0#view@(groups:g0#member)",  # wide
+    )
+    _NARROW = (
+        "docs:d1#view@carol",
+        "docs:d2#view@bob",
+        "docs:d2#view@(groups:g2#member)",
+        "docs:d1#view@nobody",
+    )
+
+    @pytest.mark.parametrize(
+        "case, parts",
+        [
+            # eight rows: one launch costs more than the narrow rows save
+            ("device", 1),
+            # the numpy twin launches nothing: split wherever it saves
+            ("host", 2),
+            # 4,095 narrow rows and one hot one: the split saves ~4M
+            # gathered elements of D
+            ("device_4096", 2),
+        ],
+    )
+    def test_mixed_fan_out_batch_without_python_copies(
+        self, monkeypatch, case, parts
+    ):
         import jax
         import jax.numpy as jnp
 
+        from keto_tpu import native
         from keto_tpu.relationtuple.columns import CheckColumns
         from keto_tpu.telemetry.devstats import DEVSTATS
 
         store = self._store()
-        oracle, eng = make_engines(store, query_mode="device")
-        reqs = [
-            t(r)
-            for r in (
-                "docs:d0#view@alice",  # wide
-                "docs:d0#view@bob",
-                "docs:d1#view@carol",
-                "docs:d2#view@alice",  # wide: via g1, one of twelve
-                "docs:d2#view@bob",
-                "docs:d2#view@(groups:g2#member)",
-                "docs:d1#view@nobody",
-                "docs:d0#view@(groups:g0#member)",
-            )
-        ]
+        device = case != "host"
+        if not device:
+            # keep the fused C path off the host query: the numpy twin
+            # takes the batch
+            monkeypatch.setattr(native, "lib", None)
+        oracle, eng = make_engines(
+            store, query_mode="device" if device else "host"
+        )
+        if case == "device_4096":
+            narrow = [t(r) for r in self._NARROW]
+            reqs = [narrow[i % len(narrow)] for i in range(4095)]
+            reqs.append(t("docs:d0#view@alice"))
+        else:
+            reqs = [t(r) for r in self._MIXED]
         cols = CheckColumns.from_tuples(reqs).validate()
         want = oracle.batch_check(reqs)
         assert any(want) and not all(want)
-        # warm: both fan-out classes' shapes compile here
+        # warm: every part's shape compiles here
         assert eng.batch_check_columns(cols) == want
 
         def no_copy(*a, **kw):
@@ -456,10 +490,21 @@ class TestDeviceQueryHandOff:
 
         monkeypatch.setattr(jnp, "asarray", no_copy)
         monkeypatch.setattr(jax, "device_put", no_copy)
+        queried = []
+        query_rows = eng._query_rows
+
+        def spy(art, ig, start, *rest):
+            queried.append(len(start))
+            return query_rows(art, ig, start, *rest)
+
+        monkeypatch.setattr(eng, "_query_rows", spy)
         before = DEVSTATS.panel()["dispatches"]
         assert eng.batch_check_columns(cols) == want
-        # one dispatch per fan-out class: narrow and wide
-        assert DEVSTATS.panel()["dispatches"] - before == 2
+        assert len(queried) == parts and sum(queried) == len(reqs)
+        # one dispatch per part on the device, none on the host twin
+        assert DEVSTATS.panel()["dispatches"] - before == (
+            parts if device else 0
+        )
         assert eng.n_oracle_rows == 0
 
     def test_query_program_keeps_its_index_parameters(self):
@@ -485,3 +530,79 @@ class TestDeviceQueryHandOff:
                 rf"parameter\({pos}\)",
                 text,
             ), name
+
+
+class TestFanoutParts:
+    """A batch holding both fan-out classes is queried as one part unless
+    splitting it saves more gathered elements of D than a launch is
+    worth; either plan answers alike."""
+
+    # sixteen rows: twelve narrow (F0 and L of 8), four wide (F0 of 32, L
+    # of 2). Whole: 16 x 32 x 8 = 4,096 gathered elements; split, padded
+    # to the device buckets: 16 x 8 x 8 + 8 x 32 x 2 = 1,536; unpadded, as
+    # on the host twin: 12 x 64 + 4 x 64 = 1,024
+    @pytest.mark.parametrize("padded, saved", [(True, 2560), (False, 3072)])
+    def test_one_part_up_to_the_launch_worth(self, padded, saved):
+        from keto_tpu.engine.closure import _NARROW_WIDTH, _fanout_parts
+
+        f0_deg = np.array([8] * 12 + [32] * 4)
+        l_deg = np.array([8] * 12 + [2] * 4)
+        narrow = (f0_deg <= _NARROW_WIDTH) & (l_deg <= _NARROW_WIDTH)
+
+        def plan(launch_elems):
+            return _fanout_parts(
+                narrow, f0_deg, l_deg, launch_elems, padded, 32, 32
+            )
+
+        (whole,) = plan(saved)
+        assert whole.tolist() == list(range(16))
+        parts = plan(saved - 1)
+        assert [p.tolist() for p in parts] == [
+            list(range(12)), list(range(12, 16))
+        ]
+
+    @staticmethod
+    def _hub_store(rng):
+        """A random graph plus users in 9 to 40 groups and sets naming 9 to
+        40 subject sets: rows of both classes, and some past the 32-wide
+        gather (the oracle answers those)."""
+        store = random_store(rng, n_objects=50, n_users=20, n_edges=150)
+        rows = set()
+        for h, k in enumerate((9, 12, 20, 40)):
+            for j in rng.choice(50, size=k, replace=False):
+                rows.add(f"n:o{j}#r1@hub{h}")
+                rows.add(f"n:o{h}#r0@(n:o{j}#r1)")
+        store.write_relation_tuples(*(t(r) for r in rows))
+        return store
+
+    @pytest.mark.parametrize("seed", range(2))
+    def test_one_part_and_split_answer_alike(self, monkeypatch, seed):
+        from keto_tpu.engine import closure
+        from keto_tpu.telemetry import MetricsRegistry
+
+        rng = np.random.default_rng(seed + 300)
+        store = self._hub_store(rng)
+        reqs = _random_requests(rng, 50, 20, k=100)
+        reqs += [
+            t(f"n:o{rng.integers(4)}#r0@hub{rng.integers(4)}")
+            for _ in range(28)
+        ]
+        oracle = CheckEngine(store, max_depth=5)
+        want = [oracle.subject_is_allowed(r) for r in reqs]
+        assert any(want) and not all(want)
+        answers, oracle_rows = {}, {}
+        for plan, launch_elems in (("one", 1 << 40), ("split", -(1 << 40))):
+            monkeypatch.setattr(closure, "_LAUNCH_ELEMS", launch_elems)
+            metrics = MetricsRegistry()
+            eng = ClosureCheckEngine(
+                SnapshotManager(store), max_depth=5, query_mode="device",
+                metrics=metrics,
+            )
+            answers[plan] = eng.batch_check(reqs)
+            oracle_rows[plan] = eng.n_oracle_rows
+            assert (
+                f'keto_closure_fanout_batches_total{{plan="{plan}"}} 1.0'
+                in metrics.expose()
+            )
+        assert answers["one"] == answers["split"] == want
+        assert oracle_rows["one"] == oracle_rows["split"] > 0

@@ -886,3 +886,54 @@ class TestInteriorWalkTelemetry:
         assert all(dispatch[0] <= a and b <= dispatch[1] for a, b in calls)
         assert dispatch[1] <= wait[0]
         assert not spans("PjitFunction(closure_query)")
+
+
+class TestClosureFanoutTelemetry:
+    """``keto_closure_fanout_batches_total{plan}`` counts each batch that
+    holds both fan-out classes once, under the plan it was queried by."""
+
+    @pytest.mark.parametrize(
+        "mode, plan", [("device", "one"), ("host", "split")]
+    )
+    def test_mixed_batch_counted_once_under_its_plan(
+        self, monkeypatch, mode, plan
+    ):
+        from keto_tpu import native
+        from keto_tpu.engine.closure import ClosureCheckEngine
+        from keto_tpu.graph import SnapshotManager
+        from keto_tpu.relationtuple import RelationTuple
+        from keto_tpu.store import InMemoryTupleStore
+
+        if mode == "host":
+            # the numpy twin, not the fused C path, queries the batch
+            monkeypatch.setattr(native, "lib", None)
+        store = InMemoryTupleStore()
+        store.write_relation_tuples(*(
+            RelationTuple.from_string(s)
+            for s in [f"groups:g{i}#member@alice" for i in range(12)]
+            + [f"docs:all#view@(groups:g{i}#member)" for i in range(12)]
+            + ["docs:d#view@(groups:g0#member)", "groups:g0#member@bob"]
+        ))
+        metrics = MetricsRegistry()
+        eng = ClosureCheckEngine(
+            SnapshotManager(store), max_depth=5, query_mode=mode,
+            metrics=metrics,
+        )
+
+        def counts():
+            name = 'keto_closure_fanout_batches_total{plan="%s"}'
+            return {
+                p: float(l.split()[-1])
+                for l in metrics.expose().splitlines()
+                for p in ("one", "split") if l.startswith(name % p + " ")
+            }
+
+        # alice (in twelve subject sets) is the wide class, bob the narrow
+        mixed = [RelationTuple.from_string(f"docs:d#view@{u}")
+                 for u in ("alice", "bob", "carol")]
+        assert eng.batch_check(mixed) == [True, True, False]
+        want = {"one": 0.0, "split": 0.0}
+        want[plan] = 1.0
+        assert counts() == want
+        assert eng.batch_check(mixed[1:]) == [True, False]  # narrow only
+        assert counts() == want
