@@ -27,8 +27,8 @@ vs_baseline is relative to the BASELINE.json north star of 1,000,000
 check RPCs/sec (the reference publishes no measured numbers — SURVEY.md §6).
 
 Env knobs: BENCH_CONFIGS (csv; default "rbac1m,github10m,rbac100m"),
-BENCH_BATCH (default 4096), BENCH_ITERS (default 30), BENCH_ENGINE
-(closure|device, default closure), BENCH_SERVER (default 1),
+BENCH_BATCH (default 4096), BENCH_ITERS (default 30), BENCH_SERVER
+(default 1),
 BENCH_SERVER_SECONDS (default 8), BENCH_REPLICATED (default 1: the
 ``replicated_read`` phase — 1 leader + 2 followers in-process, aggregate
 token-consistent follower checks/s; BENCH_REPL_SECONDS /
@@ -528,11 +528,11 @@ def _as_obj(arr) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def run_config(name: str, n_tuples: int, gen, batch: int, iters: int, engine_kind: str):
+def run_config(name: str, n_tuples: int, gen, batch: int, iters: int):
     import gc as _gc
 
     try:
-        return _run_config(name, n_tuples, gen, batch, iters, engine_kind)
+        return _run_config(name, n_tuples, gen, batch, iters)
     finally:
         # release this config's frozen graph so the next config's GC and
         # RSS aren't polluted by an unreclaimable previous store
@@ -540,9 +540,9 @@ def run_config(name: str, n_tuples: int, gen, batch: int, iters: int, engine_kin
         _gc.collect()
 
 
-def _run_config(name: str, n_tuples: int, gen, batch: int, iters: int, engine_kind: str):
-    from keto_tpu.engine.device import DeviceCheckEngine, SnapshotExpandEngine
+def _run_config(name: str, n_tuples: int, gen, batch: int, iters: int):
     from keto_tpu.engine.closure import ClosureCheckEngine, _ClosureArtifacts
+    from keto_tpu.engine.expand import SnapshotExpandEngine
     from keto_tpu.graph import SnapshotManager
     from keto_tpu.relationtuple import RelationTuple, SubjectID, SubjectSet
 
@@ -556,12 +556,7 @@ def _run_config(name: str, n_tuples: int, gen, batch: int, iters: int, engine_ki
     snap = snapshots.snapshot()
     t_encode = time.time() - t0
 
-    if engine_kind == "device":
-        engine = DeviceCheckEngine(snapshots, max_depth=5)
-    else:
-        engine = ClosureCheckEngine(
-            snapshots, max_depth=5, interior_limit=40960
-        )
+    engine = ClosureCheckEngine(snapshots, max_depth=5, interior_limit=40960)
 
     def to_requests(skeys, dkeys):
         return [
@@ -725,7 +720,7 @@ def _run_config(name: str, n_tuples: int, gen, batch: int, iters: int, engine_ki
         "padded_edges": snap.padded_edges,
         "batch": batch,
         "iters": iters,
-        "engine": engine_kind,
+        "engine": "closure",
         "build_s": round(t_build, 2),
         "encode_s": round(t_encode, 2),
         "first_batch_s": round(t_first, 2),
@@ -1758,15 +1753,12 @@ def _smoke_defaults() -> None:
         "BENCH_SERVER_WORKERS": "2",
         "BENCH_WRITE_CYCLES": "3",
         "BENCH_TAIL_N": "120",
-        "BENCH_SHARDED": "0",
         "BENCH_SHARDED_CLOSURE": "0",  # 1M closure build blows the gate
         # 1M build blows the gate here too; check.sh runs a dedicated
         # sharded-parity gate on the 8-way virtual mesh instead
         "BENCH_SHARDED_SERVING": "0",
         "BENCH_REPL_SECONDS": "2",
-        "BENCH_AUTOTUNE_SECONDS": "3",
         "BENCH_SCRUB_SECONDS": "3",
-        "BENCH_OVERLOAD_SECONDS": "3",
         "BENCH_BUDGET_S": "240",
         # cluster federation ON in the gate: the smoke numbers are
         # measured with the scrape loop live, so a federation change
@@ -1778,167 +1770,8 @@ def _smoke_defaults() -> None:
 
 
 # ---------------------------------------------------------------------------
-# sharded tier: scaling shape on a virtual CPU mesh
+# sharded tier: the mesh closure engine on a virtual CPU mesh
 # ---------------------------------------------------------------------------
-
-
-def _sharded_child():
-    """Runs inside a JAX_PLATFORMS=cpu subprocess with 8 virtual devices:
-    measure the sharded check_ids path across mesh shapes. CPU numbers are
-    not TPU numbers — what this validates is that the collective structure
-    compiles, executes, and scales sanely as edges spread over the mesh."""
-    import jax
-
-    from keto_tpu.graph import SnapshotManager
-    from keto_tpu.parallel import ShardedCheckEngine, make_mesh
-
-    rng = np.random.default_rng(7)
-    store, sample, _roots = gen_rbac(50_000, rng)
-    snapshots = SnapshotManager(store)
-    snap = snapshots.snapshot()
-    lookup = snap.vocab.lookup
-    dummy = snap.dummy_node
-    batch = 512
-    iters = 3
-    batches = []
-    for _ in range(iters):
-        skeys, dkeys = sample(rng, batch)
-        s = np.array(
-            [v if (v := lookup(k)) is not None else dummy for k in skeys],
-            np.int64,
-        )
-        d = np.array(
-            [v if (v := lookup(k)) is not None else dummy for k in dkeys],
-            np.int64,
-        )
-        batches.append((s, d))
-    for data, edge in ((1, 8), (2, 4), (4, 2), (8, 1)):
-        mesh = make_mesh(jax.devices()[:8], data=data, edge=edge)
-        engine = ShardedCheckEngine(snapshots, mesh=mesh, max_depth=5)
-        engine.check_ids(*batches[0])  # compile
-        t0 = time.time()
-        for s, d in batches:
-            engine.check_ids(s, d)
-        rps = batch * iters / (time.time() - t0)
-        print(
-            json.dumps(
-                {
-                    "config": "sharded_scatter_cpu8",
-                    # the scatter BFS tier is a mesh-correctness PARITY
-                    # ORACLE only. Live traffic is served by
-                    # parallel/serving.ShardedServingEngine (the
-                    # ``sharded_serving:*`` phase below), which routes the
-                    # sharded CLOSURE kernel through the CheckBatcher.
-                    "role": "parity-oracle",
-                    "mesh": f"{data}x{edge}",
-                    "tuples": len(store),
-                    "batch": batch,
-                    "check_rps_encoded": round(rps),
-                }
-            ),
-            flush=True,
-        )
-
-    # the 1B-rung kernel, engine-direct: D replicated, boundary CSRs
-    # node-striped over 'edge', two pmin collectives per batch. A
-    # scaled-down model of the BASELINE v5e-16 configuration: per-shard
-    # residency bytes are logged so the 1B projection is arithmetic, not
-    # faith. Engine-direct rungs are the MESH ORACLE; serving-tier
-    # numbers (through CheckBatcher) come from ``sharded_serving:*``.
-    from keto_tpu.parallel import ShardedClosureEngine
-
-    # 200k keeps the interior ~2.2k so the O(M^3) closure build stays
-    # CPU-feasible on the virtual mesh; raise on real TPU hardware
-    n_cls = int(os.environ.get("BENCH_SHARDED_CLOSURE_TUPLES", 200_000))
-    store2, sample2, _roots2 = gen_rbac(n_cls, np.random.default_rng(7))
-    snapshots2 = SnapshotManager(store2)
-    snap2 = snapshots2.snapshot()
-    lookup2 = snap2.vocab.lookup
-    dummy2 = snap2.dummy_node
-    cls_batches = []
-    for _ in range(iters):
-        skeys, dkeys = sample2(rng, batch)
-        s = np.array(
-            [v if (v := lookup2(k)) is not None else dummy2 for k in skeys],
-            np.int64,
-        )
-        d = np.array(
-            [v if (v := lookup2(k)) is not None else dummy2 for k in dkeys],
-            np.int64,
-        )
-        is_id = np.fromiter((len(k) == 1 for k in dkeys), bool, count=batch)
-        cls_batches.append((s, d, is_id))
-    for data, edge in ((1, 8), (2, 4)):
-        mesh = make_mesh(jax.devices()[:8], data=data, edge=edge)
-        engine = ShardedClosureEngine(snapshots2, mesh=mesh, max_depth=5)
-        engine.check_ids(*cls_batches[0])  # closure build + compile
-        t0 = time.time()
-        for s, d, flag in cls_batches:
-            engine.check_ids(s, d, flag)
-        rps = batch * iters / (time.time() - t0)
-        per_shard = engine.shard_bytes()
-        edges_per_shard = snap2.num_edges / edge
-        print(
-            json.dumps(
-                {
-                    "config": "sharded_closure_oracle_cpu8",
-                    "role": "mesh-oracle",
-                    "mesh": f"{data}x{edge}",
-                    "tuples": len(store2),
-                    "batch": batch,
-                    "check_rps_encoded": round(rps),
-                    # wide-fanout handling: escalated device-pass rate and
-                    # the (should-be-~0) host-oracle fallback rate
-                    "overflow_stats": engine.overflow_stats,
-                    "per_shard_bytes": per_shard,
-                    # straight-line projection of the striped classes to
-                    # the 1B rung (D stays fixed — interior doesn't scale
-                    # with users/objects)
-                    "projected_1b_per_shard_gb": round(
-                        (
-                            per_shard["total_per_shard"]
-                            - per_shard["d_replicated"]
-                        )
-                        * (1_000_000_000 / 16 / edges_per_shard)
-                        / 1e9
-                        + per_shard["d_replicated"] / 1e9,
-                        2,
-                    ),
-                }
-            ),
-            flush=True,
-        )
-
-
-def run_sharded_bench():
-    import subprocess
-
-    from keto_tpu.utils.jaxenv import cpu_env
-
-    env = cpu_env(8)
-    repo = os.path.dirname(os.path.abspath(__file__))
-    proc = subprocess.run(
-        [
-            sys.executable,
-            "-c",
-            f"import sys; sys.path.insert(0, {repo!r}); "
-            "import bench; bench._sharded_child()",
-        ],
-        env=env,
-        cwd=repo,
-        capture_output=True,
-        text=True,
-        timeout=min(1200.0, max(60.0, _budget_left())),
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("{"):
-            print(line, file=sys.stderr, flush=True)
-    if proc.returncode != 0:
-        print(
-            f"sharded bench failed rc={proc.returncode}: "
-            f"{proc.stderr[-1000:]}",
-            file=sys.stderr,
-        )
 
 
 def _sharded_closure_child():
@@ -2295,232 +2128,6 @@ def run_list_serving_bench() -> None:
     _heartbeat("list_serving", rps=summary["list_objects_rps"])
 
 
-def run_autotune_bench() -> None:
-    """The online autotuner (PR 18) against the REAL pipelined serving
-    path: two legs over the same warm DeviceCheckEngine. ``hand_tuned``
-    serves with the repo-default knobs (pipeline_depth=2,
-    encode_workers=2); ``autotuned`` starts DETUNED (depth 1, one
-    encoder) and lets the AutoTuner climb back through live
-    ``reconfigure()`` moves, fed by a ledger adapter that counts
-    finished checks and reads per-stage seconds off the batcher's
-    keto_pipeline_stage_seconds histogram. Both legs report the mean
-    rps of their settled second half (same estimator, same store, same
-    thread count), so the headline gains ``hand_tuned_rps`` /
-    ``autotuned_rps`` plus the controller's final knob vector
-    (``autotune_knobs``) and its move/revert counts; --smoke gates
-    ``autotuned_rps >= 0.95 * hand_tuned_rps``."""
-    import threading
-
-    from keto_tpu.engine.autotune import AutoTuner, Knob
-    from keto_tpu.engine.batcher import CheckBatcher
-    from keto_tpu.engine.device import DeviceCheckEngine
-    from keto_tpu.graph.snapshot import SnapshotManager
-    from keto_tpu.relationtuple.definitions import (
-        RelationTuple,
-        SubjectID,
-        SubjectSet,
-    )
-    from keto_tpu.store.memory import InMemoryTupleStore
-    from keto_tpu.telemetry import MetricsRegistry
-
-    leg_seconds = float(os.environ.get("BENCH_AUTOTUNE_SECONDS", 8))
-    n_threads = int(os.environ.get("BENCH_AUTOTUNE_THREADS", 6))
-    n_windows = 12
-
-    # rbac-shaped store (users -> groups -> roles -> resources): checks
-    # exercise multi-hop BFS but the build stays well under a second
-    n_users, n_groups, n_roles, n_resources = 64, 8, 4, 200
-    rng = np.random.default_rng(29)
-    tuples = []
-    for u in range(n_users):
-        for g in rng.choice(n_groups, 2, replace=False):
-            tuples.append(
-                RelationTuple("rbac", f"g{g}", "member", SubjectID(f"u{u}"))
-            )
-    for g in range(n_groups):
-        tuples.append(
-            RelationTuple(
-                "rbac", f"role{g % n_roles}", "member",
-                SubjectSet("rbac", f"g{g}", "member"),
-            )
-        )
-    for res in range(n_resources):
-        tuples.append(
-            RelationTuple(
-                "rbac", f"res{res}", "view",
-                SubjectSet("rbac", f"role{res % n_roles}", "member"),
-            )
-        )
-    store = InMemoryTupleStore()
-    store.write_relation_tuples(*tuples)
-    engine = DeviceCheckEngine(SnapshotManager(store), max_depth=5)
-    reqs = [
-        RelationTuple(
-            "rbac", f"res{int(rng.integers(n_resources))}", "view",
-            SubjectID(f"u{int(rng.integers(n_users))}"),
-        )
-        for _ in range(512)
-    ]
-
-    class _Leg:
-        """Drive the single-check path (the pipelined one — check_batch
-        dispatches monolithically and would never touch the knobs) from
-        worker threads; per-window completion rates land in window_rps."""
-
-        def __init__(self, batcher):
-            self.batcher = batcher
-            self.done = 0
-            self.errors = 0
-            self._lock = threading.Lock()
-            self._stop = threading.Event()
-            self.window_rps: list[float] = []
-
-        def _worker(self, wid: int) -> None:
-            i = wid
-            while not self._stop.is_set():
-                try:
-                    self.batcher.check(reqs[i % len(reqs)], timeout=30)
-                except Exception:
-                    with self._lock:
-                        self.errors += 1
-                    continue
-                i += n_threads
-                with self._lock:
-                    self.done += 1
-
-        def run(self, seconds: float, on_window=None) -> None:
-            threads = [
-                threading.Thread(
-                    target=self._worker, args=(w,), daemon=True
-                )
-                for w in range(n_threads)
-            ]
-            for th in threads:
-                th.start()
-            window_s = seconds / n_windows
-            for _ in range(n_windows):
-                before = self.done
-                t0 = time.monotonic()
-                time.sleep(window_s)
-                dt = time.monotonic() - t0
-                self.window_rps.append(
-                    (self.done - before) / max(dt, 1e-9)
-                )
-                if on_window is not None:
-                    on_window()
-            self._stop.set()
-            for th in threads:
-                th.join(timeout=10)
-
-        def settled_rps(self) -> float:
-            # mean of the second half of windows: leg A's half skips any
-            # residual compile/warm cost, leg B's skips the climb itself
-            tail = self.window_rps[len(self.window_rps) // 2:]
-            return sum(tail) / max(len(tail), 1)
-
-    # -- leg A: hand-tuned defaults (plus an untimed warm drive so the
-    #    XLA bucket compiles are paid before either leg's clock starts)
-    hand = CheckBatcher(
-        engine, max_batch=128, window_s=0.0005,
-        metrics=MetricsRegistry(), pipeline_depth=2, encode_workers=2,
-    )
-    _Leg(hand).run(min(1.0, leg_seconds / 4))
-    leg_hand = _Leg(hand)
-    leg_hand.run(leg_seconds)
-    hand.close()
-
-    # -- leg B: detuned start, the controller climbs back live
-    m_auto = MetricsRegistry()
-    auto = CheckBatcher(
-        engine, max_batch=128, window_s=0.0005,
-        metrics=m_auto, pipeline_depth=1, encode_workers=1,
-    )
-    leg_auto = _Leg(auto)
-
-    class _PipelineLedger:
-        """Attribution-snapshot adapter: the contextvar TimeLedgers do
-        not propagate into the batcher's stage threads, so requests are
-        the bench loop's own completion count, wall is the monotonic
-        clock (the tuner only ever diffs), and per-stage seconds are the
-        cumulative sums of the stage histogram children."""
-
-        def snapshot(self) -> dict:
-            stages = {}
-            h = m_auto.get("keto_pipeline_stage_seconds")
-            if h is not None:
-                for labels, child in h._series():
-                    stages[labels.get("stage", "?")] = {
-                        "seconds": float(child._sum),
-                        "share_of_wall": 0.0,
-                    }
-            attributed = sum(v["seconds"] for v in stages.values())
-            wall = time.monotonic()
-            return {
-                "requests": leg_auto.done,
-                "entries": leg_auto.done,
-                "wall_s": wall,
-                "attributed_s": attributed,
-                "unattributed_s": 0.0,
-                "coverage": 1.0,
-                "stages": stages,
-            }
-
-    knobs = [
-        Knob(
-            "pipeline_depth", stage="device", lo=1, hi=4, step=1,
-            read=lambda: auto.pipeline_depth,
-            apply=lambda v: auto.reconfigure(pipeline_depth=int(v)),
-        ),
-        Knob(
-            "encode_workers", stage="encode", lo=1, hi=4, step=1,
-            read=lambda: auto.encode_workers,
-            apply=lambda v: auto.reconfigure(encode_workers=int(v)),
-        ),
-    ]
-    tuner = AutoTuner(
-        knobs,
-        attribution=_PipelineLedger(),
-        metrics=m_auto,
-        min_requests=16,
-        # CPU windows are noisy: a wider dead-band than the serving
-        # default keeps the controller from churning on jitter alone
-        revert_threshold=0.10,
-        backoff_ticks=2,
-    )
-    leg_auto.run(leg_seconds, on_window=tuner.step)
-    knob_vector = tuner.knob_values()
-    moves, reverts = tuner.moves_total, tuner.reverts_total
-    auto.close()
-
-    summary = {
-        "seconds_per_leg": round(leg_seconds, 2),
-        "threads": n_threads,
-        "checks_hand_tuned": leg_hand.done,
-        "checks_autotuned": leg_auto.done,
-        "check_errors": leg_hand.errors + leg_auto.errors,
-        "hand_tuned_rps": round(leg_hand.settled_rps(), 1),
-        "autotuned_rps": round(leg_auto.settled_rps(), 1),
-        "autotune_knobs": knob_vector,
-        "autotune_moves": moves,
-        "autotune_reverts": reverts,
-    }
-    print(
-        json.dumps({"config": "autotune", **summary}),
-        file=sys.stderr,
-        flush=True,
-    )
-    _EXTRA_HEADLINE["autotune"] = summary
-    for key in (
-        "hand_tuned_rps",
-        "autotuned_rps",
-        "autotune_knobs",
-        "autotune_moves",
-        "autotune_reverts",
-    ):
-        _EXTRA_HEADLINE[key] = summary[key]
-    _heartbeat("autotune", autotuned_rps=summary["autotuned_rps"])
-
-
 def run_scrub_overhead_bench() -> None:
     """The integrity scrubber's serving tax, measured on the REAL check
     path: one warm ClosureCheckEngine + CheckBatcher under steady
@@ -2557,7 +2164,7 @@ def run_scrub_overhead_bench() -> None:
     n_pairs = 6
     window_s = leg_seconds / n_pairs
 
-    # same rbac-shaped store as the autotune leg: multi-hop BFS checks,
+    # rbac-shaped store: multi-hop BFS checks,
     # sub-second build
     n_users, n_groups, n_roles, n_resources = 64, 8, 4, 200
     rng = np.random.default_rng(29)
@@ -2719,239 +2326,6 @@ def run_scrub_overhead_bench() -> None:
     _heartbeat(
         "scrub_overhead",
         scrub_overhead_frac=summary["scrub_overhead_frac"],
-    )
-
-
-def run_overload_bench() -> None:
-    """The overload-control plane under open-loop pressure, on the REAL
-    batcher path: one warm DeviceCheckEngine + CheckBatcher fronted by
-    an OverloadController, driven at 1x (closed loop, measures
-    capacity), then ~2x and ~10x the measured capacity (open loop, a
-    paced submit pool with a 8/62/30 critical/default/sheddable mix and
-    a shared client RetryBudget). The engine is deliberately
-    window-bound (small max_batch, wide window) so the client pool can
-    genuinely out-offer it. Headline: ``goodput_at_10x_frac`` (served
-    accepted checks/s at 10x over 1x capacity), ``shed_rate_by_class``
-    and ``retry_amplification`` at 10x; --smoke gates
-    ``goodput_at_10x_frac >= 0.8``."""
-    import threading
-    from concurrent.futures import ThreadPoolExecutor
-
-    from keto_tpu.client.retry import RetryBudget
-    from keto_tpu.engine.batcher import CheckBatcher
-    from keto_tpu.engine.device import DeviceCheckEngine
-    from keto_tpu.engine.overload import (
-        CRITICAL,
-        DEFAULT,
-        SHEDDABLE,
-        AdaptiveLimiter,
-        BrownoutController,
-        OverloadController,
-    )
-    from keto_tpu.graph.snapshot import SnapshotManager
-    from keto_tpu.relationtuple import RelationTuple, SubjectID
-    from keto_tpu.store.memory import InMemoryTupleStore
-    from keto_tpu.telemetry import MetricsRegistry
-    from keto_tpu.utils.errors import ErrResourceExhausted
-
-    leg_seconds = float(os.environ.get("BENCH_OVERLOAD_SECONDS", 6))
-    n_closed = int(os.environ.get("BENCH_OVERLOAD_THREADS", 8))
-
-    # flat store: the phase measures admission control, not BFS depth
-    n_objects = 256
-    store = InMemoryTupleStore()
-    store.write_relation_tuples(
-        *[
-            RelationTuple("ns", f"o{i}", "view", SubjectID("u"))
-            for i in range(n_objects)
-        ]
-    )
-    engine = DeviceCheckEngine(SnapshotManager(store), max_depth=2)
-    reqs = [
-        RelationTuple("ns", f"o{i}", "view", SubjectID("u"))
-        for i in range(n_objects)
-    ]
-    metrics = MetricsRegistry()
-    controller = OverloadController(
-        max_queue=1_000_000,  # backstop out of reach: ladder decisions only
-        limiter=AdaptiveLimiter(
-            initial=1_000_000, max_limit=1_000_000,
-            target_delay_s=0.05, interval_s=0.05,
-        ),
-        brownout=BrownoutController(hysteresis_s=0.4, min_dwell_s=0.025),
-        metrics=metrics,
-    )
-    # deliberately window-bound around ~1k checks/s: 10x of that is an
-    # offered rate a single-core client harness can actually sustain —
-    # the phase must measure the PLANE under overload, not the
-    # submitter starving the engine
-    batcher = CheckBatcher(
-        engine, max_batch=8, window_s=0.008, metrics=metrics,
-        max_queue=100_000,  # static backstop out of reach: every shed
-        # in this phase is the overload plane's decision
-        overload=controller,
-    )
-
-    lock = threading.Lock()
-    counters = {"accepted": 0, "attempts": 0}
-    last_accept = [0.0]
-    by_class: dict = {}
-    budget = RetryBudget(ratio=0.1)
-
-    def crit_for(i: int) -> str:
-        # 10/60/30: at 10x the critical slice alone is ~1x capacity, so
-        # even a full rung-4 brownout leaves goodput near capacity
-        r = i % 50
-        return CRITICAL if r < 5 else (DEFAULT if r < 35 else SHEDDABLE)
-
-    def one_check(i: int, crit: str, retry: bool) -> None:
-        budget.on_request()
-        for attempt in (0, 1):
-            with lock:
-                counters["attempts"] += 1
-            try:
-                batcher.check(
-                    reqs[i % n_objects], timeout=30, criticality=crit
-                )
-            except ErrResourceExhausted as e:
-                with lock:
-                    cls = by_class.setdefault(crit, [0, 0])
-                    if "culled" not in str(e):
-                        cls[1] += 1
-                if retry and attempt == 0 and budget.spend():
-                    continue
-                return
-            except Exception:
-                return
-            with lock:
-                counters["accepted"] += 1
-                last_accept[0] = time.monotonic()
-                by_class.setdefault(crit, [0, 0])[0] += 1
-            return
-
-    def reset() -> None:
-        with lock:
-            counters["accepted"] = 0
-            counters["attempts"] = 0
-            last_accept[0] = 0.0
-            by_class.clear()
-
-    # -- 1x: closed loop, measures this process's capacity -------------------
-    def closed_leg(seconds: float) -> float:
-        reset()
-        stop = threading.Event()
-
-        def worker(wid: int) -> None:
-            i = wid
-            while not stop.is_set():
-                one_check(i, DEFAULT, retry=False)
-                i += n_closed
-
-        threads = [
-            threading.Thread(target=worker, args=(w,), daemon=True)
-            for w in range(n_closed)
-        ]
-        t0 = time.monotonic()
-        for th in threads:
-            th.start()
-        time.sleep(seconds)
-        stop.set()
-        for th in threads:
-            th.join(timeout=10)
-        return counters["accepted"] / max(time.monotonic() - t0, 1e-9)
-
-    closed_leg(min(1.0, leg_seconds / 4))  # untimed warm: pay the compiles
-    capacity = closed_leg(leg_seconds)
-
-    # -- open-loop legs at a multiple of capacity -----------------------------
-    def open_leg(multiple: float, seconds: float) -> dict:
-        reset()
-        rate = multiple * max(capacity, 1.0)
-        n_offered = min(int(rate * seconds), 30000)
-        max_state = [0]
-        pool = ThreadPoolExecutor(max_workers=128)
-        t0 = time.monotonic()
-        i = 0
-        try:
-            while i < n_offered:
-                tick_deadline = time.monotonic() + 0.005
-                target = min(
-                    n_offered,
-                    int((time.monotonic() - t0) * rate) + int(rate * 0.005),
-                )
-                while i < target:
-                    pool.submit(one_check, i, crit_for(i), True)
-                    i += 1
-                max_state[0] = max(max_state[0], controller.state())
-                now = time.monotonic()
-                if now < tick_deadline:
-                    time.sleep(tick_deadline - now)
-        finally:
-            pool.shutdown(wait=True)
-        wall = time.monotonic() - t0
-        with lock:
-            sheds = {c: v[1] for c, v in by_class.items()}
-            total = {c: v[0] + v[1] for c, v in by_class.items()}
-            # goodput over the period work was actually being served:
-            # once the last acceptance lands, the remaining wall is the
-            # shed-path drain, not serving time
-            served_wall = (
-                last_accept[0] - t0 if last_accept[0] > t0 else wall
-            )
-            goodput = counters["accepted"] / max(served_wall, 1e-9)
-            amplification = counters["attempts"] / max(1, n_offered)
-        return {
-            "multiple": multiple,
-            "offered": n_offered,
-            "wall_s": round(wall, 2),
-            "goodput_rps": round(goodput, 1),
-            "max_state": max_state[0],
-            "shed_rate_by_class": {
-                c: round(sheds.get(c, 0) / max(1, total.get(c, 1)), 3)
-                for c in (CRITICAL, DEFAULT, SHEDDABLE)
-            },
-            "critical_sheds": sheds.get(CRITICAL, 0),
-            "retry_amplification": round(amplification, 3),
-        }
-
-    leg_2x = open_leg(2.0, leg_seconds)
-    # quiet gap so the ladder steps down between legs and the 10x leg
-    # starts from a clean rung (one rung per hysteresis window)
-    t_gap = time.monotonic() + 5.0
-    while time.monotonic() < t_gap and controller.state() != 0:
-        one_check(0, DEFAULT, retry=False)
-        time.sleep(0.02)
-    leg_10x = open_leg(10.0, leg_seconds)
-    batcher.close()
-
-    summary = {
-        "seconds_per_leg": round(leg_seconds, 2),
-        "capacity_rps": round(capacity, 1),
-        "leg_2x": leg_2x,
-        "leg_10x": leg_10x,
-        "goodput_at_10x_frac": round(
-            leg_10x["goodput_rps"] / max(capacity, 1e-9), 3
-        ),
-        "shed_rate_by_class": leg_10x["shed_rate_by_class"],
-        "retry_amplification": leg_10x["retry_amplification"],
-        "overload_state_max": leg_10x["max_state"],
-        "critical_sheds": leg_2x["critical_sheds"]
-        + leg_10x["critical_sheds"],
-    }
-    print(
-        json.dumps({"config": "overload", **summary}),
-        file=sys.stderr,
-        flush=True,
-    )
-    _EXTRA_HEADLINE["overload"] = summary
-    for key in (
-        "goodput_at_10x_frac",
-        "shed_rate_by_class",
-        "retry_amplification",
-    ):
-        _EXTRA_HEADLINE[key] = summary[key]
-    _heartbeat(
-        "overload", goodput_at_10x_frac=summary["goodput_at_10x_frac"]
     )
 
 
@@ -3240,7 +2614,6 @@ def main():
 
     batch = int(os.environ.get("BENCH_BATCH", 4096))
     iters = int(os.environ.get("BENCH_ITERS", 30))
-    engine_kind = os.environ.get("BENCH_ENGINE", "closure")
     names = os.environ.get(
         "BENCH_CONFIGS", "rbac1m,github10m,rbac100m"
     ).split(",")
@@ -3282,7 +2655,7 @@ def main():
             continue
         try:
             results.append(
-                run_config(name, n, gen, batch, iters, engine_kind)
+                run_config(name, n, gen, batch, iters)
             )
         except _BudgetExhausted as e:
             # the partial pool is on disk; the next run resumes the build
@@ -3348,23 +2721,6 @@ def main():
                 flush=True,
             )
 
-    if os.environ.get("BENCH_AUTOTUNE", "1") == "1" and not _skip_phase(
-        "autotune", 45.0
-    ):
-        try:
-            run_autotune_bench()
-        except Exception as e:
-            import traceback
-
-            traceback.print_exc()
-            print(
-                json.dumps(
-                    {"config": "autotune", "error": repr(e)[:300]}
-                ),
-                file=sys.stderr,
-                flush=True,
-            )
-
     if os.environ.get("BENCH_SCRUB", "1") == "1" and not _skip_phase(
         "scrub_overhead", 45.0
     ):
@@ -3378,33 +2734,6 @@ def main():
                 json.dumps(
                     {"config": "scrub_overhead", "error": repr(e)[:300]}
                 ),
-                file=sys.stderr,
-                flush=True,
-            )
-
-    if os.environ.get("BENCH_OVERLOAD", "1") == "1" and not _skip_phase(
-        "overload", 45.0
-    ):
-        try:
-            run_overload_bench()
-        except Exception as e:
-            import traceback
-
-            traceback.print_exc()
-            print(
-                json.dumps({"config": "overload", "error": repr(e)[:300]}),
-                file=sys.stderr,
-                flush=True,
-            )
-
-    if os.environ.get("BENCH_SHARDED", "1") == "1" and not _skip_phase(
-        "sharded", 120.0
-    ):
-        try:
-            run_sharded_bench()
-        except Exception as e:
-            print(
-                json.dumps({"config": "sharded", "error": repr(e)[:300]}),
                 file=sys.stderr,
                 flush=True,
             )
@@ -3568,28 +2897,6 @@ def main():
                     flush=True,
                 )
                 sys.exit(3)
-        # autotune gate: the feedback controller, started DETUNED on the
-        # same engine, must recover at least 95% of hand-tuned
-        # throughput — a controller that wedges a knob at a bad value,
-        # or a reconfigure seam that stalls traffic, fails here
-        at = _EXTRA_HEADLINE.get("autotune") or {}
-        if at.get("hand_tuned_rps") and (
-            at.get("autotuned_rps", 0) < 0.95 * at["hand_tuned_rps"]
-        ):
-            print(
-                json.dumps(
-                    {
-                        "gate": "autotune_rps",
-                        "autotuned_rps": at.get("autotuned_rps"),
-                        "hand_tuned_rps": at.get("hand_tuned_rps"),
-                        "required_ratio": 0.95,
-                        "autotune_knobs": at.get("autotune_knobs"),
-                    }
-                ),
-                file=sys.stderr,
-                flush=True,
-            )
-            sys.exit(3)
         # scrub overhead gate: the always-on integrity scrubber, at a
         # duty cycle ABOVE the production default, must cost at most a
         # small fraction of steady-state check throughput — an
@@ -3613,33 +2920,6 @@ def main():
                         "scrub_off_rps": so.get("scrub_off_rps"),
                         "scrub_on_rps": so.get("scrub_on_rps"),
                         "scrub_cycles": so.get("scrub_cycles"),
-                    }
-                ),
-                file=sys.stderr,
-                flush=True,
-            )
-            sys.exit(3)
-
-        # overload gate: at 10x offered load the admission plane must
-        # keep serving at least 80% of measured capacity — a limiter
-        # that collapses (sheds everything) or a ladder that never
-        # engages (queue melts down, goodput dies in timeouts) fails
-        # here. Critical sheds are a hard zero: the plane's contract.
-        ov = _EXTRA_HEADLINE.get("overload") or {}
-        if ov.get("capacity_rps") and (
-            ov.get("goodput_at_10x_frac", 0.0) < 0.8
-            or ov.get("critical_sheds", 0) > 0
-        ):
-            print(
-                json.dumps(
-                    {
-                        "gate": "overload_goodput",
-                        "goodput_at_10x_frac": ov.get("goodput_at_10x_frac"),
-                        "required": 0.8,
-                        "critical_sheds": ov.get("critical_sheds"),
-                        "capacity_rps": ov.get("capacity_rps"),
-                        "shed_rate_by_class": ov.get("shed_rate_by_class"),
-                        "retry_amplification": ov.get("retry_amplification"),
                     }
                 ),
                 file=sys.stderr,
@@ -3686,13 +2966,9 @@ _HIGHER_BETTER = (
     "device_check_rps",
     "sharded_batch_rps",
     "list_objects_rps",
-    "hand_tuned_rps",
-    "autotuned_rps",
-    "goodput_at_10x_frac",
 )
 _LOWER_BETTER = (
     "scrub_overhead_frac",
-    "retry_amplification",
     "batch_p95_ms",
     "expand_p95_ms",
     "staleness_p95_ms",

@@ -208,19 +208,7 @@ CONFIG_SCHEMA = {
         "engine": {
             "type": "object",
             "properties": {
-                "mode": {
-                    "enum": [
-                        "device",
-                        "host",
-                        "auto",
-                        "dense",
-                        "scatter",
-                        "packed",
-                        "closure",
-                        "sharded",
-                    ]
-                },
-                "dense_threshold": {"type": "integer", "minimum": 2},
+                "mode": {"enum": ["host", "auto", "closure"]},
                 "max_batch": {"type": "integer", "minimum": 1},
                 "batch_window_us": {"type": "number", "minimum": 0},
                 "interior_limit": {"type": "integer", "minimum": 2},
@@ -250,15 +238,6 @@ CONFIG_SCHEMA = {
                 "fallback": {"type": "boolean"},
                 "fallback_threshold": {"type": "integer", "minimum": 1},
                 "fallback_cooldown_ms": {"type": "number", "minimum": 0},
-                "mesh": {
-                    "type": "object",
-                    "properties": {
-                        "data": {"type": "integer", "minimum": 1},
-                        # 0 = use all remaining devices on the edge axis
-                        "edge": {"type": "integer", "minimum": 0},
-                    },
-                    "additionalProperties": False,
-                },
                 # sharded serving tier (parallel/serving.py): route live
                 # check traffic through the edge-partitioned mesh closure
                 # engine; auto-falls back to the single-chip engine when
@@ -267,9 +246,8 @@ CONFIG_SCHEMA = {
                     "type": "object",
                     "properties": {
                         "enabled": {"type": "boolean"},
-                        # mesh axes, same semantics as engine.mesh.*:
-                        # data = batch parallelism, edge = node stripes
-                        # (0 = all remaining devices)
+                        # mesh axes: data = batch parallelism, edge =
+                        # node stripes (0 = all remaining devices)
                         "data": {"type": "integer", "minimum": 1},
                         "edge": {"type": "integer", "minimum": 0},
                         # values gathered per re-stripe chunk, bounding
@@ -723,7 +701,6 @@ DEFAULTS = {
     "tracing.provider": "",
     "namespaces": [],
     "engine.mode": "closure",
-    "engine.dense_threshold": 8192,
     "engine.max_batch": 4096,
     "engine.batch_window_us": 200,
     "engine.interior_limit": 16384,
@@ -744,8 +721,6 @@ DEFAULTS = {
     "engine.closure_block_workers": 0,
     "engine.expand_page_size": 0,
     "engine.compile_cache_dir": "",
-    "engine.mesh.data": 1,
-    "engine.mesh.edge": 0,
     "engine.sharding.enabled": False,
     "engine.sharding.data": 1,
     "engine.sharding.edge": 0,

@@ -24,8 +24,7 @@ from ..api.rest import build_read_app, build_write_app
 from ..api.services import HealthServicer, _DirectChecker
 from ..engine.batcher import CheckBatcher
 from ..engine.check import CheckEngine
-from ..engine.device import DeviceCheckEngine, SnapshotExpandEngine
-from ..engine.expand import ExpandEngine
+from ..engine.expand import ExpandEngine, SnapshotExpandEngine
 from ..graph.snapshot import SnapshotManager
 from ..store.memory import InMemoryTupleStore
 from ..faults import FAULTS
@@ -254,10 +253,6 @@ class DeviceSupervisor:
             if not devs:
                 return False
             jax.config.update("jax_default_device", devs[0])
-            # the packed kernel is Mosaic/TPU; anywhere else it must run
-            # in pallas interpret mode
-            if hasattr(self.engine, "interpret"):
-                self.engine.interpret = platform != "tpu"
             return True
         except Exception as e:
             self._event("swap_failed", backend=platform, error=str(e)[-200:])
@@ -948,7 +943,7 @@ class Registry:
                 )
             if mode == "host":
                 self._check_engine = CheckEngine(self.store(), max_depth=max_depth)
-            elif mode in ("closure", "auto"):
+            else:
                 # the default: gather-only closure path (an interior
                 # walk for interiors past the closure limit), with exact
                 # fallback inside the engine for overflowing rows
@@ -999,29 +994,6 @@ class Registry:
                         hbm.wait_for_headroom
                         if (hbm := self.hbm_admission()) is not None
                         else None
-                    ),
-                )
-            elif mode == "sharded":
-                from ..parallel import ShardedCheckEngine, make_mesh
-
-                data = int(self.config.get("engine.mesh.data"))
-                edge = int(self.config.get("engine.mesh.edge")) or None
-                self._check_engine = ShardedCheckEngine(
-                    self.snapshots(),
-                    mesh=make_mesh(data=data, edge=edge),
-                    max_depth=max_depth,
-                )
-            else:
-                # 'device' -> size-based propagation choice; 'dense'/
-                # 'scatter'/'packed' force that propagation path
-                self._check_engine = DeviceCheckEngine(
-                    self.snapshots(),
-                    max_depth=max_depth,
-                    mode=mode
-                    if mode in ("dense", "scatter", "packed")
-                    else "auto",
-                    dense_threshold=int(
-                        self.config.get("engine.dense_threshold")
                     ),
                 )
         return self._check_engine

@@ -4,7 +4,7 @@ The reference runs one goroutine per request, each walking the graph alone
 (SURVEY.md §2.10). On TPU the economics invert: one batched frontier
 expansion amortizes kernel launch and HBM traffic over every in-flight
 request. The batcher is that seam: callers block on ``check()``, and the
-dispatch machinery drains the queue into ``DeviceCheckEngine`` batches —
+dispatch machinery drains the queue into device engine batches —
 taking whatever has accumulated while the previous batch was on device (the
 natural batching window), plus a tiny fixed window when the queue is empty.
 
@@ -107,8 +107,8 @@ class _PBatch:
         # [(request, depth, Future, t_enqueued, deadline, ledger,
         #   span_ctx), ...]
         self.items = items
-        self.enc = None  # EncodedBatch after the encode stage
-        self.launched = None  # LaunchedBatch after the launch stage
+        self.enc = None  # the engine's encoded batch after encode
+        self.launched = None  # its launched batch after the launch stage
         self.keys = None  # encoded-cache keys (when the cache is on)
         self.t_encoded = 0.0
         self.hbm_token = 0  # HBM admission reservation; 0 = none held
@@ -574,8 +574,8 @@ class CheckBatcher:
         the encoded-request cache in bulk on the encoded
         (snapshot_version, start, target, depth) id triples; no
         ``RelationTuple``/``Subject`` objects are built unless the
-        circuit-breaker fallback fires (lazy materialization inside
-        ``EncodedBatch``)."""
+        circuit-breaker fallback fires (lazy materialization inside the
+        engine's encoded batch)."""
         if self._closed:
             raise BatcherClosed()
         n = len(cols)
@@ -809,7 +809,7 @@ class CheckBatcher:
         # prefer the split encode/launch/decode path: the circuit-breaker
         # wrapper overrides launch/decode, so a breaker-open or failed
         # batch is re-answered by the host oracle from tuples the
-        # EncodedBatch materializes lazily out of the id arrays
+        # encoded batch materializes lazily out of the id arrays
         encode_ids = getattr(self.engine, "encode_ids", None)
         if encode_ids is not None:
             enc = encode_ids(s, t, d)

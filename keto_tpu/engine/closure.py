@@ -1,6 +1,6 @@
 """Closure check engine: snapshot-time MXU closure, gather-only queries.
 
-The fastest check path. Where ``DeviceCheckEngine`` runs a lockstep BFS per
+The fastest check path. Where a lockstep BFS walks the graph for every
 batch, this engine pays the graph traversal ONCE per snapshot — a bounded
 all-pairs-distance closure over the small interior subgraph
 (keto_tpu.graph.interior), built with systolic-array matmuls — and then

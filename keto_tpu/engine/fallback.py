@@ -439,8 +439,8 @@ class DeviceFallbackEngine:
                 if handled is not None:
                     return handled
         # circuit open (or the launch itself died): answer this batch from
-        # the host oracle NOW — its staging buffers go back to the pool and
-        # decode becomes a no-op unwrap
+        # the host oracle NOW — the batch is released and decode becomes a
+        # no-op unwrap
         return self._answer_from_oracle(enc)
 
     def _answer_from_oracle(self, enc) -> "_FallbackAnswered":
@@ -535,7 +535,7 @@ class DeviceFallbackEngine:
         try:
             launched = self.primary.launch_encoded(enc)
         except Exception as e:
-            # a raised launch leaves the half's staging buffers checked out
+            # a raised launch never reaches decode: release the half here
             enc.release()
             if (
                 classify_device_error(e) == "oom"
@@ -558,39 +558,18 @@ class DeviceFallbackEngine:
         enc = launched.enc
         n = enc.n
         depths = enc.depths
-        # Lazy materialization: per-tuple batches hold their requests and
-        # columnar batches hold their columns, so the oracle's tuples are
-        # built ONLY inside the failure branches below — a healthy decode
-        # never touches per-item objects. Pure-id batches (encode_ids) are
-        # the one exception: they can only decode back to tuples while
-        # their staging buffers are alive, and primary.decode_launched
-        # releases those, so snap the materialization up front for that
-        # shape alone.
-        requests = None
-        if (
-            getattr(enc, "_requests", 0) is None
-            and getattr(enc, "_cols", 0) is None
-        ):
-            requests = enc.requests
+        # the oracle's tuples are built (enc.requests, lazily) ONLY inside
+        # the failure branches below — a healthy decode never touches
+        # per-item objects
         deadlines = getattr(enc, "deadlines", None)
         try:
             results = self.primary.decode_launched(launched)
         except Exception as e:
             self._note_failure(e)
-            return self._fallback_check(
-                requests if requests is not None else enc.requests,
-                0,
-                depths,
-                deadlines,
-            )
+            return self._fallback_check(enc.requests, 0, depths, deadlines)
         if not _valid_batch(results, n):
             self._record_failure(None)
-            return self._fallback_check(
-                requests if requests is not None else enc.requests,
-                0,
-                depths,
-                deadlines,
-            )
+            return self._fallback_check(enc.requests, 0, depths, deadlines)
         self._record_success()
         return [bool(v) for v in results]
 

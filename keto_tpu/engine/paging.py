@@ -1,6 +1,6 @@
 """Shared continuation-token mint/validate for paged read surfaces.
 
-Expand paging (engine/expand.py host walk, engine/device.py snapshot walk)
+Expand paging (engine/expand.py host walk, SnapshotExpandEngine's CSR walk)
 and list paging (engine/listing.py) all cut version-pinned cursors with the
 same failure contract:
 

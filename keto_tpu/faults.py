@@ -39,23 +39,23 @@ site                      effect when armed
 ``batcher.decode_die``    the pipeline decode thread raises and dies at the
                           top of its loop; its held batch must fail typed
                           and the stage restart (engine/batcher.py)
-``device.compile_error``  ``DeviceCheckEngine.batch_check`` raises as an XLA
-                          compile failure would (engine/device.py)
-``device.batch_nan``      the device engine returns non-boolean garbage for
-                          the batch, as a numerically sick chip would
-                          (engine/device.py)
+``device.compile_error``  a launch raises as an XLA compile failure would
+                          (parallel/serving.py)
+``device.batch_nan``      a launch returns non-boolean garbage for the
+                          batch, as a numerically sick chip would
+                          (parallel/serving.py)
 ``device.oom``            a launch raises as an XLA RESOURCE_EXHAUSTED (HBM
                           out-of-memory) would; the breaker's OOM policy
                           must bisect and re-dispatch the batch halves
-                          (engine/device.py + engine/fallback.py)
+                          (parallel/serving.py + engine/fallback.py)
 ``device.compile_fail``   a launch raises as a *shape-specific* XLA
                           compilation failure would; the (bucket, snapshot)
                           quarantine must absorb it without tripping the
-                          global breaker (engine/device.py)
+                          global breaker (parallel/serving.py)
 ``device.lost``           a launch raises as a DEVICE_LOST / wedged-driver
                           error would; the device supervisor must tear the
                           engine down and re-init through a backend probe
-                          (engine/device.py + driver/registry.py)
+                          (parallel/serving.py + driver/registry.py)
 ``backend.probe_hang``    the supervisor's backend re-probe "hangs" the way
                           ``jax.devices()`` did in BENCH_r05; the supervised
                           probe must count it as a failed attempt instead of
@@ -130,8 +130,8 @@ site                      seam that honors it when armed
                           batches must still flush and queued requests
                           must survive into the rebuilt pipeline
                           (engine/batcher.py)
-``device.slow``           the device engine stalls inside the dispatch
-                          itself (engine/device.py)
+``device.slow``           a launch stalls inside the dispatch itself
+                          (parallel/serving.py)
 ``delta.slow``            the parent stalls before broadcasting a delta
                           frame (driver/replicas.py)
 ``replica.slow``          a serving replica stalls before answering a check

@@ -3,36 +3,31 @@
 The reference's hot loop is a mutually recursive DFS with one SQL round-trip
 per subject-set node per page (internal/check/engine.go:82-114). Here the
 same question — reachability through subject-set indirections, depth-limited
-— is answered for a whole batch of requests at once by fixed-depth frontier
-expansion over the resident edge arrays (SURVEY.md §7).
+— is answered for a whole batch of requests at once from the resident
+graph (SURVEY.md §7):
+
+- ``build_closure`` / ``build_closure_packed`` build the interior distance
+  matrix D once per snapshot;
+- ``closure_query`` answers a batch by gathering D rows through each row's
+  boundary CSRs (F0, L);
+- ``interior_walk`` answers a batch whose interior is too large for D by
+  walking the interior's CSR on the device, all rows in lockstep.
 """
 
-from .frontier import (
-    batched_check_dense,
-    batched_check_scatter,
-    batched_distances_dense,
-    batched_distances_scatter,
-    build_dense_adjacency,
-    pick_edge_chunk,
-)
 from .closure import (
     INF_DIST,
     build_closure,
     build_closure_packed,
     closure_query,
+    interior_walk,
     pack_adjacency,
 )
 
 __all__ = [
-    "batched_check_dense",
-    "batched_check_scatter",
-    "batched_distances_dense",
-    "batched_distances_scatter",
-    "build_dense_adjacency",
-    "pick_edge_chunk",
     "INF_DIST",
     "build_closure",
     "build_closure_packed",
     "closure_query",
+    "interior_walk",
     "pack_adjacency",
 ]

@@ -2,20 +2,18 @@
 
 The reference scales horizontally with stateless replicas over a shared SQL
 database and delegates data distribution to CockroachDB (SURVEY.md §2.10).
-The TPU-native equivalent: shard the edge arrays over an ICI mesh with
-``jax.sharding`` + ``shard_map``, exchange frontiers with XLA collectives
-per expansion step, and keep the whole depth loop inside one compiled
-program — no host round-trips between steps.
+The TPU-native equivalent: stripe the closure engine's CSRs over an ICI
+mesh with ``jax.sharding`` + ``shard_map``, replicate the interior distance
+matrix, and answer a batch in one compiled program whose collectives ride
+ICI — no host round-trips between phases.
 """
 
 from .closure_sharded import ShardedClosureEngine
+from .mesh import make_mesh
 from .serving import ShardedServingEngine
-from .sharded import ShardedCheckEngine, make_mesh, sharded_check
 
 __all__ = [
-    "ShardedCheckEngine",
     "ShardedClosureEngine",
     "ShardedServingEngine",
     "make_mesh",
-    "sharded_check",
 ]

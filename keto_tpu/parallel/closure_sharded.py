@@ -33,17 +33,20 @@ shard, compute locally, reduce over the mesh):
   allowed  = (direct & depth>=1) | (1 + best + extra <= depth)
 
 Rows whose true fan-out exceeds the static gather widths report an
-overflow flag and are re-answered host-side by the exact oracle — the
-same contract as the single-chip engine's numpy path.
+overflow flag; they run a second device pass at escalated widths, and
+only rows overflowing those too are re-answered host-side by the exact
+oracle.
 
-This module is the kernel + base residency layer. Live check traffic
-reaches it through :class:`.serving.ShardedServingEngine`, the serving
-wrapper the registry wires up under ``engine.sharding.enabled``: it adds
-the split encode/launch/decode batch API for ``CheckBatcher`` and the
-circuit breaker, incremental re-sharding across snapshot rebuilds, and
-per-shard residency accounting. :class:`ShardedClosureEngine` used
-directly (bench `sharded_closure_oracle` configs, parity tests) remains
-the mesh-correctness oracle against that serving path.
+This module is the kernel, the base residency layer and the query path:
+``check_ids`` is encode_ids -> launch_encoded -> decode_launched, the
+split batch API ``CheckBatcher`` and the circuit breaker drive. Live
+check traffic reaches it through :class:`.serving.ShardedServingEngine`,
+the serving wrapper the registry wires up under
+``engine.sharding.enabled``: it adds the launch's fault sites,
+incremental re-sharding across snapshot rebuilds, and per-shard
+residency accounting. :class:`ShardedClosureEngine` used directly (bench
+`sharded_closure_oracle` configs, parity tests) rebuilds its residency
+per snapshot, the mesh-correctness oracle against that serving path.
 
 Design sketch per VERDICT r3 next-#6; BASELINE.md v5e-16 configuration.
 """
@@ -62,11 +65,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..engine.check import DEFAULT_MAX_DEPTH, CheckEngine
-from ..graph.interior import InteriorGraph, build_interior
+from ..graph.interior import build_interior
 from ..graph.snapshot import GraphSnapshot, SnapshotManager
 from ..ops.closure import INF_DIST, build_closure_packed, pack_adjacency
 from ..relationtuple.definitions import RelationTuple, SubjectID, SubjectSet
-from .sharded import make_mesh
+from .mesh import make_mesh
 
 
 def _stripe_csr(
@@ -255,6 +258,87 @@ def _sharded_closure_check(
     )
 
 
+def _decode_ids(snap, start, target) -> list:
+    """Vocab-decode id pairs back to RelationTuples (the host-oracle
+    re-answer of overflow rows and failed batches; the hot path never
+    runs this)."""
+    vocab = snap.vocab
+    n_live = len(vocab)
+    out = []
+    for s, t in zip(start, target):
+        if int(s) < n_live:
+            ns, obj, rel = vocab.key(int(s))
+        else:  # dummy/unknown start: resolves to no tuples downstream
+            ns = obj = rel = ""
+        subject = (
+            vocab.subject_of(int(t)) if int(t) < n_live else SubjectID(id="")
+        )
+        out.append(
+            RelationTuple(
+                namespace=ns, object=obj, relation=rel, subject=subject
+            )
+        )
+    return out
+
+
+class _ShardedEncodedBatch:
+    """A pure-id batch parked between encode and launch on the sharded
+    path. Plain numpy arrays (no staging pool — the mesh upload sharding
+    re-lays the buffers anyway); carries exactly the attributes the
+    circuit breaker's fallback/bisection contract reads (``n``, ``b``,
+    ``snap``, ``start``, ``target``, ``depths``, lazy ``requests``)."""
+
+    __slots__ = (
+        "_requests", "depths", "n", "b", "snap", "start", "target",
+        "depth", "flag",
+    )
+
+    def __init__(self, depths, n, b, snap, start, target, flag, depth):
+        self._requests = None
+        self.depths = depths
+        self.n = n
+        self.b = b
+        self.snap = snap
+        self.start = start
+        self.target = target
+        self.depth = depth
+        self.flag = flag
+
+    @property
+    def requests(self):
+        """Per-item RelationTuples, decoded through the snapshot vocab on
+        first access — only the breaker's host-oracle fallback reads this."""
+        if self._requests is None:
+            self._requests = _decode_ids(
+                self.snap, self.start[: self.n], self.target[: self.n]
+            )
+        return self._requests
+
+    @property
+    def version(self) -> int:
+        return self.snap.version
+
+    def release(self) -> None:
+        """No staging pool on this path — idempotent no-op kept for the
+        breaker/batcher release contract."""
+
+
+class _ShardedLaunched:
+    """A dispatched sharded batch: un-materialized device results. JAX
+    async dispatch returns as soon as the kernel is enqueued; blocking
+    (and overflow escalation) happens in :meth:`ShardedServingEngine.
+    decode_launched`. ``garbage`` stands for a numerically sick chip
+    (the ``device.batch_nan`` fault site): decode returns non-booleans."""
+
+    __slots__ = ("enc", "allowed", "overflow", "garbage")
+
+    def __init__(self, enc, allowed=None, overflow=None, garbage=False):
+        self.enc = enc
+        self.allowed = allowed
+        self.overflow = overflow
+        self.garbage = garbage
+
+
 class ShardedClosureEngine:
     """ClosureCheckEngine's multi-chip sibling: D replicated, boundary
     CSRs node-striped over the mesh's 'edge' axis, batch data-parallel
@@ -391,77 +475,111 @@ class ShardedClosureEngine:
         is_id: Optional[np.ndarray] = None,
         depths: Optional[np.ndarray] = None,
     ) -> np.ndarray:
-        start = np.asarray(start, dtype=np.int64)
+        """allowed: bool[n] for id pairs, through encode/launch/decode.
+        Target kinds come from the snapshot vocab (``is_id`` is accepted
+        for signature parity: a known node's kind is its vocab key's, and
+        an unknown one clamps to the dummy, denied either way)."""
+        del is_id
         if len(start) == 0:
             return np.zeros(0, dtype=bool)
+        enc = self.encode_ids(start, target, depths)
+        return np.asarray(self.decode_launched(self.launch_encoded(enc)))
+
+    # -- split encode/launch/decode --------------------------------------------
+
+    def encode_ids(self, start, target, depths=None):
+        return self.encode_ids_at(
+            self.snapshots.snapshot(), start, target, depths
+        )
+
+    def encode_ids_at(self, snap, start, target, depths=None):
+        start = np.asarray(start, dtype=np.int64)
         target = np.asarray(target, dtype=np.int64)
-        snap = self.snapshots.snapshot()
-        (
-            _snap, ig, m_pad, d,
-            f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v, _bytes,
-        ) = self._residency(snap)
         n = len(start)
-        b = self._bucket_batch(n)
+        b = self._bucket_batch(max(n, 1))
         pn = snap.padded_nodes
         dummy = snap.dummy_node
         gmax = self.global_max_depth
         s = np.full(b, dummy, dtype=np.int32)
         t = np.full(b, dummy, dtype=np.int32)
+        dp = np.ones(b, dtype=np.int32)
         flag = np.zeros(b, dtype=bool)
-        depth = np.ones(b, dtype=np.int32)
         s[:n] = np.where((start < 0) | (start >= pn), dummy, start)
         t[:n] = np.where((target < 0) | (target >= pn), dummy, target)
-        if is_id is None:
-            # infer from the vocab when the caller didn't say
-            is_set = snap.vocab.is_set_array()
-            if len(is_set):
-                safe = np.clip(t[:n], 0, len(is_set) - 1)
-                flag[:n] = ~is_set[safe]
-            else:
-                # empty vocab (boot warmup before any write): every
-                # target is an unknown id, clamped to dummy and denied
-                flag[:n] = True
-        else:
-            flag[:n] = np.asarray(is_id, dtype=bool)[:n]
         if depths is None:
-            depth[:n] = gmax
+            dp[:n] = gmax
         else:
             want = np.asarray(depths, dtype=np.int32)
-            depth[:n] = np.where((want <= 0) | (want > gmax), gmax, want)
-
-        def device_pass(sv, tv, fv, dv, f0_w, l_w):
-            data_sh = NamedSharding(self.mesh, P("data"))
-            a, o = _sharded_closure_check(
-                d, f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v,
-                jax.device_put(sv, data_sh),
-                jax.device_put(tv, data_sh),
-                jax.device_put(fv, data_sh),
-                jax.device_put(dv, data_sh),
-                mesh=self.mesh,
-                n_shards=self.n_edge,
-                m_pad=m_pad,
-                f0_max=f0_w,
-                l_max=l_w,
-                pn=pn,
-            )
-            return np.asarray(a), np.asarray(o)
-
-        allowed, overflow = device_pass(
-            s, t, flag, depth, self.f0_max, self.l_max
+            dp[:n] = np.where((want <= 0) | (want > gmax), gmax, want)
+        is_set = snap.vocab.is_set_array()
+        if len(is_set):
+            safe = np.clip(t[:n], 0, len(is_set) - 1)
+            flag[:n] = ~is_set[safe]
+        else:
+            # empty vocab (boot warmup before any write): every target
+            # is an unknown id — clamped to dummy and denied anyway
+            flag[:n] = True
+        return _ShardedEncodedBatch(
+            dp[:n].tolist(), n, b, snap, s, t, flag, dp
         )
-        allowed = allowed[:n].copy()
-        overflow = overflow[:n]
+
+    def launch_encoded(self, enc: _ShardedEncodedBatch) -> _ShardedLaunched:
+        r = self._residency(enc.snap)
+        allowed, overflow = self._device_pass(
+            r, enc.start, enc.target, enc.flag, enc.depth,
+            self.f0_max, self.l_max,
+        )
+        return _ShardedLaunched(enc, allowed, overflow)
+
+    def decode_launched(self, launched: _ShardedLaunched) -> list[bool]:
+        enc = launched.enc
+        n = enc.n
+        allowed = np.asarray(launched.allowed)[:n].copy()
+        overflow = np.asarray(launched.overflow)[:n]
         self.overflow_stats["rows"] += n
+        r = self._residency(enc.snap)
+        allowed = self._resolve_overflow(
+            r, enc.snap, allowed, overflow,
+            enc.start, enc.target, enc.flag, enc.depth, n,
+        )
+        return allowed.tolist()
+
+    def _device_pass(self, r, sv, tv, fv, dv, f0_w, l_w):
+        """Dispatch the sharded kernel; returns un-materialized device
+        arrays (async — materialization blocks in the caller)."""
+        (
+            snap, _ig, m_pad, d,
+            f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v, _bytes,
+        ) = r
+        data_sh = NamedSharding(self.mesh, P("data"))
+        return _sharded_closure_check(
+            d, f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v,
+            jax.device_put(sv, data_sh),
+            jax.device_put(tv, data_sh),
+            jax.device_put(fv, data_sh),
+            jax.device_put(dv, data_sh),
+            mesh=self.mesh,
+            n_shards=self.n_edge,
+            m_pad=m_pad,
+            f0_max=f0_w,
+            l_max=l_w,
+            pn=snap.padded_nodes,
+        )
+
+    def _resolve_overflow(
+        self, r, snap, allowed, overflow, s, t, flag, depth, n
+    ) -> np.ndarray:
+        """Rows whose fan-out overflowed the narrow pass: a SECOND device
+        pass at escalated gather widths (a user in hundreds of groups is
+        ordinary in team-heavy graphs), then the exact host oracle for
+        rows overflowing those too (dummy/unknown endpoints decode to
+        inert empties the oracle denies, matching the clamp semantics).
+        ``overflow_stats`` counts both tiers."""
         if overflow.any():
-            # wide fan-out rows: SECOND device pass at escalated gather
-            # widths (a user in hundreds of groups is ordinary in
-            # team-heavy graphs — VERDICT r4 weak #6; the old host-oracle
-            # funnel made the hot tail single-threaded Python). Only rows
-            # overflowing the escalated widths too fall back to the exact
-            # host oracle, and that rate is tracked for the bench/dryrun.
             idxs = np.nonzero(overflow)[0]
             self.overflow_stats["escalated"] += len(idxs)
             k = len(idxs)
+            dummy = snap.dummy_node
             b2 = self._bucket_batch(k)
             s2 = np.full(b2, dummy, dtype=np.int32)
             t2 = np.full(b2, dummy, dtype=np.int32)
@@ -469,41 +587,21 @@ class ShardedClosureEngine:
             depth2 = np.ones(b2, dtype=np.int32)
             s2[:k], t2[:k] = s[idxs], t[idxs]
             flag2[:k], depth2[:k] = flag[idxs], depth[idxs]
-            allowed2, overflow2 = device_pass(
-                s2, t2, flag2, depth2,
+            allowed2, overflow2 = self._device_pass(
+                r, s2, t2, flag2, depth2,
                 self.f0_max_escalated, self.l_max_escalated,
             )
-            allowed[idxs] = allowed2[:k]
+            allowed[idxs] = np.asarray(allowed2)[:k]
             overflow = np.zeros(n, dtype=bool)
-            overflow[idxs[overflow2[:k]]] = True
+            overflow[idxs[np.asarray(overflow2)[:k]]] = True
         if overflow.any():
-            # beyond even the escalated widths: exact host fallback (same
-            # contract as the single-chip engine's width-capped numpy
-            # path). Dummy/unknown endpoints decode to inert empties —
-            # the oracle denies them, matching the clamp semantics.
             fb = self.fallback_engine()
             idxs = np.nonzero(overflow)[0]
             self.overflow_stats["host_fallback"] += len(idxs)
-            vocab = snap.vocab
-            n_live = min(len(vocab), dummy)
-            reqs = []
-            for i in idxs:
-                si, ti = int(s[i]), int(t[i])
-                ns, obj, rel = (
-                    vocab.key(si) if si < n_live else ("", "", "")
-                )
-                subject = (
-                    vocab.subject_of(ti)
-                    if ti < n_live
-                    else SubjectID(id="")
-                )
-                reqs.append(
-                    RelationTuple(
-                        namespace=ns, object=obj, relation=rel,
-                        subject=subject,
-                    )
-                )
-            res = fb.batch_check(reqs, depths=[int(depth[i]) for i in idxs])
+            reqs = _decode_ids(snap, s[idxs], t[idxs])
+            res = fb.batch_check(
+                reqs, depths=[int(depth[i]) for i in idxs]
+            )
             allowed[idxs] = res
         return allowed
 
@@ -528,14 +626,11 @@ class ShardedClosureEngine:
         t_ids = snap.vocab.lookup_bulk(tkeys)
         start = np.where((s_ids < 0) | (s_ids >= pn), dummy, s_ids)
         target = np.where((t_ids < 0) | (t_ids >= pn), dummy, t_ids)
-        is_id = np.fromiter(
-            (len(k) == 1 for k in tkeys), bool, count=len(requests)
-        )
         if depths is not None:
             want = np.asarray(depths, dtype=np.int32)
         else:
             want = np.full(len(requests), max_depth, dtype=np.int32)
-        return self.check_ids(start, target, is_id, want).tolist()
+        return self.check_ids(start, target, depths=want).tolist()
 
     def subject_is_allowed(
         self, requested: RelationTuple, max_depth: int = 0

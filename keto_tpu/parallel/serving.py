@@ -5,12 +5,12 @@ first-class serving path (not a bench parity oracle).
 ShardedClosureEngine` with everything ``CheckBatcher`` and the circuit
 breaker need to route live check traffic into the mesh:
 
-- the split ``encode_ids``/``launch_encoded``/``decode_launched`` API
-  (same contract as ``DeviceCheckEngine``), so the batcher's encoded and
-  columnar paths, the breaker's host-oracle fallback, and the OOM
-  bisection all work unchanged. Overflow rows (fan-out beyond the
-  escalated gather widths) are re-answered by the exact host oracle —
-  the same funnel the breaker uses for failed batches;
+- the fault sites of the split ``encode_ids``/``launch_encoded``/
+  ``decode_launched`` API (inherited; every check goes through it, so
+  the batcher's encoded path, the breaker's host-oracle fallback, the
+  OOM bisection and the compile quarantine all guard it): the
+  ``device.*`` and ``shard.*`` sites fire in its launch, and a garbage
+  launch decodes to NaNs the breaker refuses;
 - residency that survives snapshot rebuilds: the replicated interior
   distance matrix D is kept as a host uint8 bitset and updated with the
   semiring dirty-row machinery (``update_closure_bitset``) on
@@ -33,124 +33,31 @@ the mesh has more than one device (see driver/registry.py).
 
 from __future__ import annotations
 
-import threading
-import time
-from typing import Optional, Sequence
+from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.check import DEFAULT_MAX_DEPTH
-from ..engine.device import _decode_ids
 from ..engine.semiring import build_closure_bitset, update_closure_bitset
 from ..faults import FAULTS
 from ..graph.interior import build_interior
 from ..graph.snapshot import GraphSnapshot, SnapshotManager
 from .closure_sharded import (
     ShardedClosureEngine,
-    _sharded_closure_check,
+    _ShardedEncodedBatch,
+    _ShardedLaunched,
     _stripe_csr,
     _stripe_vector,
 )
-from .sharded import make_mesh
-
-
-class _ShardedEncodedBatch:
-    """A pure-id batch parked between encode and launch on the sharded
-    path. Plain numpy arrays (no staging pool — the mesh upload sharding
-    re-lays the buffers anyway); carries exactly the attributes the
-    circuit breaker's fallback/bisection contract reads (``n``, ``b``,
-    ``snap``, ``start``, ``target``, ``depths``, lazy ``requests``)."""
-
-    __slots__ = (
-        "_requests", "_cols", "depths", "deadlines", "n", "b", "snap",
-        "start", "target", "depth", "flag",
-    )
-
-    def __init__(self, depths, n, b, snap, start, target, flag, depth):
-        self._requests = None
-        self._cols = None
-        self.depths = depths
-        self.deadlines = None
-        self.n = n
-        self.b = b
-        self.snap = snap
-        self.start = start
-        self.target = target
-        self.depth = depth
-        self.flag = flag
-
-    @property
-    def requests(self):
-        """Per-item RelationTuples, decoded through the snapshot vocab on
-        first access — only the breaker's host-oracle fallback reads this."""
-        if self._requests is None:
-            self._requests = _decode_ids(
-                self.snap, self.start[: self.n], self.target[: self.n]
-            )
-        return self._requests
-
-    @property
-    def version(self) -> int:
-        return self.snap.version
-
-    def keys(self) -> list[tuple[int, int, int]]:
-        n = self.n
-        return list(
-            zip(
-                self.start[:n].tolist(),
-                self.target[:n].tolist(),
-                self.depth[:n].tolist(),
-            )
-        )
-
-    def compact(self, keep: Sequence[int]) -> None:
-        m = len(keep)
-        if m == self.n:
-            return
-        idx = np.asarray(keep, dtype=np.int64)
-        self.start[:m] = self.start[idx]
-        self.target[:m] = self.target[idx]
-        self.depth[:m] = self.depth[idx]
-        self.flag[:m] = self.flag[idx]
-        dummy = self.snap.dummy_node
-        self.start[m : self.n] = dummy
-        self.target[m : self.n] = dummy
-        self.depth[m : self.n] = 1
-        self.flag[m : self.n] = False
-        if self._requests is not None:
-            self._requests = [self._requests[i] for i in keep]
-        if self.depths is not None:
-            self.depths = [self.depths[i] for i in keep]
-        if self.deadlines is not None:
-            self.deadlines = [self.deadlines[i] for i in keep]
-        self.n = m
-
-    def release(self) -> None:
-        """No staging pool on this path — idempotent no-op kept for the
-        breaker/batcher release contract."""
-
-
-class _ShardedLaunched:
-    """A dispatched sharded batch: un-materialized device results. JAX
-    async dispatch returns as soon as the kernel is enqueued; blocking
-    (and overflow escalation) happens in :meth:`ShardedServingEngine.
-    decode_launched`."""
-
-    __slots__ = ("enc", "allowed", "overflow")
-
-    def __init__(self, enc, allowed, overflow):
-        self.enc = enc
-        self.allowed = allowed
-        self.overflow = overflow
 
 
 class ShardedServingEngine(ShardedClosureEngine):
     """The serving wrapper around the edge-partitioned mesh closure
-    kernel. See the module docstring for the contract; the query math is
-    entirely inherited — this class owns residency lifetime, the split
-    batch API, escalation accounting, and the admission/metrics seams."""
+    kernel. See the module docstring for the contract; the query path is
+    entirely inherited — this class owns residency lifetime, the launch's
+    fault sites, escalation accounting, and the admission/metrics seams."""
 
     def __init__(
         self,
@@ -591,136 +498,30 @@ class ShardedServingEngine(ShardedClosureEngine):
                     budget=self.escalation_budget,
                 )
 
-    def check_ids(
-        self,
-        start: np.ndarray,
-        target: np.ndarray,
-        is_id: Optional[np.ndarray] = None,
-        depths: Optional[np.ndarray] = None,
-    ) -> np.ndarray:
-        before = dict(self.overflow_stats)
-        out = super().check_ids(start, target, is_id, depths)
-        self._note_escalations(before, len(out))
-        return out
-
-    # -- split encode/launch/decode (the CheckBatcher + breaker seam) ----------
-
-    def encode_ids(self, start, target, depths=None):
-        return self.encode_ids_at(
-            self.snapshots.snapshot(), start, target, depths
-        )
-
-    def encode_ids_at(self, snap, start, target, depths=None):
-        start = np.asarray(start, dtype=np.int64)
-        target = np.asarray(target, dtype=np.int64)
-        n = len(start)
-        b = self._bucket_batch(max(n, 1))
-        pn = snap.padded_nodes
-        dummy = snap.dummy_node
-        gmax = self.global_max_depth
-        s = np.full(b, dummy, dtype=np.int32)
-        t = np.full(b, dummy, dtype=np.int32)
-        dp = np.ones(b, dtype=np.int32)
-        flag = np.zeros(b, dtype=bool)
-        s[:n] = np.where((start < 0) | (start >= pn), dummy, start)
-        t[:n] = np.where((target < 0) | (target >= pn), dummy, target)
-        if depths is None:
-            dp[:n] = gmax
-        else:
-            want = np.asarray(depths, dtype=np.int32)
-            dp[:n] = np.where((want <= 0) | (want > gmax), gmax, want)
-        is_set = snap.vocab.is_set_array()
-        if len(is_set):
-            safe = np.clip(t[:n], 0, len(is_set) - 1)
-            flag[:n] = ~is_set[safe]
-        else:
-            # empty vocab (boot warmup before any write): every target
-            # is an unknown id — clamped to dummy and denied anyway
-            flag[:n] = True
-        return _ShardedEncodedBatch(
-            dp[:n].tolist(), n, b, snap, s, t, flag, dp
-        )
+    # -- launch/decode (the CheckBatcher + breaker seam) ------------------------
 
     def launch_encoded(self, enc: _ShardedEncodedBatch) -> _ShardedLaunched:
+        # fault sites: stand-ins for an XLA compile failure, an HBM
+        # out-of-memory, a shape-specific compile failure, a lost device,
+        # a slow dispatch and a numerically sick chip returning garbage —
+        # the typed recovery policies in engine/fallback.py and the
+        # device supervisor in driver/registry.py are tested against
+        # exactly these
+        FAULTS.fire("device.compile_error")
+        FAULTS.fire("device.oom")
+        FAULTS.fire("device.compile_fail")
+        FAULTS.fire("device.lost")
+        FAULTS.maybe_sleep("device.slow")
         FAULTS.fire("shard.launch_fail")
         FAULTS.maybe_sleep("shard.launch_slow")
-        r = self._residency(enc.snap)
-        allowed, overflow = self._device_pass(
-            r, enc.start, enc.target, enc.flag, enc.depth,
-            self.f0_max, self.l_max,
-        )
-        return _ShardedLaunched(enc, allowed, overflow)
+        if FAULTS.should_fire("device.batch_nan"):
+            return _ShardedLaunched(enc, garbage=True)
+        return super().launch_encoded(enc)
 
     def decode_launched(self, launched: _ShardedLaunched) -> list[bool]:
-        enc = launched.enc
-        n = enc.n
-        allowed = np.asarray(launched.allowed)[:n].copy()
-        overflow = np.asarray(launched.overflow)[:n]
+        if launched.garbage:
+            return [float("nan")] * launched.enc.n
         before = dict(self.overflow_stats)
-        self.overflow_stats["rows"] += n
-        r = self._residency(enc.snap)
-        allowed = self._resolve_overflow(
-            r, enc.snap, allowed, overflow,
-            enc.start, enc.target, enc.flag, enc.depth, n,
-        )
-        self._note_escalations(before, n)
-        return allowed.tolist()
-
-    def _device_pass(self, r, sv, tv, fv, dv, f0_w, l_w):
-        """Dispatch the sharded kernel; returns un-materialized device
-        arrays (async — materialization blocks in the caller)."""
-        (
-            snap, _ig, m_pad, d,
-            f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v, _bytes,
-        ) = r
-        data_sh = NamedSharding(self.mesh, P("data"))
-        return _sharded_closure_check(
-            d, f0_ip, f0_v, l_ip, l_v, int_idx, out_ip, out_v,
-            jax.device_put(sv, data_sh),
-            jax.device_put(tv, data_sh),
-            jax.device_put(fv, data_sh),
-            jax.device_put(dv, data_sh),
-            mesh=self.mesh,
-            n_shards=self.n_edge,
-            m_pad=m_pad,
-            f0_max=f0_w,
-            l_max=l_w,
-            pn=snap.padded_nodes,
-        )
-
-    def _resolve_overflow(
-        self, r, snap, allowed, overflow, s, t, flag, depth, n
-    ) -> np.ndarray:
-        """Same two-tier overflow contract as the inherited check_ids:
-        escalated-width second device pass, then the exact host oracle
-        for the residue (dummy/unknown endpoints decode to inert empties
-        the oracle denies)."""
-        if overflow.any():
-            idxs = np.nonzero(overflow)[0]
-            self.overflow_stats["escalated"] += len(idxs)
-            k = len(idxs)
-            dummy = snap.dummy_node
-            b2 = self._bucket_batch(k)
-            s2 = np.full(b2, dummy, dtype=np.int32)
-            t2 = np.full(b2, dummy, dtype=np.int32)
-            flag2 = np.zeros(b2, dtype=bool)
-            depth2 = np.ones(b2, dtype=np.int32)
-            s2[:k], t2[:k] = s[idxs], t[idxs]
-            flag2[:k], depth2[:k] = flag[idxs], depth[idxs]
-            allowed2, overflow2 = self._device_pass(
-                r, s2, t2, flag2, depth2,
-                self.f0_max_escalated, self.l_max_escalated,
-            )
-            allowed[idxs] = np.asarray(allowed2)[:k]
-            overflow = np.zeros(n, dtype=bool)
-            overflow[idxs[np.asarray(overflow2)[:k]]] = True
-        if overflow.any():
-            fb = self.fallback_engine()
-            idxs = np.nonzero(overflow)[0]
-            self.overflow_stats["host_fallback"] += len(idxs)
-            reqs = _decode_ids(snap, s[idxs], t[idxs])
-            res = fb.batch_check(
-                reqs, depths=[int(depth[i]) for i in idxs]
-            )
-            allowed[idxs] = res
-        return allowed
+        out = super().decode_launched(launched)
+        self._note_escalations(before, launched.enc.n)
+        return out

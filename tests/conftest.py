@@ -20,7 +20,28 @@ if "xla_force_host_platform_device_count" not in flags:
 import pytest  # noqa: E402
 
 from keto_tpu.namespace import MemoryNamespaceManager  # noqa: E402
+from keto_tpu.relationtuple import RelationTuple  # noqa: E402
 from keto_tpu.store import InMemoryTupleStore  # noqa: E402
+
+
+def random_store(rng, n_objects, n_users, n_edges, n_rel=3):
+    """Random tuple graph with a healthy share of subject-set indirections
+    (shared by the engine parity suites: ``from conftest import
+    random_store``)."""
+    store = InMemoryTupleStore()
+    tuples = set()
+    for _ in range(n_edges):
+        obj = f"o{rng.integers(n_objects)}"
+        rel = f"r{rng.integers(n_rel)}"
+        if rng.random() < 0.45:
+            sub = f"n:o{rng.integers(n_objects)}#r{rng.integers(n_rel)}"
+        else:
+            sub = f"u{rng.integers(n_users)}"
+        tuples.add(f"n:{obj}#{rel}@({sub})")
+    store.write_relation_tuples(
+        *(RelationTuple.from_string(s) for s in tuples)
+    )
+    return store
 
 
 @pytest.fixture

@@ -295,8 +295,9 @@ class TestAttributionEndpoint:
         from keto_tpu.client import GrpcClient, RestClient
         from keto_tpu.faults import FAULTS
 
-        # both slowness seams, as in the bench tail phase: device.slow
-        # fires on device query paths, replica.slow on any
+        # both slowness seams, as in the bench tail phase: replica.slow
+        # fires on any path; device.slow only in the sharded serving
+        # engine's launch, which this server (no engine.sharding) never runs
         FAULTS.arm_slow("device.slow", sleep_ms=20, times=3)
         FAULTS.arm_slow("replica.slow", sleep_ms=20, times=3)
         try:

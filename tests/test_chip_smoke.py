@@ -72,7 +72,7 @@ def test_reference_matches_check_engine_on_random_graphs():
     from keto_tpu.relationtuple import RelationTuple
     from keto_tpu.store import ColumnarTupleStore
 
-    from test_device_engines import random_store
+    from conftest import random_store
 
     for seed in range(3):
         mem = random_store(np.random.default_rng(seed), 20, 10, 200)

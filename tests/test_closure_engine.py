@@ -1,20 +1,21 @@
 """ClosureCheckEngine vs the host oracle: the gather-only closure path must
-agree bit-for-bit with host BFS on every graph — the same scenario matrix the
-lockstep device engines run (reference internal/check/engine_test.go:45-581),
-plus closure-specific edges: overflow fallback, interior-limit fallback, and
-write-driven closure rebuilds."""
+agree bit-for-bit with host BFS on every graph — the scenario matrix of
+reference internal/check/engine_test.go:45-581 at each query placement
+(host queries, device queries, and the interior walk), plus closure-specific
+edges: overflow fallback, interior-limit fallback, and write-driven closure
+rebuilds."""
 
 import numpy as np
 import pytest
 
 from keto_tpu.engine import CheckEngine
-from keto_tpu.engine.closure import ClosureCheckEngine
+from keto_tpu.engine.closure import ClosureCheckEngine, _WalkArtifacts
 from keto_tpu.graph import SnapshotManager
 from keto_tpu.graph.interior import build_interior
 from keto_tpu.relationtuple import RelationTuple, SubjectSet
 from keto_tpu.store import InMemoryTupleStore
 
-from test_device_engines import random_store
+from conftest import random_store
 
 
 def t(s: str) -> RelationTuple:
@@ -34,79 +35,108 @@ def make_engines(store, max_depth=5, **kw):
     )
 
 
+# where a batch is answered: the closure's D queried by the host kernels or
+# on the device, or the interior's CSR walked on the device (a limit below
+# every interior size, the empty one included, forces the walk)
+PLACEMENTS = {
+    "host": dict(query_mode="host"),
+    "device": dict(query_mode="device"),
+    "walk": dict(query_mode="device", interior_limit=-1),
+}
+
+
+@pytest.fixture(params=list(PLACEMENTS))
+def placed(request):
+    """``placed(store, max_depth=5)`` -> (oracle, engine) at this test's
+    placement; a walk case fails unless every engine it built walks."""
+    built = []
+
+    def make(store, max_depth=5, **kw):
+        oracle, eng = make_engines(
+            store, max_depth, **PLACEMENTS[request.param], **kw
+        )
+        built.append(eng)
+        return oracle, eng
+
+    yield make
+    if request.param == "walk":
+        for eng in built:
+            assert isinstance(eng._state, _WalkArtifacts)
+
+
 class TestClosureScenarios:
-    def test_direct_inclusion(self, store):
+    def test_direct_inclusion(self, store, placed):
         store.write_relation_tuples(t("n:obj#access@alice"))
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert eng.subject_is_allowed(t("n:obj#access@alice"))
         assert not eng.subject_is_allowed(t("n:obj#access@bob"))
 
-    def test_indirect_inclusion_two_levels(self, store):
+    def test_indirect_inclusion_two_levels(self, store, placed):
         store.write_relation_tuples(
             t("n:obj#access@(n:org#member)"),
             t("n:org#member@(n:team#member)"),
             t("n:team#member@alice"),
         )
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert eng.subject_is_allowed(t("n:obj#access@alice"))
         assert eng.subject_is_allowed(t("n:obj#access@(n:team#member)"))
         assert not eng.subject_is_allowed(t("n:obj#access@mallory"))
 
-    def test_wrong_object_or_relation(self, store):
+    def test_wrong_object_or_relation(self, store, placed):
         store.write_relation_tuples(t("n:obj#access@alice"))
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert not eng.subject_is_allowed(t("n:other#access@alice"))
         assert not eng.subject_is_allowed(t("n:obj#write@alice"))
         assert not eng.subject_is_allowed(t("other:obj#access@alice"))
 
-    def test_circular_tuples_terminate(self, store):
+    def test_circular_tuples_terminate(self, store, placed):
         store.write_relation_tuples(t("n:a#r@(n:b#r)"), t("n:b#r@(n:a#r)"))
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert not eng.subject_is_allowed(t("n:a#r@alice"))
         # the sets themselves are mutually reachable (cycle of length 2)
         assert eng.subject_is_allowed(t("n:a#r@(n:a#r)"))
         assert eng.subject_is_allowed(t("n:a#r@(n:b#r)"))
 
-    def test_depth_budget(self, store):
+    def test_depth_budget(self, store, placed):
         store.write_relation_tuples(
             t("n:obj#r@(n:s1#m)"),
             t("n:s1#m@(n:s2#m)"),
             t("n:s2#m@(n:s3#m)"),
             t("n:s3#m@alice"),
         )
-        _, eng = make_engines(store, max_depth=10)
+        _, eng = placed(store, max_depth=10)
         req = t("n:obj#r@alice")
         assert not eng.subject_is_allowed(req, max_depth=3)
         assert eng.subject_is_allowed(req, max_depth=4)
         assert eng.subject_is_allowed(req, max_depth=0)  # clamps to global
         assert eng.subject_is_allowed(req, max_depth=99)
 
-    def test_global_max_depth_precedence(self, store):
+    def test_global_max_depth_precedence(self, store, placed):
         store.write_relation_tuples(
             t("n:obj#r@(n:s1#m)"),
             t("n:s1#m@(n:s2#m)"),
             t("n:s2#m@alice"),
         )
-        _, eng = make_engines(store, max_depth=2)
+        _, eng = placed(store, max_depth=2)
         assert not eng.subject_is_allowed(t("n:obj#r@alice"), max_depth=50)
 
-    def test_subject_set_exact_match_semantics(self, store):
+    def test_subject_set_exact_match_semantics(self, store, placed):
         store.write_relation_tuples(t("n:obj#r@alice"))
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert not eng.subject_is_allowed(t("n:obj#r@(n:obj#r)"))
 
-    def test_set_target_depth_one(self, store):
+    def test_set_target_depth_one(self, store, placed):
         # direct set-to-set edge must be allowed at depth 1 exactly
         store.write_relation_tuples(t("n:obj#r@(n:grp#m)"), t("n:grp#m@u"))
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         assert eng.subject_is_allowed(t("n:obj#r@(n:grp#m)"), max_depth=1)
 
-    def test_unknown_everything(self, store):
-        _, eng = make_engines(store)
+    def test_unknown_everything(self, store, placed):
+        _, eng = placed(store)
         assert not eng.subject_is_allowed(t("no:thing#here@nobody"))
 
-    def test_write_visibility_rebuilds_closure(self, store):
-        _, eng = make_engines(store)
+    def test_write_visibility_rebuilds_closure(self, store, placed):
+        _, eng = placed(store)
         req = t("n:obj#r@alice")
         assert not eng.subject_is_allowed(req)
         store.write_relation_tuples(req)
@@ -118,16 +148,36 @@ class TestClosureScenarios:
         store.write_relation_tuples(t("n:g#m@alice"))
         assert eng.subject_is_allowed(req)
 
-    def test_batch_mixed_depths(self, store):
+    def test_batch_mixed_depths(self, store, placed):
         store.write_relation_tuples(
             t("n:obj#r@(n:s1#m)"),
             t("n:s1#m@alice"),
             t("n:obj#r@bob"),
         )
-        _, eng = make_engines(store)
+        _, eng = placed(store)
         reqs = [t("n:obj#r@alice"), t("n:obj#r@bob"), t("n:obj#r@eve")]
         assert eng.batch_check(reqs, depths=[1, 1, 5]) == [False, True, False]
         assert eng.batch_check(reqs, depths=[2, 1, 5]) == [True, True, False]
+
+    def test_exact_depth_boundary(self, store, placed):
+        """A path of length d is allowed at depth d and denied at d-1,
+        with the request's depth equal to the global maximum."""
+        store.write_relation_tuples(t("n:a#r@(n:b#r)"), t("n:b#r@u"))
+        _, eng = placed(store, max_depth=2)
+        assert eng.subject_is_allowed(t("n:a#r@u"), max_depth=2)
+        assert not eng.subject_is_allowed(t("n:a#r@u"), max_depth=1)
+
+    def test_start_equals_target_needs_real_path(self, store, placed):
+        """set@same-set is allowed only through an actual cycle: the start
+        itself, at distance 0, does not satisfy the check."""
+        store.write_relation_tuples(
+            t("n:obj#r@alice"), t("n:obj#r@(n:grp#m)"), t("n:grp#m@bob")
+        )
+        _, eng = placed(store)
+        assert not eng.subject_is_allowed(t("n:obj#r@(n:obj#r)"))
+        assert not eng.subject_is_allowed(t("n:grp#m@(n:grp#m)"))
+        store.write_relation_tuples(t("n:grp#m@(n:obj#r)"))
+        assert eng.subject_is_allowed(t("n:grp#m@(n:grp#m)"))
 
 
 def _random_requests(rng, n_objects, n_users, k=64):
@@ -145,21 +195,21 @@ def _random_requests(rng, n_objects, n_users, k=64):
 
 class TestClosureMatchesOracle:
     @pytest.mark.parametrize("seed", range(6))
-    def test_random_graphs(self, seed):
+    def test_random_graphs(self, seed, placed):
         rng = np.random.default_rng(seed)
         store = random_store(rng, n_objects=15, n_users=10, n_edges=120)
         for depth in (1, 2, 3, 5, 8):
-            host, eng = make_engines(store, max_depth=depth)
+            host, eng = placed(store, max_depth=depth)
             reqs = _random_requests(rng, 15, 10)
             expect = [host.subject_is_allowed(r) for r in reqs]
             got = eng.batch_check(reqs)
             assert got == expect, f"seed={seed} depth={depth}"
 
     @pytest.mark.parametrize("seed", range(3))
-    def test_random_graphs_per_request_depths(self, seed):
+    def test_random_graphs_per_request_depths(self, seed, placed):
         rng = np.random.default_rng(seed + 50)
         store = random_store(rng, n_objects=12, n_users=8, n_edges=90)
-        host, eng = make_engines(store, max_depth=8)
+        host, eng = placed(store, max_depth=8)
         reqs = _random_requests(rng, 12, 8)
         depths = [int(rng.integers(1, 9)) for _ in reqs]
         expect = [

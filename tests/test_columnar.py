@@ -9,7 +9,7 @@ and to the host oracle — through every layer it touches.
   and CheckBatcher.check_batch_encoded, all against CheckEngine (oracle)
 - breaker-open fallback: an encoded/columnar batch re-answered by the
   host oracle from lazily materialized tuples, answers unchanged
-- encoded-cache correctness across writes (snapshot-version stamps)
+- result-cache correctness across writes (snapshot-version stamps)
 - live-server REST + gRPC columnar transports: parity with the per-tuple
   transport, malformed bodies rejected with 400/INVALID_ARGUMENT
 """
@@ -20,6 +20,7 @@ import threading
 
 import grpc
 import httpx
+import jax
 import numpy as np
 import pytest
 
@@ -28,11 +29,12 @@ from keto_tpu.api.services import CheckServiceStub
 from keto_tpu.driver import Config, Registry
 from keto_tpu.engine.batcher import CheckBatcher
 from keto_tpu.engine.check import CheckEngine
+from keto_tpu.engine.cache import CheckResultCache
 from keto_tpu.engine.closure import ClosureCheckEngine
-from keto_tpu.engine.device import DeviceCheckEngine
 from keto_tpu.engine.fallback import DeviceFallbackEngine
 from keto_tpu.faults import FAULTS
 from keto_tpu.graph import SnapshotManager
+from keto_tpu.parallel import ShardedServingEngine, make_mesh
 from keto_tpu.relationtuple import (
     CheckColumns,
     RelationTuple,
@@ -181,11 +183,9 @@ class TestEngineParityFuzz:
         store = _random_store(rng)
         snaps = SnapshotManager(store)
         oracle = CheckEngine(store, max_depth=5)
-        device = DeviceCheckEngine(snaps, max_depth=5)
-        closure = ClosureCheckEngine(snaps, max_depth=5)
-        batcher = CheckBatcher(
-            device, window_s=0, encoded_cache_size=512
-        )
+        device = ClosureCheckEngine(snaps, max_depth=5, query_mode="device")
+        closure = ClosureCheckEngine(snaps, max_depth=5, query_mode="host")
+        batcher = CheckBatcher(device, window_s=0)
         try:
             for _round in range(3):
                 reqs = _random_requests(rng, 48)
@@ -221,8 +221,6 @@ class TestEngineParityFuzz:
         store = _random_store(rng)
         snaps = SnapshotManager(store)
         oracle = CheckEngine(store, max_depth=5)
-        from keto_tpu.engine.cache import CheckResultCache
-
         store_ref = store
         batcher = CheckBatcher(
             ClosureCheckEngine(snaps, max_depth=5), window_s=0,
@@ -257,7 +255,10 @@ class TestBreakerFallbackParity:
         store = _random_store(rng)
         snaps = SnapshotManager(store)
         fb = DeviceFallbackEngine(
-            DeviceCheckEngine(snaps, max_depth=5),
+            ShardedServingEngine(
+                snaps, mesh=make_mesh(jax.devices()[:2], data=1),
+                max_depth=5,
+            ),
             lambda: CheckEngine(store, max_depth=5),
             failure_threshold=1,
             cooldown_s=60.0,
@@ -310,16 +311,21 @@ class TestBreakerFallbackParity:
             b.close()
 
 
-class TestEncodedCacheFreshness:
+class TestResultCacheFreshness:
     def test_cache_does_not_serve_stale_answers_across_writes(self):
+        """The result cache the served columnar path probes is stamped
+        with the store version: a write makes every cached row a miss."""
         store = InMemoryTupleStore()
         store.write_relation_tuples(
             _t("n:doc0#view@(n:team0#member)"),
             _t("n:team0#member@alice"),
         )
         snaps = SnapshotManager(store)
-        engine = DeviceCheckEngine(snaps, max_depth=5)
-        b = CheckBatcher(engine, window_s=0, encoded_cache_size=512)
+        engine = ClosureCheckEngine(snaps, max_depth=5, query_mode="device")
+        b = CheckBatcher(
+            engine, window_s=0, cache=CheckResultCache(512),
+            version_fn=lambda: store.version,
+        )
         try:
             cols = CheckColumns.from_tuples(
                 [_t("n:doc0#view@alice"), _t("n:doc0#view@bob")]

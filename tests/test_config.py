@@ -1,6 +1,12 @@
-"""Config provider regression tests (falsy defaults, env/flag precedence)."""
+"""Config provider regression tests (falsy defaults, env/flag precedence,
+the engine modes a config may name)."""
+
+import json
+
+import pytest
 
 from keto_tpu.driver.config import Config
+from keto_tpu.utils.errors import ErrMalformedInput
 
 
 class TestConfigDefaults:
@@ -96,3 +102,30 @@ class TestShardingConfig:
                 {"engine": {"sharding": {"escalation_budget": 2.0}}},
                 CONFIG_SCHEMA,
             )
+
+
+class TestEngineModes:
+    @pytest.mark.parametrize("mode", ["host", "auto", "closure"])
+    def test_served_modes_load(self, tmp_path, mode):
+        path = tmp_path / "keto.yml"
+        path.write_text(f"engine:\n  mode: {mode}\n")
+        assert Config(config_file=str(path), env={}).engine_mode() == mode
+
+    @pytest.mark.parametrize(
+        "engine",
+        [
+            {"mode": "dense"},
+            {"mode": "device"},
+            {"mode": "scatter"},
+            {"mode": "packed"},
+            {"mode": "sharded"},
+            {"mesh": {"data": 1, "edge": 0}},
+        ],
+    )
+    def test_removed_engine_settings_refused_at_load(self, tmp_path, engine):
+        """A config file naming a deleted engine mode or one of its
+        options fails to load with the schema error."""
+        path = tmp_path / "keto.json"
+        path.write_text(json.dumps({"engine": engine}))
+        with pytest.raises(ErrMalformedInput, match="invalid configuration"):
+            Config(config_file=str(path), env={})

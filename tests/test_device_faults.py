@@ -5,8 +5,8 @@ engine/hbm.py, driver/registry.py DeviceSupervisor).
   transient), including injected-fault sites
 - OOM batch bisection: parity-exact against the unsplit host oracle under
   fuzzed batch sizes and armed-OOM counts (random split trees), unicode
-  vocab, and encoded-cache-hit interleaving — with ZERO host-fallback
-  escalations
+  vocab, and batches arriving through the batcher's encoded path — with
+  ZERO host-fallback escalations
 - compile-failure quarantine: the failing (bucket, snapshot) shape routes
   to the oracle without opening the circuit for every other shape
 - device-lost -> supervised backend failover -> bounded recovery, end to
@@ -22,10 +22,10 @@ import random
 import threading
 import time
 
+import jax
 import pytest
 
 from keto_tpu.engine import CheckEngine
-from keto_tpu.engine.device import DeviceCheckEngine
 from keto_tpu.engine.fallback import (
     DeviceFallbackEngine,
     classify_device_error,
@@ -33,6 +33,7 @@ from keto_tpu.engine.fallback import (
 from keto_tpu.engine.hbm import HbmAdmission
 from keto_tpu.faults import FAULTS, FaultInjected
 from keto_tpu.graph import SnapshotManager
+from keto_tpu.parallel import ShardedServingEngine, make_mesh
 from keto_tpu.relationtuple import RelationTuple
 from keto_tpu.store import InMemoryTupleStore
 
@@ -49,8 +50,12 @@ def _clean_faults():
 
 
 def make_breaker(store, **kw):
+    """The breaker around the sharded serving engine on a two-device mesh:
+    the engine whose launch carries the ``device.*`` fault sites."""
     mgr = SnapshotManager(store)
-    dev = DeviceCheckEngine(mgr, max_depth=5, mode="scatter")
+    dev = ShardedServingEngine(
+        mgr, mesh=make_mesh(jax.devices()[:2], data=1), max_depth=5
+    )
     breaker = DeviceFallbackEngine(
         dev,
         fallback_factory=lambda: CheckEngine(store, max_depth=5),
@@ -75,6 +80,14 @@ def _seed_unicode_graph(store):
     tuples.append(t("n:группа1#member@ユーザー1"))
     tuples.append(t("n:plain#view@böb"))
     store.write_relation_tuples(*tuples)
+
+
+def _encode(breaker, reqs):
+    """Vocab-encode requests and park them as the engine's encoded batch
+    (``encode_ids``, the batcher's encoded path)."""
+    snap = breaker.snapshots.snapshot()
+    start, target = snap.encode_requests(reqs)
+    return breaker.encode_ids(start, target)
 
 
 def _request_pool(rng, k):
@@ -122,7 +135,7 @@ class TestClassification:
 
 class TestOomBisection:
     def _roundtrip(self, breaker, reqs):
-        enc = breaker.encode_batch(reqs)
+        enc = _encode(breaker, reqs)
         launched = breaker.launch_encoded(enc)
         return [bool(v) for v in breaker.decode_launched(launched)]
 
@@ -180,11 +193,11 @@ class TestOomBisection:
         FAULTS.reset()
 
     def test_cache_hit_interleaving(self):
-        """Encoded-cache hits compact the batch before launch; the OOM
-        bisection of the compacted MISS rows must still merge back into
-        a parity-exact full answer."""
+        """Batches reach the OOM bisection through the batcher's encoded
+        path (this engine keeps no encoded-request cache, so a batch of
+        repeated and fresh rows launches whole): the bisected halves must
+        merge back into a parity-exact full answer."""
         from keto_tpu.engine.batcher import CheckBatcher
-        from keto_tpu.relationtuple.columns import CheckColumns
 
         store = InMemoryTupleStore()
         _seed_unicode_graph(store)
@@ -196,25 +209,18 @@ class TestOomBisection:
         try:
             rng = random.Random(5)
             warm = _request_pool(rng, 24)
-            cols_w = CheckColumns(
-                ["n"] * len(warm),
-                [r.object for r in warm],
-                ["view"] * len(warm),
-                subject_ids=[r.subject.id for r in warm],
-            ).validate()
-            batcher.check_batch_columnar(cols_w, 5)  # populate the cache
-            mixed = warm[:12] + _request_pool(rng, 36)  # hits + fresh rows
+            snap = breaker.snapshots.snapshot()
+            batcher.check_batch_encoded(*snap.encode_requests(warm), [5] * 24)
+            mixed = warm[:12] + _request_pool(rng, 36)  # repeats + fresh
             want = [oracle.subject_is_allowed(r) for r in mixed]
-            cols_m = CheckColumns(
-                ["n"] * len(mixed),
-                [r.object for r in mixed],
-                ["view"] * len(mixed),
-                subject_ids=[r.subject.id for r in mixed],
-            ).validate()
             FAULTS.arm("device.oom", times=2)
-            got = batcher.check_batch_columnar(cols_m, 5)
+            got = batcher.check_batch_encoded(
+                *snap.encode_requests(mixed), [5] * len(mixed)
+            )
             assert [bool(v) for v in got] == want
+            assert not FAULTS.armed("device.oom")
             assert not breaker.circuit_open()
+            assert breaker._fallback is None
         finally:
             batcher.close()
 
@@ -229,7 +235,7 @@ class TestCompileQuarantine:
         reqs = _request_pool(rng, 20)
         want = [oracle.subject_is_allowed(r) for r in reqs]
         FAULTS.arm("device.compile_fail")
-        enc = breaker.encode_batch(reqs)
+        enc = _encode(breaker, reqs)
         got = breaker.decode_launched(breaker.launch_encoded(enc))
         assert [bool(v) for v in got] == want
         assert not breaker.circuit_open()
@@ -237,13 +243,13 @@ class TestCompileQuarantine:
         assert len(q) == 1 and q[0]["bucket"] == 32
         # the same shape now routes straight to the oracle (no fault
         # armed, but the quarantine remembers) — still correct
-        enc2 = breaker.encode_batch(reqs)
+        enc2 = _encode(breaker, reqs)
         got2 = breaker.decode_launched(breaker.launch_encoded(enc2))
         assert [bool(v) for v in got2] == want
         # a DIFFERENT bucket is untouched by the quarantine: the device
         # still serves it
         small = reqs[:4]
-        enc3 = breaker.encode_batch(small)
+        enc3 = _encode(breaker, small)
         got3 = breaker.decode_launched(breaker.launch_encoded(enc3))
         assert [bool(v) for v in got3] == want[:4]
         assert not breaker.circuit_open()
@@ -322,7 +328,7 @@ class TestFailoverEndToEnd:
                 "namespaces": [{"id": 1, "name": "n"}],
                 "log": {"level": "error"},
                 "engine": {
-                    "mode": "device",
+                    "sharding": {"enabled": True},
                     "max_batch": 128,
                     "cache_size": 0,
                     "encoded_cache_size": 0,
@@ -394,8 +400,6 @@ class TestFailoverEndToEnd:
         from keto_tpu.driver.registry import DeviceSupervisor
 
         class _Eng:
-            interpret = False
-
             def reset_residency(self):
                 pass
 

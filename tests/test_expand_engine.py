@@ -2,11 +2,17 @@
 leaf for subject ids, one/two-level expansion, max-depth degradation to leaf,
 pagination, subject-set leaves, circular tuples."""
 
-from keto_tpu.engine.expand import ExpandEngine
+import numpy as np
+import pytest
+
+from keto_tpu.engine.expand import ExpandEngine, SnapshotExpandEngine
 from keto_tpu.engine.tree import NodeType, Tree
+from keto_tpu.graph import SnapshotManager
 from keto_tpu.namespace import MemoryNamespaceManager
 from keto_tpu.relationtuple import RelationTuple, SubjectID, SubjectSet
 from keto_tpu.store import InMemoryTupleStore
+
+from conftest import random_store
 
 
 def make_env(*namespaces):
@@ -140,3 +146,26 @@ class TestExpandEngine:
         s = str(e.build_tree(root, 100))
         assert "∪ n:obj#access" in s
         assert "u1" in s
+
+
+class TestSnapshotExpandMatchesStore:
+    """The snapshot engine's CSR walk builds the store engine's tree node
+    for node on random graphs, at every depth."""
+
+    @pytest.mark.parametrize("seed", range(3))
+    def test_random_graphs_expand(self, seed):
+        rng = np.random.default_rng(seed + 100)
+        store = random_store(rng, n_objects=10, n_users=8, n_edges=60)
+        host = ExpandEngine(store, max_depth=7)
+        dev = SnapshotExpandEngine(SnapshotManager(store), max_depth=7)
+        for depth in (1, 2, 4, 7):
+            for o in range(10):
+                for r in range(3):
+                    subject = SubjectSet(
+                        namespace="n", object=f"o{o}", relation=f"r{r}"
+                    )
+                    ht = host.build_tree(subject, max_depth=depth)
+                    dt = dev.build_tree(subject, max_depth=depth)
+                    hd = None if ht is None else ht.to_dict()
+                    dd = None if dt is None else dt.to_dict()
+                    assert hd == dd, f"seed={seed} depth={depth} {subject}"

@@ -1,6 +1,6 @@
 """Paged Expand: worklist traversal, page stitching, and token hygiene.
 
-The contract (keto_tpu/engine/expand.py + engine/device.py): the explicit
+The contract (keto_tpu/engine/expand.py, both engines): the explicit
 work-stack traversal never hits Python's recursion limit, paged expansion
 stitched with ``apply_expand_patches`` is byte-identical to the unpaged
 tree for every page size, and continuation tokens fail closed — garbage,
@@ -12,9 +12,9 @@ import sys
 import numpy as np
 import pytest
 
-from keto_tpu.engine.device import SnapshotExpandEngine
 from keto_tpu.engine.expand import (
     ExpandEngine,
+    SnapshotExpandEngine,
     decode_expand_page_token,
     encode_expand_page_token,
 )
@@ -25,7 +25,7 @@ from keto_tpu.relationtuple import RelationTuple, SubjectID, SubjectSet
 from keto_tpu.store import InMemoryTupleStore
 from keto_tpu.utils.errors import ErrMalformedInput, ErrMalformedPageToken
 
-from test_device_engines import random_store
+from conftest import random_store
 
 
 def t(s: str) -> RelationTuple:

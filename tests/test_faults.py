@@ -151,7 +151,7 @@ class TestDispatcherWatchdog:
 
 
 class _FakeEncoded:
-    """Stand-in for engine.device.EncodedBatch: just enough surface for
+    """Stand-in for an engine's encoded batch: just enough surface for
     the pipelined batcher (version/keys for the encoded cache, release for
     crash cleanup)."""
 
@@ -522,8 +522,9 @@ class TestDeviceCircuitBreaker:
 
     def test_injected_device_faults_reach_host_fallback_end_to_end(self):
         """The registry-wired path: device.compile_error and
-        device.batch_nan (engine/device.py fault sites) degrade to the
-        host oracle; answers stay correct throughout."""
+        device.batch_nan (fault sites of the sharded serving engine's
+        launch) degrade to the host oracle; answers stay correct
+        throughout."""
         from keto_tpu.driver import Config, Registry
 
         cfg = Config(
@@ -531,7 +532,7 @@ class TestDeviceCircuitBreaker:
                 "namespaces": [{"id": 1, "name": "n"}],
                 "log": {"level": "error"},
                 "engine": {
-                    "mode": "device",
+                    "sharding": {"enabled": True},
                     "cache_size": 0,  # a cache hit would mask the faults
                     "encoded_cache_size": 0,  # ditto for the encoded cache
                     "fallback_threshold": 2,

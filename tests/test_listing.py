@@ -23,7 +23,7 @@ from keto_tpu.relationtuple import RelationTuple, SubjectID, SubjectSet
 from keto_tpu.store import InMemoryTupleStore
 from keto_tpu.utils.errors import ErrMalformedPageToken, ErrStalePageToken
 from tests.test_api_server import ServerFixture
-from tests.test_device_engines import random_store
+from conftest import random_store
 
 
 def t(s: str) -> RelationTuple:

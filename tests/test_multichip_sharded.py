@@ -1,4 +1,6 @@
-"""Sharded engine correctness on a multi-device mesh.
+"""Sharded engines' correctness on a multi-device mesh: the serving
+engine (``engine.sharding.enabled``) and the mesh closure engine under it,
+against the host oracle.
 
 Runs only when >= 8 devices are visible: the 8-device virtual CPU mesh
 conftest.py sets up, and test_sharded_subprocess.py re-runs them in a
@@ -11,9 +13,11 @@ import pytest
 
 from keto_tpu.engine import CheckEngine
 from keto_tpu.graph import SnapshotManager
-from keto_tpu.parallel import ShardedCheckEngine, make_mesh
+from keto_tpu.parallel import ShardedServingEngine, make_mesh
 from keto_tpu.relationtuple import RelationTuple
 from keto_tpu.store import InMemoryTupleStore
+
+from conftest import random_store
 
 needs_mesh = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 devices (virtual CPU mesh)"
@@ -22,21 +26,6 @@ needs_mesh = pytest.mark.skipif(
 
 def t(s: str) -> RelationTuple:
     return RelationTuple.from_string(s)
-
-
-def random_store(rng, n_objects, n_users, n_edges, n_rel=3):
-    store = InMemoryTupleStore()
-    tuples = set()
-    for _ in range(n_edges):
-        obj = f"o{rng.integers(n_objects)}"
-        rel = f"r{rng.integers(n_rel)}"
-        if rng.random() < 0.45:
-            sub = f"n:o{rng.integers(n_objects)}#r{rng.integers(n_rel)}"
-        else:
-            sub = f"u{rng.integers(n_users)}"
-        tuples.add(f"n:{obj}#{rel}@({sub})")
-    store.write_relation_tuples(*(t(s) for s in tuples))
-    return store
 
 
 @needs_mesh
@@ -48,7 +37,7 @@ def test_sharded_matches_oracle(mesh_shape):
     data, edge = mesh_shape
     mesh = make_mesh(data=data, edge=edge)
     host = CheckEngine(store, max_depth=5)
-    sharded = ShardedCheckEngine(mgr, mesh=mesh, max_depth=5)
+    sharded = ShardedServingEngine(mgr, mesh=mesh, max_depth=5)
     reqs = []
     for _ in range(96):
         obj = f"o{rng.integers(20)}"
@@ -70,7 +59,7 @@ def test_sharded_depth_budget_and_writes():
         t("n:obj#r@(n:s1#m)"), t("n:s1#m@(n:s2#m)"), t("n:s2#m@alice")
     )
     mgr = SnapshotManager(store)
-    eng = ShardedCheckEngine(mgr, mesh=make_mesh(data=2, edge=4), max_depth=8)
+    eng = ShardedServingEngine(mgr, mesh=make_mesh(data=2, edge=4), max_depth=8)
     req = t("n:obj#r@alice")
     assert not eng.subject_is_allowed(req, max_depth=2)
     assert eng.subject_is_allowed(req, max_depth=3)
@@ -87,7 +76,7 @@ def test_sharded_check_ids_matches_object_api():
     store = random_store(rng, n_objects=16, n_users=10, n_edges=220)
     mgr = SnapshotManager(store)
     host = CheckEngine(store, max_depth=5)
-    eng = ShardedCheckEngine(mgr, mesh=make_mesh(data=2, edge=4), max_depth=5)
+    eng = ShardedServingEngine(mgr, mesh=make_mesh(data=2, edge=4), max_depth=5)
     snap = mgr.snapshot()
     reqs = []
     for _ in range(64):
@@ -118,7 +107,7 @@ def test_sharded_circular_and_unknowns():
     store = InMemoryTupleStore()
     store.write_relation_tuples(t("n:a#r@(n:b#r)"), t("n:b#r@(n:a#r)"))
     mgr = SnapshotManager(store)
-    eng = ShardedCheckEngine(mgr, mesh=make_mesh(data=1, edge=8))
+    eng = ShardedServingEngine(mgr, mesh=make_mesh(data=1, edge=8))
     assert not eng.subject_is_allowed(t("n:a#r@alice"))
     assert eng.subject_is_allowed(t("n:a#r@(n:a#r)"))
     assert not eng.subject_is_allowed(t("zz:zz#zz@nobody"))

@@ -17,7 +17,7 @@ from keto_tpu.graph import SnapshotManager
 from keto_tpu.relationtuple import RelationTuple
 from keto_tpu.store import InMemoryTupleStore
 
-from test_device_engines import random_store
+from conftest import random_store
 
 pytestmark = pytest.mark.skipif(
     native.lib is None, reason="native kernels unavailable (no C compiler)"

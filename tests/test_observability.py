@@ -516,8 +516,9 @@ class TestSLOBurnRate:
 
 @pytest.fixture(scope="module")
 def drill():
-    """A device-engine server with an armed /debug surface and tight
-    flight/SLO thresholds — the seeded slow-request drill target."""
+    """A sharded-serving server (whose launch honors device.slow) with an
+    armed /debug surface and tight flight/SLO thresholds — the seeded
+    slow-request drill target."""
     import os
     import tempfile
 
@@ -530,7 +531,7 @@ def drill():
                 "write": {"port": 0, "host": "127.0.0.1"},
             },
             "log": {"level": "error"},
-            "engine": {"mode": "device", "max_batch": 64},
+            "engine": {"sharding": {"enabled": True}, "max_batch": 64},
             "telemetry": {
                 "flight": {
                     "capacity": 64, "slow_ms": 50, "dir": dump_dir,

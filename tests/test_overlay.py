@@ -20,7 +20,7 @@ from keto_tpu.graph import SnapshotManager
 from keto_tpu.relationtuple import RelationTuple
 from keto_tpu.store import InMemoryTupleStore
 
-from test_device_engines import random_store
+from conftest import random_store
 
 
 def t(s: str) -> RelationTuple:

@@ -139,7 +139,7 @@ class TestIsolation:
 
 class TestDeviceIntegration:
     def test_snapshot_manager_over_sqlite(self, tmp_path, nsmgr):
-        from keto_tpu.engine.device import DeviceCheckEngine
+        from keto_tpu.engine.closure import ClosureCheckEngine
         from keto_tpu.graph import SnapshotManager
 
         s = SQLiteTupleStore(str(tmp_path / "g.db"), namespace_manager=nsmgr)
@@ -147,7 +147,7 @@ class TestDeviceIntegration:
             t("n:obj#access@(n:org#member)"), t("n:org#member@alice")
         )
         mgr = SnapshotManager(s)
-        dev = DeviceCheckEngine(mgr)
+        dev = ClosureCheckEngine(mgr, query_mode="device")
         assert dev.subject_is_allowed(t("n:obj#access@alice"))
         assert not dev.subject_is_allowed(t("n:obj#access@bob"))
         # incremental write-through

@@ -1,21 +1,17 @@
-"""Pipelined dispatch vs host oracle: the multi-stage batcher (encode ->
-launch -> decode, >=2 batches in flight, encoded-request cache) must answer
-exactly like the host BFS CheckEngine under concurrent mixed-size traffic —
-the ISSUE-2 acceptance drill. Also covers cache invalidation across writes,
-the check_batch bulk result cache, and the /pipeline stats surface."""
-
-import threading
+"""The batcher's dispatch choice and the check_batch bulk result cache:
+caller-assembled batches probe the result cache in bulk and dispatch only
+the missing rows; an engine without the split encode/launch/decode API
+keeps the serial dispatcher whatever ``pipeline_depth`` asks for."""
 
 import pytest
 
 from keto_tpu.engine import CheckEngine
 from keto_tpu.engine.batcher import CheckBatcher
 from keto_tpu.engine.cache import CheckResultCache
-from keto_tpu.engine.device import DeviceCheckEngine
+from keto_tpu.engine.closure import ClosureCheckEngine
 from keto_tpu.graph import SnapshotManager
 from keto_tpu.relationtuple import RelationTuple
 from keto_tpu.store import InMemoryTupleStore
-from keto_tpu.telemetry import MetricsRegistry
 
 
 def t(s: str) -> RelationTuple:
@@ -52,91 +48,10 @@ def _workload():
     return reqs
 
 
-@pytest.fixture
-def pipelined(store):
-    mgr = SnapshotManager(store)
-    engine = DeviceCheckEngine(mgr, max_depth=5)
-    b = CheckBatcher(
-        engine,
-        window_s=0.0005,
-        metrics=MetricsRegistry(),
-        pipeline_depth=2,
-        encode_workers=2,
-        encoded_cache_size=4096,
-    )
-    yield b
-    b.close()
-
-
-class TestPipelineParity:
-    def test_batcher_is_pipelined(self, pipelined):
-        assert pipelined.pipelined is True
-        assert len(pipelined._threads) == 4  # 2 encode + launch + decode
-
-    def test_concurrent_mixed_batches_match_host_oracle(
-        self, store, pipelined
-    ):
-        oracle = CheckEngine(store, max_depth=5)
-        reqs = _workload()
-        want = [oracle.subject_is_allowed(r) for r in reqs]
-        got = [None] * len(reqs)
-        errs = []
-
-        def worker(wid, n_threads=6):
-            try:
-                # staggered slices -> batches coalesce at varying sizes,
-                # landing in different padding buckets concurrently
-                for i in range(wid, len(reqs), n_threads):
-                    got[i] = pipelined.check(reqs[i], timeout=30)
-            except Exception as e:  # pragma: no cover - failure detail
-                errs.append(e)
-
-        threads = [
-            threading.Thread(target=worker, args=(w,)) for w in range(6)
-        ]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join(timeout=60)
-        assert not errs
-        assert got == want
-
-    def test_encoded_cache_hits_and_stays_correct(self, store, pipelined):
-        oracle = CheckEngine(store, max_depth=5)
-        reqs = _workload()[:32]
-        want = [oracle.subject_is_allowed(r) for r in reqs]
-        # two passes: the second round's rows resolve from the
-        # encoded-request cache, and must still match the oracle
-        for _round in range(2):
-            assert [pipelined.check(r, timeout=30) for r in reqs] == want
-        assert len(pipelined.encoded_cache) > 0
-
-    def test_write_invalidates_encoded_cache(self, store, pipelined):
-        req = t("n:doc0#view@newcomer")
-        assert pipelined.check(req, timeout=30) is False
-        store.write_relation_tuples(t("n:group0#member@newcomer"))
-        # the snapshot version moved: the cached denial must not be served
-        assert pipelined.check(req, timeout=30) is True
-
-    def test_pipeline_stats_shape(self, pipelined):
-        stats = pipelined.pipeline_stats()
-        assert stats["pipelined"] is True
-        assert stats["pipeline_depth"] == 2
-        assert stats["encode_workers"] == 2
-        for key in (
-            "queue_depth",
-            "launch_queue_depth",
-            "decode_queue_depth",
-            "batches_in_pipeline",
-            "encoded_cache_entries",
-        ):
-            assert isinstance(stats[key], int)
-
-
 class TestCheckBatchBulkCache:
     def test_check_batch_uses_result_cache(self, store):
         mgr = SnapshotManager(store)
-        engine = DeviceCheckEngine(mgr, max_depth=5)
+        engine = ClosureCheckEngine(mgr, max_depth=5)
         calls = []
         real = engine.batch_check
 

@@ -23,7 +23,7 @@ from keto_tpu.relationtuple import RelationTuple, SubjectSet
 from keto_tpu.store import InMemoryTupleStore
 
 from test_closure_engine import _random_requests
-from test_device_engines import random_store
+from conftest import random_store
 
 
 def t(s: str) -> RelationTuple:

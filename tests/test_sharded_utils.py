@@ -1,8 +1,8 @@
-"""Host-side unit tests for the sharded engine's helpers (no device mesh
+"""Host-side unit tests for the sharded engines' helpers (no device mesh
 needed; the full sharded behavior runs under the virtual-mesh subprocess
 suite in test_multichip_sharded.py)."""
 
-from keto_tpu.parallel.sharded import ShardedCheckEngine
+from keto_tpu.parallel import ShardedServingEngine
 
 
 class TestShardedBucket:
@@ -14,7 +14,7 @@ class TestShardedBucket:
             eng = Dummy()
             eng.n_data = n_data
             for n in (1, 7, 8, 9, 100, 4096):
-                b = ShardedCheckEngine._bucket_batch(eng, n)
+                b = ShardedServingEngine._bucket_batch(eng, n)
                 assert b >= n
                 assert b % n_data == 0
                 per = b // n_data

@@ -72,24 +72,6 @@ def test_semiring_pallas_builder_at_interior_limit(one_chip):
     assert mem.temp_size_in_bytes < 16 * 10**9
 
 
-def test_packed_batched_check_kernel(one_chip):
-    from keto_tpu.ops.packed import packed_batched_check
-
-    nodes, edges = 1 << 19, 1 << 20
-    vec = lambda n: _spec((n,), jnp.int32, one_chip)  # noqa: E731
-    compiled = packed_batched_check.lower(
-        vec(edges),
-        vec(edges),
-        vec(_BATCH),
-        vec(_BATCH),
-        vec(_BATCH),
-        padded_nodes=nodes,
-        max_steps=5,
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 10**9
-
-
 def test_closure_query_at_rbac1m_width(one_chip):
     from keto_tpu.ops.closure import closure_query
 
@@ -135,7 +117,7 @@ def test_interior_walk_at_drive8m_size(one_chip):
 
 def test_sharded_serving_step_on_four_chips(topo):
     from keto_tpu.parallel.closure_sharded import _sharded_closure_check
-    from keto_tpu.parallel.sharded import make_mesh
+    from keto_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(topo.devices, data=1)
     n = mesh.shape["edge"]

@@ -183,7 +183,7 @@ def main():
     import gc as _gc
 
     _gc.freeze()
-    from keto_tpu.engine.device import SnapshotExpandEngine
+    from keto_tpu.engine.expand import SnapshotExpandEngine
 
     expander = SnapshotExpandEngine(snapshots, max_depth=5)
     exp_lat = []

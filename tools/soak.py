@@ -54,6 +54,15 @@ import time
 from concurrent.futures import TimeoutError as _FutTimeout
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# two virtual CPU devices: the engine and device-chaos phases serve from
+# the sharded serving engine, whose launch carries the device.* fault sites
+if "xla_force_host_platform_device_count" not in os.environ.get(
+    "XLA_FLAGS", ""
+):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=2"
+    ).strip()
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -435,7 +444,7 @@ def run_engine_soak(
             "namespaces": [{"id": 1, "name": "n"}],
             "log": {"level": "error"},
             "engine": {
-                "mode": "device",
+                "sharding": {"enabled": True},
                 "max_batch": 256,
                 "cache_size": 0,  # a cache hit would mask engine faults
                 "encoded_cache_size": 0,
@@ -604,11 +613,12 @@ def run_engine_soak(
     # -- drain + final parity sweep against the host oracle -----------------
     FAULTS.reset()  # disarm leftovers (e.g. an unconsumed slow arming)
     deadline = time.time() + 30
+    # a serial batcher (no split-API engine) keeps no pipeline registry
     stats = checker.pipeline_stats()
-    while stats["batches_in_pipeline"] and time.time() < deadline:
+    while stats.get("batches_in_pipeline") and time.time() < deadline:
         time.sleep(0.05)
         stats = checker.pipeline_stats()
-    if stats["batches_in_pipeline"]:
+    if stats.get("batches_in_pipeline"):
         violations.add(
             f"pipeline did not drain: {stats['batches_in_pipeline']} "
             "batches still registered"
@@ -697,7 +707,8 @@ def run_overload_drill(seed: int, smoke: bool = True) -> dict:
             "namespaces": [{"id": 1, "name": "n"}],
             "log": {"level": "error"},
             "engine": {
-                "mode": "device",
+                "mode": "closure",
+                "query_mode": "device",
                 # a deliberately window-bound engine (~max_batch/window
                 # checks/s) so a thread-pool client can genuinely offer
                 # 10x its capacity — the pool of blocking workers tops
@@ -981,7 +992,7 @@ def run_device_chaos(seed: int) -> dict:
     answers and bounded recovery throughout:
 
     1. OOM bisection — ``device.oom`` armed 3x against one 120-row
-       columnar batch: every answer must match the host oracle, the
+       encoded batch: every answer must match the host oracle, the
        breaker must stay closed (no host-fallback escalation), and
        ``keto_device_oom_bisections_total`` must reach >= 3.
     2. Compile-failure quarantine — ``device.compile_fail`` armed: the
@@ -1003,7 +1014,7 @@ def run_device_chaos(seed: int) -> dict:
             "namespaces": [{"id": 1, "name": "n"}],
             "log": {"level": "error"},
             "engine": {
-                "mode": "device",
+                "sharding": {"enabled": True},
                 "max_batch": 256,
                 "cache_size": 0,  # a cache hit would mask device faults
                 "encoded_cache_size": 0,
@@ -1048,8 +1059,13 @@ def run_device_chaos(seed: int) -> dict:
         return cols.validate(), want
 
     def wrong_count(cols_want, label: str) -> int:
+        # the encoded path: the breaker's launch seam (OOM bisection,
+        # compile quarantine) guards exactly these batches
         cols, want = cols_want
-        got = checker.check_batch_columnar(cols, 5)
+        s_ids, t_ids = reg.snapshots().snapshot().encode_requests_columnar(
+            cols
+        )
+        got = checker.check_batch_encoded(s_ids, t_ids, [5] * len(want))
         wrong = sum(1 for g, w in zip(got, want) if bool(g) is not w)
         if wrong:
             violations.add(f"{label}: {wrong}/{len(want)} wrong answers")
