@@ -23,6 +23,15 @@ Two dispatch shapes share this class:
   (start, target, depth) triple was answered at this snapshot version skip
   the kernel entirely.
 
+Caller-assembled columnar batches (``check_batch_columnar``, the BatchCheck
+transports) skip the queue and run on their own handler threads, and
+concurrent ones group-commit: a call that needs the engine either leads an
+engine pass, with every call pending at that moment merged into one
+``batch_check_columns``, or waits for a leader to answer it. At most
+``_COLUMNS_PASSES`` passes run at once; a finishing leader hands its slot
+to the oldest waiting call. ``keto_batcher_columns_pass_calls`` counts the
+calls each pass served.
+
 Because callers funnel through shared-fate stage threads, every stage is
 supervised the same way the PR-1 dispatcher was:
 
@@ -54,7 +63,10 @@ from concurrent.futures import Future
 from concurrent.futures import TimeoutError as _FutTimeout
 from typing import Optional, Sequence
 
+import numpy as np
+
 from ..faults import FAULTS
+from ..relationtuple.columns import CheckColumns
 from ..relationtuple.definitions import RelationTuple
 from ..telemetry.attribution import current_ledger, ledger_mark
 from ..telemetry.metrics import (
@@ -95,6 +107,29 @@ class DispatcherCrashed(ErrInternal):
 
 # close()/clean-shutdown marker passed down the stage queues
 _SENTINEL = object()
+
+#: columnar engine passes in flight at once. One: on a v5e a second
+#: concurrent pass halved the calls each pass served and raised the p95 of
+#: the rbac1m and github6m cells; only the interior-walk cell, whose device
+#: time a call is ~8x larger, gained from it (PERF.md, section 6)
+_COLUMNS_PASSES = 1
+
+
+class _ColumnsCall:
+    """A columnar call in the group commit: its rows and depth; once its
+    pass ends, its answers or the pass's exception. ``wake`` is held until
+    a leader releases it, with the answers or with the lead."""
+
+    __slots__ = ("cols", "depth", "wake", "lead", "result", "error")
+
+    def __init__(self, cols, depth: int):
+        self.cols = cols
+        self.depth = depth
+        self.wake = threading.Lock()
+        self.wake.acquire()
+        self.lead = False
+        self.result = None
+        self.error = None
 
 
 class _PBatch:
@@ -188,6 +223,7 @@ class CheckBatcher:
         self._m_columnar = None
         self._m_deadline = None
         self._m_cancelled = None
+        self._m_pass_calls = None
         # per-stage cull tallies mirrored outside the metrics registry so
         # pipeline_stats() (the /pipeline endpoint) can surface them even
         # on metric-less builds
@@ -211,6 +247,11 @@ class CheckBatcher:
                 "keto_batcher_columnar_batches_total",
                 "caller-assembled batches served through the columnar "
                 "zero-object path",
+            )
+            self._m_pass_calls = metrics.histogram(
+                "keto_batcher_columns_pass_calls",
+                "columnar calls served by one engine pass (group commit)",
+                buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32),
             )
             self._m_deadline = deadline_expired_counter(metrics)
             self._m_cancelled = metrics.counter(
@@ -238,6 +279,11 @@ class CheckBatcher:
         # yet — the watchdog fails exactly these on a dispatcher death, and
         # close() fails them after the join budget
         self._inflight: list[tuple] = []
+        # columnar group commit: engine passes running and the calls
+        # waiting for one, oldest first (_run_columns)
+        self._pass_lock = threading.Lock()
+        self._passes = 0
+        self._pending: list[_ColumnsCall] = []
         # pipelined mode: every batch admitted to the pipeline and not yet
         # resolved, whichever stage or queue currently owns it — close()
         # fails the stragglers after the join budget
@@ -689,6 +735,85 @@ class CheckBatcher:
         return out
 
     def _run_columns(self, cols, max_depth: int) -> list[bool]:
+        """Group commit: if fewer than ``_COLUMNS_PASSES`` engine passes
+        are running the call leads a pass at once, else it joins the
+        pending list and sleeps until a finishing leader either hands it
+        the lead or wakes it with its answers. A leader serves every call
+        pending when it starts, up to ``_admit_rows()`` rows, in one
+        engine call. Nothing waits for company: a lone call leads a pass
+        of one."""
+        call = _ColumnsCall(cols, max_depth)
+        with self._pass_lock:
+            if self._passes < _COLUMNS_PASSES:
+                # nothing is pending while a slot is free: a finishing
+                # leader hands its slot to the oldest waiter
+                self._passes += 1
+                call.lead = True
+            else:
+                self._pending.append(call)
+        if not call.lead:
+            call.wake.acquire()
+            ledger_mark("queue")
+            if not call.lead:
+                if call.error is not None:
+                    raise call.error
+                return call.result
+        return self._lead_pass(call)
+
+    def _lead_pass(self, call: "_ColumnsCall") -> list[bool]:
+        group = [call]
+        try:
+            room = self._admit_rows() - len(call.cols)
+            with self._pass_lock:
+                pending = self._pending
+                while pending and len(pending[0].cols) <= room:
+                    room -= len(pending[0].cols)
+                    group.append(pending.pop(0))
+            if self._m_pass_calls is not None:
+                self._m_pass_calls.observe(len(group))
+            if len(group) == 1:
+                answers = self._engine_columns(call.cols, call.depth)
+            else:
+                merged = CheckColumns.concat([c.cols for c in group])
+                if all(c.depth == call.depth for c in group):
+                    answers = self._engine_columns(merged, call.depth)
+                else:
+                    answers = self._engine_columns(
+                        merged,
+                        0,
+                        np.repeat(
+                            [c.depth for c in group],
+                            [len(c.cols) for c in group],
+                        ),
+                    )
+        except BaseException as e:
+            self._hand_off()
+            for c in group[1:]:
+                c.error = e
+                c.wake.release()
+            raise
+        self._hand_off()
+        if len(group) == 1:
+            return answers
+        at = len(call.cols)
+        for c in group[1:]:
+            c.result = answers[at : at + len(c.cols)]
+            at += len(c.cols)
+            c.wake.release()
+        return answers[: len(call.cols)]
+
+    def _hand_off(self) -> None:
+        """End a pass: its slot goes to the oldest pending call, which
+        wakes as the next leader, or is freed when none is pending."""
+        with self._pass_lock:
+            if not self._pending:
+                self._passes -= 1
+                return
+            succ = self._pending.pop(0)
+        succ.lead = True
+        succ.wake.release()
+
+    def _engine_columns(self, cols, max_depth: int, depths=None) -> list[bool]:
         run = getattr(self.engine, "batch_check_columns", None)
         out: list[bool] = []
         n = len(cols)
@@ -700,14 +825,16 @@ class CheckBatcher:
                 if i == 0 and n <= step
                 else cols.select(range(i, min(i + step, n)))
             )
+            # per-row depths only where a pass merged calls of two depths
+            kw = {} if depths is None else {"depths": depths[i : i + step]}
             if run is not None:
                 # the engines' columnar answers are already a list of bools
-                out.extend(run(chunk, max_depth))
+                out.extend(run(chunk, max_depth, **kw))
             else:
                 out.extend(
                     bool(v)
                     for v in self.engine.batch_check(
-                        chunk.materialize(), max_depth
+                        chunk.materialize(), max_depth, **kw
                     )
                 )
             i += step
@@ -750,8 +877,6 @@ class CheckBatcher:
                         else self.max_freshness_wait_s()
                     ),
                 )
-        import numpy as np
-
         s = np.asarray(start_ids, dtype=np.int64)
         t = np.asarray(target_ids, dtype=np.int64)
         gmax = int(getattr(self.engine, "global_max_depth", 0) or 0)
@@ -825,8 +950,6 @@ class CheckBatcher:
                 "engine has no array-native check path "
                 "(check_batch_encoded needs check_ids or encode_ids)"
             )
-        import numpy as np
-
         # the closure engine's array path wants per-row subject kinds;
         # derive them from the vocab (ids out of range read as sets —
         # they clamp to the inert dummy downstream anyway)

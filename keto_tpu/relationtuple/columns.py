@@ -210,6 +210,19 @@ class CheckColumns:
     # -- constructors --------------------------------------------------------
 
     @classmethod
+    def concat(cls, parts: Sequence["CheckColumns"]) -> "CheckColumns":
+        """Every row of ``parts``, in order, as one batch. An omitted
+        subject column (length 0, see ``validate``) reads as all-empty."""
+        out = cls.__new__(cls)
+        for name in cls.__slots__:
+            col: List[str] = []
+            for p in parts:
+                c = getattr(p, name)
+                col.extend(c if len(c) else [""] * len(p))
+            setattr(out, name, col)
+        return out
+
+    @classmethod
     def from_proto(cls, request) -> "CheckColumns":
         """Decode the columnar repeated fields of a ``BatchCheckRequest``
         (fields 5..11) straight into columns."""

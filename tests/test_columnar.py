@@ -17,6 +17,7 @@ and to the host oracle — through every layer it touches.
 import asyncio
 import json
 import threading
+import time
 
 import grpc
 import httpx
@@ -174,6 +175,19 @@ class TestCheckColumns:
         cols = CheckColumns.from_tuples(reqs)
         sub = cols.select([0, 3])
         assert sub.materialize() == [reqs[0], reqs[3]]
+
+    def test_concat_keeps_rows_in_order(self):
+        a = CheckColumns.from_tuples([_t("n:o0#view@u0")])
+        b = CheckColumns(["n", "n"], ["o1", "o2"], ["view", "edit"],
+                         subject_ids=["u1", "u2"])  # set columns omitted
+        c = CheckColumns.from_tuples([_t("n:o3#view@(n:g#member)")])
+        got = CheckColumns.concat([a, b, CheckColumns([], [], []), c])
+        assert len(got) == 4
+        assert got.materialize() == [
+            _t("n:o0#view@u0"), _t("n:o1#view@u1"), _t("n:o2#edit@u2"),
+            _t("n:o3#view@(n:g#member)"),
+        ]
+        assert got.validate() is got
 
 
 class TestEngineParityFuzz:
@@ -345,6 +359,256 @@ class TestResultCacheFreshness:
             assert [bool(v) for v in got] == [False, True]
         finally:
             b.close()
+
+
+def _chain_store():
+    """g0 -> g1 -> ... -> g6 -> alice as nested member sets, so how deep
+    a check may walk decides its answer."""
+    store = InMemoryTupleStore()
+    store.write_relation_tuples(
+        *[_t(f"n:g{i}#member@(n:g{i + 1}#member)") for i in range(6)],
+        _t("n:g6#member@alice"),
+        _t("n:doc#view@(n:g0#member)"),
+        _t("n:g3#member@bob"),
+    )
+    return store
+
+
+def _chain_call(k: int) -> CheckColumns:
+    rows = [f"n:g{i}#member@alice" for i in range(7)] + [
+        "n:doc#view@alice", "n:doc#view@bob", "n:g0#member@carol",
+        "ghost:x#view@alice",
+    ]
+    # each call a different rotation and length of the rows
+    rows = (rows[k % len(rows):] + rows[: k % len(rows)])[: 4 + k % 8]
+    return CheckColumns.from_tuples([_t(r) for r in rows])
+
+
+class _GatedEngine:
+    """Delegates to ``inner``. Pass k (in order of entry) releases
+    ``entered``, then blocks on ``gates[k]`` while k < len(gates); the
+    pass numbered ``fail_pass`` raises instead of answering."""
+
+    def __init__(self, inner, n_gates: int, fail_pass=None):
+        self.inner = inner
+        self.gates = [threading.Event() for _ in range(n_gates)]
+        self.entered = threading.Semaphore(0)
+        self.rows: list[int] = []  # rows of each pass, in order of entry
+        self.fail_pass = fail_pass
+        self._lock = threading.Lock()
+
+    def batch_check_columns(self, cols, max_depth=0, depths=None):
+        with self._lock:
+            k = len(self.rows)
+            self.rows.append(len(cols))
+        self.entered.release()
+        if k < len(self.gates):
+            assert self.gates[k].wait(30)
+        if k == self.fail_pass:
+            raise RuntimeError("injected engine pass failure")
+        return self.inner.batch_check_columns(cols, max_depth, depths)
+
+
+def _pass_calls(metrics) -> tuple[float, float]:
+    """(passes, calls served) of ``keto_batcher_columns_pass_calls``."""
+    got = {}
+    for line in metrics.expose().splitlines():
+        for k in ("count", "sum"):
+            if line.startswith(f"keto_batcher_columns_pass_calls_{k} "):
+                got[k] = float(line.split()[-1])
+    return got["count"], got["sum"]
+
+
+def _run_queued(batcher, stub, calls):
+    """Hold every pass slot in ``stub``, queue ``calls`` ((cols, depth)
+    pairs) behind them, then let the passes go one at a time. Returns each
+    call's answers, or the exception it raised."""
+    out: list = [None] * len(calls)
+
+    def send(i, cols, depth):
+        try:
+            out[i] = batcher.check_batch_columnar(cols, depth)
+        except Exception as e:  # noqa: BLE001 - the test inspects it
+            out[i] = e
+
+    one = CheckColumns.from_tuples([_t("n:doc#view@alice")])
+    holders = [
+        threading.Thread(target=batcher.check_batch_columnar, args=(one, 0))
+        for _ in stub.gates
+    ]
+    for t in holders:
+        t.start()
+        assert stub.entered.acquire(timeout=30)
+    threads = [
+        threading.Thread(target=send, args=(i, cols, depth))
+        for i, (cols, depth) in enumerate(calls)
+    ]
+    for t in threads:
+        t.start()
+    deadline = time.monotonic() + 30
+    while len(batcher._pending) < len(calls):
+        assert time.monotonic() < deadline, "calls never queued"
+        time.sleep(0.001)
+    # the first held pass ends: its slot goes to the oldest queued call,
+    # which serves every queued call in one pass before the second ends
+    stub.gates[0].set()
+    assert stub.entered.acquire(timeout=30)
+    for g in stub.gates[1:]:
+        g.set()
+    for t in holders + threads:
+        t.join(30)
+    return out
+
+
+class TestColumnsGroupCommit:
+    """Concurrent columnar calls share engine passes: a call queued behind
+    the running passes is answered by the next one, with its own depth,
+    and gets exactly the answers it gets alone."""
+
+    _DEPTHS = {"mixed": [1, 2, 3, 0, 5, 6, 8, 4, 2, 7], "same": [0] * 10}
+
+    @pytest.mark.parametrize("depths", sorted(_DEPTHS))
+    def test_queued_calls_share_one_pass(self, depths):
+        from keto_tpu.engine.batcher import _COLUMNS_PASSES
+        from keto_tpu.telemetry.metrics import MetricsRegistry
+
+        store = _chain_store()
+        oracle = CheckEngine(store, max_depth=8)
+        engine = ClosureCheckEngine(
+            SnapshotManager(store), max_depth=8, query_mode="device"
+        )
+        calls = [(_chain_call(k), d)
+                 for k, d in enumerate(self._DEPTHS[depths])]
+        alone = CheckBatcher(engine, window_s=0)
+        try:
+            want = [alone.check_batch_columnar(c, d) for c, d in calls]
+        finally:
+            alone.close()
+        for (cols, d), w in zip(calls, want):
+            assert w == [bool(oracle.subject_is_allowed(r, d))
+                         for r in cols.materialize()]
+        # the answers turn on the depth: g0 reaches alice through 6 sets
+        alice = _t("n:g0#member@alice")
+        assert not oracle.subject_is_allowed(alice, 3)
+        assert oracle.subject_is_allowed(alice, 0)
+        metrics = MetricsRegistry()
+        stub = _GatedEngine(engine, _COLUMNS_PASSES)
+        b = CheckBatcher(stub, window_s=0, metrics=metrics)
+        try:
+            got = _run_queued(b, stub, calls)
+        finally:
+            b.close()
+        assert got == want
+        held = [1] * _COLUMNS_PASSES
+        assert stub.rows == held + [sum(len(c) for c, _ in calls)]
+        assert _pass_calls(metrics) == (
+            _COLUMNS_PASSES + 1, _COLUMNS_PASSES + len(calls))
+
+    def test_lone_call_is_a_pass_of_one(self):
+        from keto_tpu.telemetry.metrics import MetricsRegistry
+
+        store = _chain_store()
+        metrics = MetricsRegistry()
+        b = CheckBatcher(
+            ClosureCheckEngine(SnapshotManager(store), max_depth=8),
+            window_s=0, metrics=metrics,
+        )
+        try:
+            for k in range(5):
+                b.check_batch_columnar(_chain_call(k), k)
+        finally:
+            b.close()
+        assert _pass_calls(metrics) == (5, 5)
+        assert b._passes == 0 and not b._pending
+
+    def test_concurrent_calls_match_oracle(self):
+        """More client threads than cores, a short switch interval: every
+        call is served by exactly one pass, with its own answers."""
+        import sys
+
+        from keto_tpu.telemetry.metrics import MetricsRegistry
+
+        store = _chain_store()
+        oracle = CheckEngine(store, max_depth=8)
+        metrics = MetricsRegistry()
+        b = CheckBatcher(
+            ClosureCheckEngine(
+                SnapshotManager(store), max_depth=8, query_mode="device"
+            ),
+            window_s=0, metrics=metrics,
+        )
+        n_threads, n_calls = 16, 12
+        wrong: list = []
+
+        def client(seed):
+            rng = np.random.default_rng(seed)
+            for _ in range(n_calls):
+                k, d = int(rng.integers(40)), int(rng.integers(9))
+                cols = _chain_call(k)
+                want = [bool(oracle.subject_is_allowed(r, d))
+                        for r in cols.materialize()]
+                got = b.check_batch_columnar(cols, d)
+                if got != want:
+                    wrong.append((k, d, got, want))
+
+        threads = [threading.Thread(target=client, args=(s,))
+                   for s in range(n_threads)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+            b.close()
+        assert not any(t.is_alive() for t in threads)
+        assert not wrong
+        passes, served = _pass_calls(metrics)
+        assert served == n_threads * n_calls and passes <= served
+        assert b._passes == 0 and not b._pending
+
+    @pytest.mark.parametrize("breaker", [True, False])
+    def test_failed_pass_reaches_every_member(self, breaker):
+        """A merged pass that raises is re-answered by the breaker's host
+        oracle for every member, as one failure; without a breaker every
+        member gets the exception."""
+        from keto_tpu.engine.batcher import _COLUMNS_PASSES
+        from keto_tpu.telemetry.metrics import MetricsRegistry
+
+        store = _chain_store()
+        oracle = CheckEngine(store, max_depth=8)
+        stub = _GatedEngine(
+            ClosureCheckEngine(SnapshotManager(store), max_depth=8),
+            _COLUMNS_PASSES, fail_pass=_COLUMNS_PASSES,
+        )
+        metrics = MetricsRegistry()
+        engine = stub
+        if breaker:
+            engine = DeviceFallbackEngine(
+                stub, lambda: CheckEngine(store, max_depth=8),
+                failure_threshold=3, metrics=metrics,
+            )
+        calls = [(_chain_call(k), d) for k, d in enumerate([0, 2, 5, 3])]
+        b = CheckBatcher(engine, window_s=0, metrics=metrics)
+        try:
+            got = _run_queued(b, stub, calls)
+        finally:
+            b.close()
+        assert len(stub.rows) == _COLUMNS_PASSES + 1
+        if breaker:
+            assert got == [
+                [bool(oracle.subject_is_allowed(r, d))
+                 for r in cols.materialize()]
+                for cols, d in calls
+            ]
+            assert engine.n_fallback_batches == 1
+            assert "keto_device_engine_failures_total 1.0" in \
+                metrics.expose()
+        else:
+            assert all(isinstance(e, RuntimeError) for e in got)
+        assert b._passes == 0 and not b._pending
 
 
 # ---------------------------------------------------------------------------
